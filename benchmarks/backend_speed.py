@@ -344,9 +344,14 @@ def run(scale=None, n: int = 1 << 20, smoke: bool = True,
         return [dict(name=c[0], case=c[1], op=c[2], ok=bool(c[3]))
                 for c in checks if c[2] in ops]
 
+    import jax
+
+    dev = jax.devices()[0]
+    device = dict(platform=dev.platform, kind=dev.device_kind,
+                  count=jax.device_count())
     if json_out:
         with open(json_out, "w") as f:
-            json.dump(dict(n=int(x.size), eb=eb,
+            json.dump(dict(n=int(x.size), eb=eb, device=device,
                            refine_bounds=[REFINE_COARSE * eb,
                                           REFINE_FINE * eb],
                            records=records,
@@ -355,7 +360,7 @@ def run(scale=None, n: int = 1 << 20, smoke: bool = True,
         print(f"wrote {json_out} ({len(records)} decode records)")
     if json_out_compress:
         with open(json_out_compress, "w") as f:
-            json.dump(dict(n=int(x.size), eb=eb,
+            json.dump(dict(n=int(x.size), eb=eb, device=device,
                            chunk_elems=CHUNK_ELEMS,
                            records=comp_records,
                            checks=_check_dicts(("compress",))),
@@ -386,4 +391,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from pathlib import Path
+
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

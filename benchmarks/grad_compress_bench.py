@@ -133,4 +133,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from pathlib import Path
+
+    from repro import compile_cache
+    compile_cache.enable(Path(__file__).resolve().parents[1])
     main()

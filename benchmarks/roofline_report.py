@@ -9,22 +9,35 @@ the wall-clock of each decode op, and this report divides the two:
 
     achieved bytes/s per kernel  vs  the substrate's peak bandwidth
 
-Interpret-mode CPU numbers are tiny fractions of any roofline — that is
-expected and still useful as a *trend* (a regression that doubles bytes
-moved per launch shows up regardless of the substrate).  On compiled
-TPU/XLA runs the fraction becomes the real utilization figure.
+The peak is looked up by the ``device_kind`` the benchmark recorded
+(``PEAK_HBM_GBS``); a device without a published peak in the table is an
+error, so a CPU run never gets a roofline share.
 
 Usage:
-  PYTHONPATH=src python -m benchmarks.roofline_report BENCH_decode.json \
-      [--peak-gbs 819]
-
-``--peak-gbs`` sets the roofline (defaults to TPU v5e HBM, 819 GB/s; pass
-your host's STREAM number for CPU runs).
+  PYTHONPATH=src python -m benchmarks.roofline_report BENCH_decode.json
 """
 from __future__ import annotations
 
 import argparse
 import json
+
+#: published HBM bandwidth per ``jax.Device.device_kind``, GB/s.
+#: Source: Google Cloud documentation, "TPU v5e" (16 GB HBM2 at 819 GB/s
+#: per chip); JAX reports a v5e chip as "TPU v5 lite".
+PEAK_HBM_GBS = {
+    "TPU v5 lite": 819.0,
+    "TPU v5e": 819.0,
+}
+
+
+def peak_gbs(device_kind: str) -> float:
+    """Published HBM peak of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAK_HBM_GBS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published HBM peak for device kind {device_kind!r}; "
+            f"known: {sorted(PEAK_HBM_GBS)}") from None
 
 
 def kernel_rows(records):
@@ -86,12 +99,14 @@ def main():
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("bench_json", help="BENCH_decode.json from "
                    "benchmarks.backend_speed")
-    p.add_argument("--peak-gbs", type=float, default=819.0,
-                   help="roofline bandwidth in GB/s (default: TPU v5e HBM)")
     args = p.parse_args()
     with open(args.bench_json) as f:
         results = json.load(f)
-    print(render(results, args.peak_gbs))
+    kind = results.get("device", {}).get("kind")
+    if kind is None:
+        raise SystemExit(f"{args.bench_json} records no device kind; "
+                         "rerun benchmarks.backend_speed")
+    print(render(results, peak_gbs(kind)))
 
 
 if __name__ == "__main__":

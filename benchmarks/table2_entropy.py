@@ -5,7 +5,7 @@ from __future__ import annotations
 import numpy as np
 
 from .common import csv_row, datasets, timed
-from repro.core import interpolation, negabinary, quantize as Q
+from repro.core import interpolation, negabinary
 
 
 def _bit_entropy(bits: np.ndarray) -> float:
@@ -37,14 +37,8 @@ def run(scale=None):
     for name, x in list(datasets(scale).items())[:3]:
         eb = 1e-6 * float(x.max() - x.min())
 
-        def quantizer(res, tv):
-            q = Q.quantize(res, eb)
-            q[Q.escape_mask(q)] = 0
-            return q, Q.dequantize(q, eb), (np.zeros(0, np.int64),
-                                            np.zeros(0, np.float64))
-
         _, qs, _, _ = interpolation.decorrelate(
-            x.astype(np.float64), eb, interpolation.CUBIC, quantizer)
+            x.astype(np.float64), eb, interpolation.CUBIC)
         nb = negabinary.to_negabinary(np.concatenate(qs))
         ents = {p: _mean_plane_entropy(nb, p) for p in (0, 1, 2, 3)}
         rows.append(csv_row(

@@ -14,7 +14,7 @@ from repro.core import metrics
 
 def main():
     x = generate(TABLE3[0], scale=0.12)            # Density-like field
-    rng = float(x.max() - x.min())
+    rng = float(x.max()) - float(x.min())
 
     archive = Codec(eb=1e-6, relative=True).compress(x)
     print(f"field {x.shape}  raw {x.nbytes/1e6:.1f} MB  "

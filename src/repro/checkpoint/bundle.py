@@ -111,6 +111,11 @@ def encode_leaf(spec: LeafSpec, *, rel_eb: float, interp: str,
     from ..api import Codec  # deferred: keep the format importable early
     a2 = arr.reshape(arr.shape[0], -1) if arr.ndim > 2 else arr
     raw_len = a2.size * 4
+    # compressed as an (exact) float64 copy, i.e. under the float64
+    # arithmetic contract: checkpoint bounds reach the float32 ulp and
+    # below, where float32 arithmetic cannot meet them and would escape
+    # nearly every element; restore casts back to the leaf's dtype
+    a2 = a2.astype(np.float64)
     kind = "ipc"
     blob = Codec(eb=rel_eb, interp=interp, relative=True,
                  chunk_elems=chunk_elems, version=3).compress(a2).tobytes()

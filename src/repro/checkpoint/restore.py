@@ -231,7 +231,8 @@ class RestoreSession:
         ctx = self.policy.bind(chunked=True, encode=False)
         # plan every compressed leaf first (one ensure_prefix = one
         # contiguous range per plane-major leaf), then bucket chunk jobs
-        # ACROSS leaves by chunk shape so equal-shaped leaves share
+        # ACROSS leaves by chunk shape and arithmetic dtype (one batched
+        # sweep computes in one dtype) so equal-shaped leaves share
         # batched kernel launches; an ipc1 leaf is a single job keyed by
         # its own shape, so same-shape v1 leaves batch with each other
         # (and with same-shape v3 chunks — both are plain v1 sub-readers)
@@ -249,7 +250,8 @@ class RestoreSession:
                 else Fidelity.error_bound(bound)
             if e["kind"] == "ipc1":
                 keep = plan_retrieval(m, fid, self.propagation).keep_planes
-                key = tuple(m.shape) if self.group_leaves else (lid,)
+                key = (tuple(m.shape), m.work_dtype) \
+                    if self.group_leaves else (lid,)
                 buckets.setdefault(key, []).append(
                     (lid, None, reader, self._states[lid], keep))
                 continue
@@ -260,8 +262,9 @@ class RestoreSession:
             round_ts[lid] = t
             for ci in range(len(m.chunks)):
                 sub = reader.chunk_reader(ci)
-                key = tuple(sub.meta.shape) if self.group_leaves \
-                    else (lid, ci)
+                key = (tuple(sub.meta.shape),
+                       sub.meta.work_dtype) \
+                    if self.group_leaves else (lid, ci)
                 buckets.setdefault(key, []).append(
                     (lid, ci, sub, st.chunk_states[ci], keeps[ci]))
         cap = group_cap(ctx.mesh)

@@ -1,8 +1,11 @@
 """The paper's own experimental config: six SDRBench-like fields (Table 3).
 
 Offline container: synthetic seeded generators with the paper's shapes
-(scaled down by `scale` for CPU benchmarking; 1.0 = full shape).
+(scaled down by `scale` for CPU benchmarking; 1.0 = full shape).  The
+fields are float32, like the SDRBench originals, and repeat exactly across
+processes for a given (dataset, scale, seed).
 """
+import zlib
 from dataclasses import dataclass
 from typing import Tuple
 
@@ -29,9 +32,9 @@ ERROR_BOUNDS = [1e-6, 1e-9]     # relative (Fig. 5)
 
 
 def generate(ds: Dataset, scale: float = 0.25, seed: int = 0) -> np.ndarray:
-    """Seeded synthetic field with a domain-flavoured spectrum."""
+    """Seeded synthetic float32 field with a domain-flavoured spectrum."""
     shape = tuple(max(16, int(s * scale)) for s in ds.shape)
-    rng = np.random.default_rng(seed + hash(ds.name) % 1000)
+    rng = np.random.default_rng([seed, zlib.crc32(ds.name.encode())])
     grids = np.meshgrid(*[np.linspace(0, 2 * np.pi, s) for s in shape],
                         indexing="ij")
     x = np.zeros(shape)
@@ -46,4 +49,4 @@ def generate(ds: Dataset, scale: float = 0.25, seed: int = 0) -> np.ndarray:
             term = term * np.sin(m * g * rng.uniform(0.5, 1.5) + ph)
         x += amp * term
     x += noise * rng.standard_normal(shape)
-    return x
+    return x.astype(np.float32)

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from .. import interpolation, quantize
+from .. import arith, interpolation
 from . import common
 
 
@@ -23,19 +23,8 @@ class SZ3:
         x = np.asarray(x)
         L = interpolation.num_levels(x.shape)
 
-        def quantizer(res, tvals):
-            q = quantize.quantize(res, eb)
-            esc = quantize.escape_mask(q)
-            recon = quantize.dequantize(q, eb)
-            if esc.any():
-                flat = np.flatnonzero(esc.ravel())
-                vals = tvals.ravel()[flat].astype(np.float64)
-                q.ravel()[flat] = 0
-                return q, recon, (flat, vals)
-            return q, recon, (np.zeros(0, np.int64), np.zeros(0, np.float64))
-
         _, qs, escs, anchors = interpolation.decorrelate(
-            x.astype(np.float64), eb, self.interp, quantizer)
+            x.astype(np.float64), eb, self.interp)
         q_all = np.concatenate(qs) if qs else np.zeros(0, np.int64)
         lvl_sizes = [int(q.size) for q in qs]
         esc_idx, esc_val, base = [], [], 0
@@ -63,7 +52,8 @@ class SZ3:
         ev = np.frombuffer(secs[3], np.float64)
         yhat, overrides, off = [], [], 0
         for n in meta["lvl"]:
-            y = quantize.dequantize(q_all[off:off + n], meta["eb"])
+            y = arith.dequantize(q_all[off:off + n],
+                                 arith.consts(meta["eb"], np.float64))
             sel = (ei >= off) & (ei < off + n)
             overrides.append((ei[sel] - off, ev[sel]))
             yhat.append(y)

@@ -147,16 +147,31 @@ class ArchiveMeta:
     levels: List[LevelMeta]
     header_end: int
     total_size: int
+    #: largest finite |x| of the field — float32 archives only, where the
+    #: reader's rounding allowance (``loader.rounding_allowance``) needs it
+    vmax: Optional[float] = None
 
     @property
     def n_elements(self) -> int:
         return int(np.prod(self.shape))
 
+    @property
+    def work_dtype(self) -> np.dtype:
+        """Arithmetic the archive was written under (``docs/format.md``
+        §6): float32 for a float32 header that records ``vmax``, float64
+        for every other header — float64 fields, and float32 archives
+        written before the float32 contract existed."""
+        f32 = self.dtype == "float32" and self.vmax is not None
+        return np.dtype(np.float32 if f32 else np.float64)
+
 
 def write_archive(shape, dtype, eb, interp, L, anchors: np.ndarray,
                   level_blobs: List[List[bytes]], level_meta: List[Dict],
-                  esc_blobs: List[bytes]) -> bytes:
-    """Assemble the archive. level index 0 = level L (coarsest)."""
+                  esc_blobs: List[bytes], vmax: Optional[float] = None,
+                  ) -> bytes:
+    """Assemble the archive. level index 0 = level L (coarsest).  ``vmax``
+    (largest finite |x|) is recorded for float32 fields and required
+    there."""
     levels = []
     blobs: List[bytes] = []
     cursor = [0]  # patched after header length known
@@ -185,6 +200,8 @@ def write_archive(shape, dtype, eb, interp, L, anchors: np.ndarray,
                       interp=interp, L=int(L), anchors_offset=anc_off + base,
                       anchors_size=len(anc_bytes),
                       anchors_shape=list(anchors.shape), levels=abs_levels)
+        if vmax is not None:
+            header["vmax"] = float(vmax)
         hj = json.dumps(header, separators=(",", ":")).encode()
         return MAGIC + struct.pack("<I", len(hj)) + hj
 
@@ -210,9 +227,14 @@ def _assemble_v1_meta(h: dict, header_end: int, total: int,
                            anchors_offset=h["anchors_offset"],
                            anchors_size=h["anchors_size"],
                            anchors_shape=h["anchors_shape"], levels=levels,
-                           header_end=header_end, total_size=total)
+                           header_end=header_end, total_size=total,
+                           vmax=h.get("vmax"))
     except (KeyError, TypeError) as e:
         raise CorruptArchiveError(f"malformed {what} header: {e}") from e
+    if meta.vmax is not None and not isinstance(meta.vmax, (int, float)):
+        raise CorruptArchiveError(
+            f"malformed {what} header: 'vmax' must be a number, got "
+            f"{meta.vmax!r}")
     _check_extent(meta.anchors_offset, meta.anchors_size, total, "anchors")
     if meta.anchors_size != 8 * int(np.prod(meta.anchors_shape)):
         raise CorruptArchiveError(
@@ -633,6 +655,8 @@ def write_v3_archive(shape, dtype, eb, interp,
                 anchors_offset=rel["anchors", -1, -1] + base,
                 anchors_size=m.anchors_size,
                 anchors_shape=list(m.anchors_shape), levels=levels))
+            if m.vmax is not None:
+                chunk_headers[-1]["vmax"] = m.vmax
         header = dict(
             version=3, shape=list(shape), dtype=str(dtype), eb=float(eb),
             interp=interp,

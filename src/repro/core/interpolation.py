@@ -25,6 +25,8 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import arith
+
 LINEAR = "linear"
 CUBIC = "cubic"
 
@@ -96,28 +98,26 @@ def _bcast(mask: np.ndarray, axis: int, ndim: int) -> np.ndarray:
 
 
 def predict_block(view: np.ndarray, axis: int, idx: np.ndarray, s: int,
-                  n: int, interp: str) -> np.ndarray:
+                  n: int, interp: str, ftz: bool = False) -> np.ndarray:
     """Interpolate values at ``idx`` (odd multiples of s) along ``axis``.
 
     ``view`` holds the already-known values (previous level at 2s multiples).
-    Pure gather/arith — linear in the data, which Algorithm 2 (incremental
-    delta reconstruction) relies on.
+    Gathers the four neighbours and applies :func:`arith.predict` — the
+    formula the kernels run too; ``ftz`` selects the float32 contract's
+    subnormal flushing.  Linear in the data up to rounding, which
+    Algorithm 2 (incremental delta reconstruction) relies on.
     """
     nd = view.ndim
+    r_ok = _bcast(idx + s <= n - 1, axis, nd)
+    cubic_ok = _bcast((idx - 3 * s >= 0) & (idx + 3 * s <= n - 1), axis, nd) \
+        & r_ok
     l1 = np.take(view, idx - s, axis=axis)
-    r_ok = idx + s <= n - 1
     r1 = np.take(view, np.minimum(idx + s, n - 1), axis=axis)
-    lin = 0.5 * (l1 + r1)
-    if interp == LINEAR:
-        return np.where(_bcast(r_ok, axis, nd), lin, l1)
-    ll_ok = idx - 3 * s >= 0
-    rr_ok = idx + 3 * s <= n - 1
-    l3 = np.take(view, np.maximum(idx - 3 * s, 0), axis=axis)
-    r3 = np.take(view, np.minimum(idx + 3 * s, n - 1), axis=axis)
-    cub = (-l3 + 9.0 * l1 + 9.0 * r1 - r3) / 16.0
-    pred = np.where(_bcast(ll_ok & rr_ok & r_ok, axis, nd), cub,
-                    np.where(_bcast(r_ok, axis, nd), lin, l1))
-    return pred
+    l3 = r3 = None
+    if interp != LINEAR:
+        l3 = np.take(view, np.maximum(idx - 3 * s, 0), axis=axis)
+        r3 = np.take(view, np.minimum(idx + 3 * s, n - 1), axis=axis)
+    return arith.predict(np, l3, l1, r1, r3, cubic_ok, r_ok, interp, ftz)
 
 
 def _assign(view: np.ndarray, axis: int, idx: np.ndarray, vals: np.ndarray) -> None:
@@ -125,132 +125,136 @@ def _assign(view: np.ndarray, axis: int, idx: np.ndarray, vals: np.ndarray) -> N
 
 
 def decorrelate(x: np.ndarray, eb: float, interp: str,
-                quantizer: Callable[[np.ndarray, np.ndarray], Tuple],
+                phase_fn: Optional[Callable] = None,
                 ) -> Tuple[np.ndarray, List[np.ndarray], List[List[Tuple]], np.ndarray]:
-    """Compression-side sweep.
-
-    ``quantizer(residual, tvals) -> (q, recon_residual, (esc_idx, esc_vals))``
-    returns int64 bins, the dequantized residual, and escape records holding
-    the block-local flat indices and *absolute original values* of points the
-    quantizer cannot represent.  Escapes are applied as exact overwrites —
-    storing residuals would lose the value to catastrophic cancellation when
-    |pred| >> |x|.
+    """Compression-side sweep of one array (a batch of one, see
+    :func:`decorrelate_batch`).
 
     Returns (xhat, per-level q arrays [index 0 = level L], per-level escape
     records with level-global indices, anchors).
     """
-    shape = x.shape
-    L = num_levels(shape)
-    xhat = np.zeros_like(x, dtype=np.float64)
-    anc = anchor_slices(shape, L)
-    anchors = np.array(x[anc], np.float64, copy=True)
-    xhat[anc] = anchors  # P_L(0) replaced by exact anchors (lossless channel)
+    return decorrelate_batch(np.asarray(x)[None], eb, interp, phase_fn)[0]
 
-    qs: List[List[np.ndarray]] = [[] for _ in range(L)]
-    escs: List[List[Tuple]] = [[] for _ in range(L)]
+
+def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
+                      phase_fn: Optional[Callable] = None) -> List[Tuple]:
+    """Compression-side sweep over B stacked equal-shape arrays.
+
+    Computes in the field's working dtype (``arith.work_dtype``: float32
+    stays float32, everything else float64).  Per (level, dim) phase: the
+    prediction and bins come from ``phase_fn(xv, hv, ph, c)`` — the backend
+    seam, given the batched data and reconstruction views, the Phase and
+    the quantizer constants, returning ``(q, pred)`` over the target block
+    in original axis order — or, when None, from the numpy reference.  The
+    escape screen (:func:`arith.screen`), the writeback and the stream
+    bookkeeping stay here, shared by every backend.
+
+    Escape records hold block-local flat indices and *absolute original
+    values*: escapes are exact overwrites — storing residuals would lose
+    the value to catastrophic cancellation when |pred| >> |x|.
+
+    Returns B ``(xhat, qs, escs, anchors)`` tuples, bit-identical to B
+    single-array sweeps (every operation is elementwise across the batch).
+    """
+    c = arith.consts(eb, xs.dtype)
+    xs = np.asarray(xs, c.dtype)
+    B, shape = xs.shape[0], xs.shape[1:]
+    L = num_levels(shape)
+    xhat = np.zeros(xs.shape, c.dtype)
+    anc = (slice(None),) + anchor_slices(shape, L)
+    anchors = np.array(xs[anc], np.float64)
+    xhat[anc] = xs[anc]  # P_L(0) replaced by exact anchors (lossless channel)
+
+    qs: List[List[List[np.ndarray]]] = [[[] for _ in range(L)] for _ in range(B)]
+    escs: List[List[List[Tuple]]] = [[[] for _ in range(L)] for _ in range(B)]
     offsets = [0] * L
     for ph in iter_phases(shape, L):
-        xv = x[ph.view]
-        hv = xhat[ph.view]
-        pred = predict_block(hv, ph.dim, ph.targets, ph.stride, ph.n_dim, interp)
-        tvals = np.take(xv, ph.targets, axis=ph.dim).astype(np.float64)
-        q, recon_res, esc = quantizer(tvals - pred, tvals)
-        flat, vals = esc
-        block = pred + recon_res
-        if flat.size:
-            block.reshape(-1)[flat] = vals  # exact overwrite, no cancellation
-        _assign(hv, ph.dim, ph.targets, block)
+        ax = ph.dim + 1
+        xv = xs[(slice(None),) + ph.view]
+        hv = xhat[(slice(None),) + ph.view]
+        tvals = np.take(xv, ph.targets, axis=ax)
+        if phase_fn is None:
+            pred = predict_block(hv, ax, ph.targets, ph.stride, ph.n_dim,
+                                 interp, c.f32)
+            q = arith.bins(np, tvals, pred, c, np.int64)
+        else:
+            q, pred = phase_fn(xv, hv, ph, c)
+        q, block, esc = arith.screen(tvals, pred, q, c)
+        _assign(hv, ax, ph.targets, block)
         li = L - ph.level
-        qs[li].append(q.ravel())
-        escs[li].append((flat + offsets[li], vals))  # level-global indices
-        offsets[li] += q.size
-    return xhat, [np.concatenate(v) if v else np.zeros(0, np.int64) for v in qs], escs, anchors
+        for b in range(B):
+            flat = np.flatnonzero(esc[b].ravel())
+            qs[b][li].append(q[b].ravel())
+            escs[b][li].append((flat + offsets[li],
+                                tvals[b].ravel()[flat].astype(np.float64)))
+        offsets[li] += ph.count
+    return [(xhat[b],
+             [np.concatenate(v) if v else np.zeros(0, np.int64)
+              for v in qs[b]],
+             escs[b], anchors[b]) for b in range(B)]
 
 
 def reconstruct(shape: Sequence[int], interp: str, anchors: np.ndarray,
                 yhat_per_level: List[np.ndarray],
                 overrides: Optional[List[Tuple[np.ndarray, np.ndarray]]] = None,
                 out_dtype=np.float64, block_fn: Optional[Callable] = None,
-                ) -> np.ndarray:
-    """Decompression-side sweep (Algorithm 1 core).
-
-    ``yhat_per_level[i]`` is the dequantized residual stream for level L-i.
-    ``overrides[i]`` = (stream_idx, values): positions whose output is set to
-    ``values`` exactly instead of pred+res (the lossless escape channel; for
-    Algorithm 2's delta cascade the values are zeros, since escaped points
-    never change across refinements).  Aside from overrides, purely linear in
-    (anchors, yhat): the same routine reconstructs incremental deltas by
-    feeding zero anchors and residual *differences*.
-
-    ``block_fn(hv, ph, res)`` is the backend seam: given the phase view, the
-    Phase, and the flat residual slice, return the reconstructed target
-    block (pred + res) in original axis order as a writable C-order array.
-    None = the numpy reference (predict_block).  Traversal, per-level offset
-    accounting, and the override writeback stay here — shared by every
-    backend — so the semantics cannot drift between substrates.
-    """
-    L = num_levels(shape)
-    xhat = np.zeros(shape, np.float64)
-    xhat[anchor_slices(shape, L)] = anchors
-    offs = [0] * L
-    for ph in iter_phases(shape, L):
-        hv = xhat[ph.view]
-        li = L - ph.level
-        lo = offs[li]
-        res = yhat_per_level[li][lo: lo + ph.count]
-        offs[li] += ph.count
-        if block_fn is None:
-            pred = predict_block(hv, ph.dim, ph.targets, ph.stride,
-                                 ph.n_dim, interp)
-            tgt_shape = list(hv.shape)
-            tgt_shape[ph.dim] = ph.targets.size
-            block = pred + res.reshape(tgt_shape)
-        else:
-            block = block_fn(hv, ph, res)
-        if overrides is not None:
-            oidx, ovals = overrides[li]
-            if oidx.size:
-                sel = (oidx >= lo) & (oidx < lo + ph.count)
-                if sel.any():
-                    block.reshape(-1)[oidx[sel] - lo] = ovals[sel]
-        _assign(hv, ph.dim, ph.targets, block)
-    return xhat.astype(out_dtype)
+                dtype=np.float64) -> np.ndarray:
+    """Decompression-side sweep (Algorithm 1 core) of one array — a batch
+    of one, see :func:`reconstruct_batch`."""
+    anchors = np.asarray(anchors)[None]
+    yhat = [np.asarray(y)[None] for y in yhat_per_level]
+    ovr = None if overrides is None else [overrides]
+    return reconstruct_batch(shape, interp, anchors, yhat, overrides=ovr,
+                             out_dtype=out_dtype, block_fn=block_fn,
+                             dtype=dtype)[0]
 
 
 def reconstruct_batch(shape: Sequence[int], interp: str, anchors: np.ndarray,
                       yhat_per_level: List[np.ndarray],
                       overrides: Optional[List[List[Tuple[np.ndarray, np.ndarray]]]] = None,
                       out_dtype=np.float64, block_fn: Optional[Callable] = None,
-                      ) -> np.ndarray:
-    """Batched :func:`reconstruct` over B equal-``shape`` items.
+                      dtype=np.float64) -> np.ndarray:
+    """Decompression-side sweep over B equal-``shape`` items.
 
-    ``anchors`` is (B, *anchors_shape), ``yhat_per_level[i]`` is (B, n_i),
-    ``overrides[b][i]`` the per-item escape records, and the result is
-    (B, *shape).  The traversal is the single-item one with a leading batch
-    axis: every phase processes the whole stack at once (the unit of the
-    vmapped chunk engine), while override writebacks stay per item.  The
-    default (numpy) block path is element-for-element the same arithmetic
-    as B independent :func:`reconstruct` calls, so results are
-    bit-identical to the loop; batched backends plug in via ``block_fn(hv,
-    ph, res)`` with ``hv`` the batched view and ``res`` (B, count).
+    ``anchors`` is (B, *anchors_shape), ``yhat_per_level[i]`` the (B, n_i)
+    dequantized residual streams of level L-i, ``overrides[b][i]`` =
+    (stream_idx, values) the per-item positions whose output is set to
+    ``values`` exactly instead of pred+res (the lossless escape channel;
+    for Algorithm 2's delta cascade the values are zeros, since escaped
+    points never change across refinements).  ``dtype`` is the working
+    dtype of the arithmetic contract (``arith.work_dtype`` of the field).
+    Aside from overrides and rounding, linear in (anchors, yhat): the same
+    routine reconstructs incremental deltas by feeding zero anchors and
+    residual *differences*.
+
+    ``block_fn(hv, ph, res)`` is the backend seam: given the batched phase
+    view, the Phase, and the (B, count) residual slice, return the
+    reconstructed target block (pred + res) in original axis order as a
+    writable C-order array.  None = the numpy reference.  Traversal,
+    per-level offset accounting, and the override writeback stay here —
+    shared by every backend — so the semantics cannot drift between
+    substrates.  Every operation is elementwise across the batch, so
+    results are bit-identical to B single-item sweeps.
     """
+    dtype = np.dtype(dtype)
+    ftz = dtype == np.float32
     B = anchors.shape[0]
     L = num_levels(shape)
-    xhat = np.zeros((B,) + tuple(shape), np.float64)
+    xhat = np.zeros((B,) + tuple(shape), dtype)
     xhat[(slice(None),) + anchor_slices(shape, L)] = anchors
     offs = [0] * L
     for ph in iter_phases(shape, L):
         hv = xhat[(slice(None),) + ph.view]
         li = L - ph.level
         lo = offs[li]
-        res = yhat_per_level[li][:, lo: lo + ph.count]
+        res = np.asarray(yhat_per_level[li][:, lo: lo + ph.count], dtype)
         offs[li] += ph.count
         if block_fn is None:
             pred = predict_block(hv, ph.dim + 1, ph.targets, ph.stride,
-                                 ph.n_dim, interp)
+                                 ph.n_dim, interp, ftz)
             tgt_shape = list(hv.shape)
             tgt_shape[ph.dim + 1] = ph.targets.size
-            block = pred + res.reshape(tgt_shape)
+            block = arith.recon(np, pred, res.reshape(tgt_shape), ftz)
         else:
             block = block_fn(hv, ph, res)
         if overrides is not None:
@@ -261,4 +265,4 @@ def reconstruct_batch(shape: Sequence[int], interp: str, anchors: np.ndarray,
                     if sel.any():
                         block[b].reshape(-1)[oidx[sel] - lo] = ovals[sel]
         _assign(hv, ph.dim + 1, ph.targets, block)
-    return xhat.astype(out_dtype)
+    return xhat.astype(out_dtype, copy=False)

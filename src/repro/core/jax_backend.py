@@ -27,19 +27,22 @@ Backend selection (see ``pipeline.backends``):
     Pallas interpreter, so "auto" keeps the numpy reference everywhere
     else rather than silently emulating.
 
-Both backends emit byte-identical archives: the kernel quantizer divides by
-2*eb with the same f64 rounding as the numpy oracle (x64 is enabled for the
-duration of the sweep), and the packed plane words are truncated to the
-exact ``np.packbits`` byte stream (``bitplane.blobs_from_packed``).  The
-decode path (``retrieve``/``refine``) is backend-agnostic, so archives
-produced here are readable anywhere numpy runs.
+Both backends emit byte-identical archives and bit-identical
+reconstructions because both run one arithmetic contract (``core.arith``):
+a float32 field computes in float32 — the only float the chip has — and
+any other field in float64, which this backend runs only on the CPU (under
+the Pallas interpreter, with x64 enabled for the call); on a TPU a float64
+field raises and names the numpy backend.  The packed plane words are
+truncated to the exact ``np.packbits`` byte stream
+(``bitplane.blobs_from_packed``), and archives produced here are readable
+anywhere numpy runs.
 
-Escape handling stays on the host: the kernel returns (q, pred), so the
-full-precision requantization that flags outliers beyond ``quantize.QMAX``
-(where the kernel's int32 bins wrap or saturate) is one vectorized numpy
-pass over the phase — no second prediction sweep.  The writeback
-``pred + 2*eb*q`` is also done host-side in numpy: it is the archive's
-canonical rounding, shared verbatim with the numpy backend.
+The traversal, the escape screen and the writeback are shared with the
+numpy reference (``interpolation.decorrelate_batch`` /
+``reconstruct_batch``); this module only supplies the per-phase kernel
+seam.  The kernel returns (q, pred) and the host screen recomputes each
+element's reconstruction exactly as the decoder will, escaping every
+element that misses the bound.
 
 Every primitive also has a ``*_batch`` twin over stacks of equal-shaped
 chunk problems (the unit the v2 shape-group scheduler feeds): the stack
@@ -55,11 +58,12 @@ free, and still bit-identical (``compress``/``retrieve``/``refine``/
 """
 from __future__ import annotations
 
+import contextlib
 from typing import List, Tuple
 
 import numpy as np
 
-from . import bitplane, interpolation, quantize
+from . import arith, bitplane, interpolation
 
 NUMPY = "numpy"
 JAX = "jax"
@@ -80,75 +84,41 @@ def resolve(backend) -> str:
     return backends.resolve_name(backend)
 
 
+def _x64(dtype):
+    """Context for one call in ``dtype``'s arithmetic: float64 enables
+    x64 for its duration (CPU only); float32 needs nothing."""
+    if np.dtype(dtype) != np.float64:
+        return contextlib.nullcontext()
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ValueError("float64 fields compute in float64, which the TPU "
+                         "does not have: use backend='numpy' for them (or "
+                         "compress a float32 field)")
+    return jax.enable_x64(True)
+
+
+def _to_lanes(v: np.ndarray, ax: int) -> Tuple[np.ndarray, tuple]:
+    """(B, ...) phase view -> (B, R, C) with the sweep axis on lanes, plus
+    the lead shape to undo it."""
+    m = np.moveaxis(v, ax, -1)
+    lead = m.shape[1:-1]
+    return m.reshape((m.shape[0], int(np.prod(lead)), m.shape[-1])), lead
+
+
+def _from_lanes(a, lead: tuple, ax: int) -> np.ndarray:
+    """Inverse of :func:`_to_lanes` for a (B, R, T) kernel output."""
+    a = np.asarray(a)
+    return np.moveaxis(a.reshape((a.shape[0],) + lead + (a.shape[-1],)),
+                       -1, ax)
+
+
 def decorrelate(x: np.ndarray, eb: float, interp: str,
                 interpret: bool | None = None,
                 ) -> Tuple[np.ndarray, List[np.ndarray], List[List[Tuple]], np.ndarray]:
-    """Kernel-backed twin of ``interpolation.decorrelate``.
-
-    Same traversal, same return contract: (xhat, per-level q streams,
-    per-level escape records with level-global indices, anchors).  Each
-    (level, dim) phase moves the sweep axis onto lanes, runs the fused
-    predict+quantize kernel, and writes the reconstruction back into
-    ``xhat`` so later levels predict from the lossy surface — bit-exact
-    with the numpy sweep.
-    """
-    import jax
-
-    from ..kernels.interp_quant import interp_quant
-
-    shape = x.shape
-    L = interpolation.num_levels(shape)
-    xhat = np.zeros_like(x, dtype=np.float64)
-    anc = interpolation.anchor_slices(shape, L)
-    anchors = np.array(x[anc], np.float64, copy=True)
-    xhat[anc] = anchors
-
-    qs: List[List[np.ndarray]] = [[] for _ in range(L)]
-    escs: List[List[Tuple]] = [[] for _ in range(L)]
-    offsets = [0] * L
-    with jax.experimental.enable_x64():
-        for ph in interpolation.iter_phases(shape, L):
-            xv = x[ph.view]
-            hv = xhat[ph.view]
-            xm = np.ascontiguousarray(np.moveaxis(xv, ph.dim, -1))
-            hm = np.ascontiguousarray(np.moveaxis(hv, ph.dim, -1))
-            lead, C = xm.shape[:-1], xm.shape[-1]
-            R = int(np.prod(lead)) if lead else 1
-            q2, pred2 = interp_quant(xm.reshape(R, C), hm.reshape(R, C),
-                                     s=ph.stride, eb=eb, interp=interp,
-                                     interpret=interpret)
-            T = q2.shape[1]
-            # order='C' copies: device buffers arrive read-only, and ravel()
-            # on an order-'K' copy of the moveaxis view would NOT alias the
-            # data (escape zeroing below must write through)
-            q = np.array(np.moveaxis(
-                np.asarray(q2).reshape(lead + (T,)), -1, ph.dim),
-                np.int64, order="C")
-            pred = np.array(np.moveaxis(
-                np.asarray(pred2, np.float64).reshape(lead + (T,)), -1,
-                ph.dim), order="C")
-            tvals = np.take(xv, ph.targets, axis=ph.dim).astype(np.float64)
-            # canonical numpy writeback + full-precision escape requantize
-            # (the kernel's int32 bins wrap/saturate past QMAX)
-            block = pred + quantize.dequantize(q, eb)
-            qf = quantize.quantize(tvals - pred, eb)
-            esc = quantize.escape_mask(qf)
-            if esc.any():
-                flat = np.flatnonzero(esc.ravel())
-                vals = tvals.ravel()[flat]
-                q[esc] = 0
-                block[esc] = vals  # exact overwrite, no cancellation
-            else:
-                flat = np.zeros(0, np.int64)
-                vals = np.zeros(0, np.float64)
-            interpolation._assign(hv, ph.dim, ph.targets, block)
-            li = L - ph.level
-            qs[li].append(q.ravel())
-            escs[li].append((flat + offsets[li], vals))
-            offsets[li] += q.size
-    return (xhat,
-            [np.concatenate(v) if v else np.zeros(0, np.int64) for v in qs],
-            escs, anchors)
+    """Kernel-backed twin of ``interpolation.decorrelate`` (one array)."""
+    return decorrelate_batch(np.asarray(x)[None], eb, interp,
+                             interpret=interpret)[0]
 
 
 def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
@@ -156,86 +126,30 @@ def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
                       mesh=None) -> List[Tuple]:
     """Batched twin of :func:`decorrelate` over stacked equal-shape chunks.
 
-    ``xs`` is (B, *chunk_shape); returns a list of B per-chunk
-    ``(xhat, qs, escs, anchors)`` tuples whose contents are bit-identical
-    to B independent :func:`decorrelate` calls — the batch axis is purely
-    an execution detail.  Every (level, dim) phase costs ONE vmapped
-    kernel dispatch for the whole stack instead of B (the launch-count
-    bottleneck cuSZ-i identifies for multi-level interpolation on GPUs);
-    the host-side escape requantization runs vectorized over the batch,
-    with per-chunk record extraction only.
+    ``xs`` is (B, *chunk_shape); returns B per-chunk ``(xhat, qs, escs,
+    anchors)`` tuples, bit-identical to the numpy reference.  Every
+    (level, dim) phase moves the sweep axis onto lanes and costs ONE
+    vmapped ``interp_quant`` dispatch for the whole stack; the traversal
+    and the escape screen are ``interpolation.decorrelate_batch``'s.
 
     With ``mesh`` (a 1-D codec mesh), each phase dispatch is additionally
     ``shard_map``-ed: the stack axis is split across the mesh devices and
     every device runs the vmapped kernel on its local chunks
-    (:func:`decorrelate_sharded` is the registry-facing alias).  Outputs
-    stay bit-identical — sharding, like batching, is an execution detail.
+    (:func:`decorrelate_sharded` is the registry-facing alias).
     """
-    import jax
+    from ..kernels.interp_quant import interp_quant_batch
 
-    from ..kernels.interp_quant import (interp_quant_batch,
-                                        interp_quant_sharded)
+    def phase_fn(xv, hv, ph, c):
+        ax = ph.dim + 1
+        xm, lead = _to_lanes(xv, ax)
+        hm, _ = _to_lanes(hv, ax)
+        q, pred = interp_quant_batch(xm, hm, s=ph.stride, eb=eb,
+                                     interp=interp, interpret=interpret,
+                                     mesh=mesh)
+        return _from_lanes(q, lead, ax), _from_lanes(pred, lead, ax)
 
-    def phase_sweep(xm, hm, s):
-        if mesh is not None:
-            return interp_quant_sharded(xm, hm, s=s, eb=eb, interp=interp,
-                                        mesh=mesh, interpret=interpret)
-        return interp_quant_batch(xm, hm, s=s, eb=eb, interp=interp,
-                                  interpret=interpret)
-
-    B = xs.shape[0]
-    shape = xs.shape[1:]
-    L = interpolation.num_levels(shape)
-    xhat = np.zeros_like(xs, dtype=np.float64)
-    anc = (slice(None),) + interpolation.anchor_slices(shape, L)
-    anchors = np.array(xs[anc], np.float64, copy=True)
-    xhat[anc] = anchors
-
-    qs: List[List[List[np.ndarray]]] = [[[] for _ in range(L)] for _ in range(B)]
-    escs: List[List[List[Tuple]]] = [[[] for _ in range(L)] for _ in range(B)]
-    offsets = [0] * L
-    with jax.experimental.enable_x64():
-        for ph in interpolation.iter_phases(shape, L):
-            ax = ph.dim + 1  # phase axis shifted by the leading batch axis
-            xv = xs[(slice(None),) + ph.view]
-            hv = xhat[(slice(None),) + ph.view]
-            xm = np.ascontiguousarray(np.moveaxis(xv, ax, -1))
-            hm = np.ascontiguousarray(np.moveaxis(hv, ax, -1))
-            lead, C = xm.shape[1:-1], xm.shape[-1]
-            R = int(np.prod(lead)) if lead else 1
-            q3, pred3 = phase_sweep(xm.reshape(B, R, C),
-                                    hm.reshape(B, R, C), ph.stride)
-            T = q3.shape[-1]
-            # order='C' copies: see decorrelate() — escape zeroing below
-            # must write through, device buffers arrive read-only
-            q = np.array(np.moveaxis(
-                np.asarray(q3).reshape((B,) + lead + (T,)), -1, ax),
-                np.int64, order="C")
-            pred = np.array(np.moveaxis(
-                np.asarray(pred3, np.float64).reshape((B,) + lead + (T,)),
-                -1, ax), order="C")
-            tvals = np.take(xv, ph.targets, axis=ax).astype(np.float64)
-            block = pred + quantize.dequantize(q, eb)
-            qf = quantize.quantize(tvals - pred, eb)
-            esc = quantize.escape_mask(qf)
-            li = L - ph.level
-            for b in range(B):
-                if esc[b].any():
-                    flat = np.flatnonzero(esc[b].ravel())
-                    vals = tvals[b].ravel()[flat]
-                    q[b][esc[b]] = 0
-                    block[b][esc[b]] = vals  # exact overwrite, no cancellation
-                else:
-                    flat = np.zeros(0, np.int64)
-                    vals = np.zeros(0, np.float64)
-                qs[b][li].append(q[b].ravel())
-                escs[b][li].append((flat + offsets[li], vals))
-            interpolation._assign(hv, ax, ph.targets, block)
-            offsets[li] += int(q[0].size)
-    return [(xhat[b],
-             [np.concatenate(v) if v else np.zeros(0, np.int64)
-              for v in qs[b]],
-             escs[b], anchors[b]) for b in range(B)]
+    with _x64(arith.work_dtype(xs.dtype)):
+        return interpolation.decorrelate_batch(xs, eb, interp, phase_fn)
 
 
 def decorrelate_sharded(xs: np.ndarray, eb: float, interp: str, mesh,
@@ -436,38 +350,33 @@ def decode_level_sharded(blob_lists, nbits: int, n: int, mesh,
 
 def decode_level_fused(blobs, nbits: int, n: int, nb_old: np.ndarray,
                        eb: float, interpret: bool | None = None,
-                       words=None) -> Tuple[np.ndarray, np.ndarray]:
+                       words=None, dtype=np.float64,
+                       ) -> Tuple[np.ndarray, np.ndarray]:
     """Fused progressive decode of one level: ONE kernel launch replaces
-    ``decode_level`` plus the three host passes of the delta cascade.
+    ``decode_level`` plus the host dequantization.
 
-    ``nb_old`` is the session's current truncated negabinary stream for
-    the level; returns ``(nb_new, delta)`` where ``delta`` is the
-    dequantized residual increment ``(bin_new - bin_old) * 2 * eb``,
-    bit-identical to the unfused host arithmetic.  ``words`` optionally
-    carries a pre-inflated ``(words, want)`` pair from
-    :func:`inflate_level` (the two-slot prefetch hands the worker thread's
-    result through here).
+    Returns ``(nb_new, out)``.  ``dtype`` is the field's working dtype:
+    float64 gives Algorithm 2's residual delta against ``nb_old``,
+    ``(bin_new - bin_old) * 2 * eb``; float32 gives the full float32
+    residual of the new truncation (``nb_old`` unused, may be None).  Both are
+    bit-identical to the host arithmetic.  ``words`` optionally carries a
+    pre-inflated ``(words, want)`` pair from :func:`inflate_level` (the
+    two-slot prefetch hands the worker thread's result through here).
     """
-    from ..kernels.decode_fused import decode_fused
-
-    if words is None:
-        words = inflate_level(blobs, nbits, n)
-    wgrid, want = words
-    if nbits == 0 or n == 0 or want == 0:
-        return np.asarray(nb_old, np.uint32), np.zeros(n, np.float64)
-    nb_new, delta = decode_fused(wgrid, np.asarray(nb_old, np.uint32), n,
-                                 eb=eb, low_zero=nbits - want,
-                                 interpret=interpret)
-    return np.asarray(nb_new, np.uint32), np.asarray(delta, np.float64)
+    out = decode_level_fused_batch([blobs], nbits, n, [nb_old], [eb],
+                                   interpret=interpret, dtype=dtype,
+                                   words=None if words is None
+                                   else (words[0][None], [words[1]]))
+    return out[0]
 
 
 def decode_level_fused_batch(blob_lists, nbits: int, n: int, nb_olds,
                              ebs, interpret: bool | None = None,
-                             mesh=None, words=None,
+                             mesh=None, words=None, dtype=np.float64,
                              ) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Batched twin of :func:`decode_level_fused` for equal-``nbits``
     groups with per-chunk prefixes AND per-chunk error bounds (both are
-    runtime kernel operands).  Returns B ``(nb_new, delta)`` pairs from
+    runtime kernel operands).  Returns B ``(nb_new, out)`` pairs from
     one vmapped launch; with ``mesh``, the stack is split over the 1-D
     codec mesh.  ``words`` optionally carries the prefetched
     ``(word stack, wants)`` from :func:`inflate_level_batch`.
@@ -478,305 +387,91 @@ def decode_level_fused_batch(blob_lists, nbits: int, n: int, nb_olds,
     if words is None:
         words = inflate_level_batch(blob_lists, nbits, n)
     wstack, wants = words
-    olds = np.stack([np.asarray(o, np.uint32) for o in nb_olds])
+    olds = np.stack([np.zeros(n, np.uint32) if o is None
+                     else np.asarray(o, np.uint32) for o in nb_olds])
     eb_list = list(ebs) if np.ndim(ebs) else [float(ebs)] * B
+    zero = np.zeros(n, dtype)
     if nbits == 0 or n == 0 or all(w == 0 for w in wants):
-        return [(olds[b], np.zeros(n, np.float64)) for b in range(B)]
+        return [(olds[b], zero) for b in range(B)]
     lz = [nbits - w if w else 31 for w in wants]
-    nb_new, delta = decode_fused_batch(wstack, olds, n, eb=eb_list,
-                                       low_zero=lz, interpret=interpret,
-                                       mesh=mesh)
-    nb_new = np.asarray(nb_new, np.uint32)
-    delta = np.asarray(delta, np.float64)
-    out = []
-    for b in range(B):
-        if wants[b] == 0:  # nothing loaded: state and delta are untouched
-            out.append((olds[b], np.zeros(n, np.float64)))
-        else:
-            out.append((nb_new[b], delta[b]))
-    return out
+    with _x64(dtype):
+        nb_new, out = decode_fused_batch(
+            wstack, None if np.dtype(dtype) == np.float32 else olds, n,
+            eb=eb_list, low_zero=lz, interpret=interpret, mesh=mesh,
+            dtype=dtype)
+        nb_new = np.asarray(nb_new, np.uint32)
+        out = np.asarray(out, dtype)
+    # nothing loaded: state and contribution are untouched
+    return [(olds[b], zero) if wants[b] == 0 else (nb_new[b], out[b])
+            for b in range(B)]
 
 
 def decode_level_fused_sharded(blob_lists, nbits: int, n: int, nb_olds,
                                ebs, mesh, interpret: bool | None = None,
-                               words=None,
+                               words=None, dtype=np.float64,
                                ) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Sharded fused decode: :func:`decode_level_fused_batch` over a mesh."""
     return decode_level_fused_batch(blob_lists, nbits, n, nb_olds, ebs,
                                     interpret=interpret, mesh=mesh,
-                                    words=words)
-
-
-def _dense_override(oidx, ovals, lo: int, cnt: int, block_shape):
-    """Level-global escape records -> a dense (mask, values) pair for one
-    phase block, or None when the block has no escapes.  The fused level
-    kernel applies ``mask != 0 -> value`` inside the launch — same
-    semantics as the host writeback ``block.reshape(-1)[idx] = vals``."""
-    sel = (oidx >= lo) & (oidx < lo + cnt)
-    if not sel.any():
-        return None
-    m = np.zeros(cnt, np.int32)
-    v = np.zeros(cnt, np.float64)
-    m[oidx[sel] - lo] = 1
-    v[oidx[sel] - lo] = ovals[sel]
-    return m.reshape(block_shape), v.reshape(block_shape)
-
-
-def _level_blocks(shape, s: int):
-    """Static geometry of one 2-D level on its stride-s subgrid.
-
-    Returns (Ms, Ns, T0, T1, Nse): subgrid extents, phase target counts
-    (T0 odd rows, T1 odd columns) and the even-column count Nse.  The
-    phase residual blocks are (T0, Nse) and (Ms, T1) in stream C-order —
-    consecutive in the level stream, phase 0 first, matching
-    ``interpolation.iter_phases`` exactly (empty target sets drop the
-    phase there; here the count is simply 0).
-    """
-    M, N = shape
-    Ms = (M - 1) // s + 1
-    Ns = (N - 1) // s + 1
-    return Ms, Ns, Ms // 2, Ns // 2, -(-Ns // 2)
+                                    words=words, dtype=dtype)
 
 
 def reconstruct(shape, interp: str, anchors: np.ndarray,
                 yhat_per_level: List[np.ndarray],
                 overrides=None, out_dtype=np.float64,
-                interpret: bool | None = None) -> np.ndarray:
-    """Kernel-backed twin of ``interpolation.reconstruct`` (Algorithm 1).
-
-    For 2-D data the traversal is fused per LEVEL: both (level, dim) phase
-    sweeps plus the escape overrides of the level run as one
-    ``interp_recon_level`` launch on the level's stride-s subgrid
-    (``xhat[::s, ::s]`` — level-s traversal touches only s-multiples, and
-    on the subgrid the stride becomes 1 with identical boundary masks, so
-    bits cannot change).  L launches total instead of 2L plus host
-    override scatters.  Other ranks fall back to the per-phase sweep
-    (:func:`reconstruct_unfused`).
-    """
-    if len(shape) != 2:
-        return reconstruct_unfused(shape, interp, anchors, yhat_per_level,
-                                   overrides=overrides, out_dtype=out_dtype,
-                                   interpret=interpret)
-    import jax
-
-    from ..kernels.interp_recon import interp_recon_level
-
-    L = interpolation.num_levels(shape)
-    xhat = np.zeros(shape, np.float64)
-    xhat[interpolation.anchor_slices(shape, L)] = anchors
-    with jax.experimental.enable_x64():
-        for level in range(L, 0, -1):
-            s = 1 << (level - 1)
-            li = L - level
-            Ms, Ns, T0, T1, Nse = _level_blocks(shape, s)
-            if T0 == 0 and T1 == 0:
-                continue
-            stream = np.asarray(yhat_per_level[li], np.float64)
-            oidx, ovals = overrides[li] if overrides is not None else \
-                (np.zeros(0, np.int64), np.zeros(0, np.float64))
-            res0 = res1 = ov0 = ov1 = None
-            lo = 0
-            if T0 > 0:
-                cnt0 = T0 * Nse
-                res0 = stream[lo:lo + cnt0].reshape(T0, Nse)
-                ov0 = _dense_override(oidx, ovals, lo, cnt0, (T0, Nse))
-                lo += cnt0
-            if T1 > 0:
-                cnt1 = Ms * T1
-                res1 = stream[lo:lo + cnt1].reshape(Ms, T1)
-                ov1 = _dense_override(oidx, ovals, lo, cnt1, (Ms, T1))
-                lo += cnt1
-            g = np.ascontiguousarray(xhat[::s, ::s])
-            out = interp_recon_level(g, res0, res1, interp=interp, ov0=ov0,
-                                     ov1=ov1, interpret=interpret)
-            xhat[::s, ::s] = np.asarray(out, np.float64)
-    return xhat.astype(out_dtype)
-
-
-def reconstruct_unfused(shape, interp: str, anchors: np.ndarray,
-                        yhat_per_level: List[np.ndarray],
-                        overrides=None, out_dtype=np.float64,
-                        interpret: bool | None = None) -> np.ndarray:
-    """Per-phase kernel reconstruction (the pre-fusion jax path, kept as
-    the ``jax_unfused`` backend and the any-rank fallback).
-
-    The traversal, offset accounting, and escape override writeback run in
-    ``interpolation.reconstruct`` itself — this function only supplies the
-    per-phase block primitive (the backend seam), which moves the sweep
-    axis onto lanes and runs the fused predict+add-residual kernel.
-    Bit-exact with the numpy sweep: the prediction code is shared with the
-    encode kernel.
-    """
-    import jax
-
-    from ..kernels.interp_recon import interp_recon
-
-    def block_fn(hv, ph, res):
-        tgt_shape = list(hv.shape)
-        tgt_shape[ph.dim] = ph.targets.size
-        hm = np.ascontiguousarray(np.moveaxis(hv, ph.dim, -1))
-        rm = np.ascontiguousarray(np.moveaxis(
-            np.asarray(res, np.float64).reshape(tgt_shape), ph.dim, -1))
-        lead, C = hm.shape[:-1], hm.shape[-1]
-        R = int(np.prod(lead)) if lead else 1
-        out2 = interp_recon(hm.reshape(R, C), rm.reshape(R, -1),
-                            s=ph.stride, interp=interp, interpret=interpret)
-        T = out2.shape[1]
-        # order='C' copy: the override writeback addresses the block by
-        # flat index in original-axis C order
-        return np.array(np.moveaxis(
-            np.asarray(out2, np.float64).reshape(lead + (T,)), -1, ph.dim),
-            order="C")
-
-    with jax.experimental.enable_x64():
-        return interpolation.reconstruct(shape, interp, anchors,
-                                         yhat_per_level, overrides=overrides,
-                                         out_dtype=out_dtype,
-                                         block_fn=block_fn)
+                interpret: bool | None = None,
+                dtype=np.float64) -> np.ndarray:
+    """Kernel-backed twin of ``interpolation.reconstruct`` (Algorithm 1),
+    one array: :func:`reconstruct_batch` over a batch of one."""
+    return reconstruct_batch(
+        shape, interp, np.asarray(anchors)[None],
+        [np.asarray(y)[None] for y in yhat_per_level],
+        overrides=None if overrides is None else [overrides],
+        out_dtype=out_dtype, interpret=interpret, dtype=dtype)[0]
 
 
 def reconstruct_batch(shape, interp: str, anchors: np.ndarray,
                       yhat_per_level: List[np.ndarray],
                       overrides=None, out_dtype=np.float64,
                       interpret: bool | None = None,
-                      mesh=None) -> np.ndarray:
-    """Batched twin of :func:`reconstruct` over B equal-``shape`` items.
+                      mesh=None, dtype=np.float64) -> np.ndarray:
+    """Batched twin of ``interpolation.reconstruct_batch`` over B
+    equal-``shape`` items.
 
-    2-D stacks take the fused per-level path: ONE vmapped (optionally
-    mesh-sharded) ``interp_recon_level`` launch per level covers both
-    phase sweeps and every item's escape overrides (dense per-item mask
-    planes).  Per-item outputs are bit-identical to B scalar
-    :func:`reconstruct` calls.  Other ranks fall back to the per-phase
-    sweep (:func:`reconstruct_batch_unfused`).
+    The traversal, offset accounting, and escape override writeback run
+    in ``interpolation.reconstruct_batch`` itself; this function only
+    supplies the per-phase block primitive — one vmapped ``interp_recon``
+    launch per (level, dim) phase for the whole stack, ``shard_map``-ed
+    over the 1-D codec mesh when one is given.  Bit-exact with the numpy
+    sweep: both compute ``arith.recon`` from ``arith.predict``.
     """
-    if len(shape) != 2:
-        return reconstruct_batch_unfused(shape, interp, anchors,
-                                         yhat_per_level, overrides=overrides,
-                                         out_dtype=out_dtype,
-                                         interpret=interpret, mesh=mesh)
-    import jax
-
-    from ..kernels.interp_recon import (interp_recon_level_batch,
-                                        interp_recon_level_sharded)
-
-    B = anchors.shape[0]
-    L = interpolation.num_levels(shape)
-    xhat = np.zeros((B,) + tuple(shape), np.float64)
-    xhat[(slice(None),) + interpolation.anchor_slices(shape, L)] = anchors
-
-    def stack_override(li, lo, cnt, block_shape):
-        if overrides is None:
-            return None
-        pairs = [_dense_override(*overrides[b][li], lo, cnt, block_shape)
-                 for b in range(B)]
-        if all(p is None for p in pairs):
-            return None
-        zm = np.zeros(block_shape, np.int32)
-        zv = np.zeros(block_shape, np.float64)
-        return (np.stack([p[0] if p else zm for p in pairs]),
-                np.stack([p[1] if p else zv for p in pairs]))
-
-    with jax.experimental.enable_x64():
-        for level in range(L, 0, -1):
-            s = 1 << (level - 1)
-            li = L - level
-            Ms, Ns, T0, T1, Nse = _level_blocks(shape, s)
-            if T0 == 0 and T1 == 0:
-                continue
-            stream = np.asarray(yhat_per_level[li], np.float64)
-            res0 = res1 = ov0 = ov1 = None
-            lo = 0
-            if T0 > 0:
-                cnt0 = T0 * Nse
-                res0 = stream[:, lo:lo + cnt0].reshape(B, T0, Nse)
-                ov0 = stack_override(li, lo, cnt0, (T0, Nse))
-                lo += cnt0
-            if T1 > 0:
-                cnt1 = Ms * T1
-                res1 = stream[:, lo:lo + cnt1].reshape(B, Ms, T1)
-                ov1 = stack_override(li, lo, cnt1, (Ms, T1))
-                lo += cnt1
-            g = np.ascontiguousarray(xhat[:, ::s, ::s])
-            if mesh is not None:
-                out = interp_recon_level_sharded(g, res0, res1, mesh=mesh,
-                                                 interp=interp, ov0=ov0,
-                                                 ov1=ov1, interpret=interpret)
-            else:
-                out = interp_recon_level_batch(g, res0, res1, interp=interp,
-                                               ov0=ov0, ov1=ov1,
-                                               interpret=interpret)
-            xhat[:, ::s, ::s] = np.asarray(out, np.float64)
-    return xhat.astype(out_dtype)
-
-
-def reconstruct_batch_unfused(shape, interp: str, anchors: np.ndarray,
-                              yhat_per_level: List[np.ndarray],
-                              overrides=None, out_dtype=np.float64,
-                              interpret: bool | None = None,
-                              mesh=None) -> np.ndarray:
-    """Per-phase batched reconstruction (the pre-fusion jax path, kept as
-    the ``jax_unfused`` backend and the any-rank fallback).
-
-    Same seam as the scalar path: traversal, offset accounting, and the
-    per-item escape writeback run in ``interpolation.reconstruct_batch``;
-    this function only supplies the batched per-phase block primitive —
-    one vmapped ``interp_recon`` launch per phase for the whole stack.
-    With ``mesh``, each phase launch is ``shard_map``-ed over the 1-D
-    codec mesh; bits still do not change.
-    """
-    import jax
-
-    from ..kernels.interp_recon import (interp_recon_batch,
-                                        interp_recon_sharded)
+    from ..kernels.interp_recon import interp_recon_batch
 
     def block_fn(hv, ph, res):
-        B = hv.shape[0]
         ax = ph.dim + 1
         tgt_shape = list(hv.shape)
         tgt_shape[ax] = ph.targets.size
-        hm = np.ascontiguousarray(np.moveaxis(hv, ax, -1))
-        rm = np.ascontiguousarray(np.moveaxis(
-            np.asarray(res, np.float64).reshape(tgt_shape), ax, -1))
-        lead, C = hm.shape[1:-1], hm.shape[-1]
-        R = int(np.prod(lead)) if lead else 1
-        if mesh is not None:
-            out3 = interp_recon_sharded(hm.reshape(B, R, C),
-                                        rm.reshape(B, R, -1), s=ph.stride,
-                                        interp=interp, mesh=mesh,
-                                        interpret=interpret)
-        else:
-            out3 = interp_recon_batch(hm.reshape(B, R, C),
-                                      rm.reshape(B, R, -1), s=ph.stride,
-                                      interp=interp, interpret=interpret)
-        T = out3.shape[-1]
+        hm, lead = _to_lanes(hv, ax)
+        rm, _ = _to_lanes(np.asarray(res).reshape(tgt_shape), ax)
+        out = interp_recon_batch(hm, rm, s=ph.stride, interp=interp,
+                                 interpret=interpret, mesh=mesh)
         # order='C' copy: the override writeback addresses each item's
         # block by flat index in original-axis C order
-        return np.array(np.moveaxis(
-            np.asarray(out3, np.float64).reshape((B,) + lead + (T,)),
-            -1, ax), order="C")
+        return np.array(_from_lanes(out, lead, ax), order="C")
 
-    with jax.experimental.enable_x64():
+    with _x64(dtype):
         return interpolation.reconstruct_batch(
             shape, interp, anchors, yhat_per_level, overrides=overrides,
-            out_dtype=out_dtype, block_fn=block_fn)
+            out_dtype=out_dtype, block_fn=block_fn, dtype=dtype)
 
 
 def reconstruct_sharded(shape, interp: str, anchors: np.ndarray,
                         yhat_per_level: List[np.ndarray], mesh,
                         overrides=None, out_dtype=np.float64,
-                        interpret: bool | None = None) -> np.ndarray:
+                        interpret: bool | None = None,
+                        dtype=np.float64) -> np.ndarray:
     """Sharded reconstruction sweep: :func:`reconstruct_batch` over a 1-D
     codec mesh (the ``CodecBackend`` sharded-slot signature)."""
     return reconstruct_batch(shape, interp, anchors, yhat_per_level,
                              overrides=overrides, out_dtype=out_dtype,
-                             interpret=interpret, mesh=mesh)
-
-
-def reconstruct_sharded_unfused(shape, interp: str, anchors: np.ndarray,
-                                yhat_per_level: List[np.ndarray], mesh,
-                                overrides=None, out_dtype=np.float64,
-                                interpret: bool | None = None) -> np.ndarray:
-    """Sharded per-phase reconstruction (``jax_unfused`` backend slot)."""
-    return reconstruct_batch_unfused(shape, interp, anchors, yhat_per_level,
-                                     overrides=overrides, out_dtype=out_dtype,
-                                     interpret=interpret, mesh=mesh)
+                             interpret=interpret, mesh=mesh, dtype=dtype)

@@ -19,6 +19,11 @@ uses p^((l-1+1)*ndim_phases) — an upper bound that also covers within-level
 dimension-sequential amplification (see DESIGN.md §3); used by the
 adversarial property tests.
 
+Float32 archives compute in float32 (``core.arith``), and every reported
+bound of a partial read adds a rigorous rounding allowance
+(:func:`plan_bound`); a full read's bound is ``eb`` exactly, because its
+bits are the ones the encoder verified element by element.
+
 Chunked (v2) archives run this planner per chunk: error mode passes the
 requested bound straight through (per-chunk L_inf <= E implies the global
 bound), byte/bitrate budgets are pre-split across chunks proportionally to
@@ -34,12 +39,22 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from . import arith
 from .container import ArchiveMeta
 from .interpolation import PRED_NORM
 
 NBUCKETS = 1024
 PAPER = "paper"
 SAFE = "safe"
+
+#: rounding error of one float32 prediction, in units of u * M (u = 2**-24,
+#: M bounds every value): cubic rounds its two ``8x + x`` terms and three
+#: sums, of magnitude at most 9, 9, 10, 19 and 20 M, then scales by 1/16;
+#: linear rounds one sum of magnitude 2 M, then halves
+PRED_UNITS = {"cubic": 67.0 / 16.0, "linear": 1.0}
+#: flush-to-zero events per phase over two sweeps (11 each), rounded up;
+#: each moves a value by less than ``arith.TINY32``
+FLUSH_UNITS = 32
 
 
 @dataclass
@@ -84,30 +99,92 @@ def _level_cost_tables(meta: ArchiveMeta, propagation: str):
     return errs, sizes
 
 
-def plan_error_mode(meta: ArchiveMeta, E: float,
-                    propagation: str = PAPER) -> LoadPlan:
-    """Minimum-volume plan with guaranteed L_inf error <= E (requires E >= eb)."""
-    if E < meta.eb:
-        raise ValueError(f"requested bound {E} < compression bound {meta.eb}")
-    errs, sizes = _level_cost_tables(meta, propagation)
-    budget = E - meta.eb
+def rounding_allowance(meta: ArchiveMeta, keep: Sequence[int],
+                       T: float) -> float:
+    """Bound on how far a float32 partial read can stray from ``eb + T``
+    through rounding alone (0 for float64 archives and full plans).
+
+    A partial read re-sweeps from scratch with truncated bins; the full
+    read, whose bits the encoder verified to lie within ``eb``, differs
+    from it by the propagated truncation ``T`` plus what rounding adds.
+    Levels coarser than the coarsest truncated level ``l*`` hold identical
+    bins in both sweeps, hence identical bits, so the first phase of
+    ``l*`` predicts from identical neighbours.  Every other phase at
+    ``l*`` and below can differ by both sweeps' prediction rounding
+    (``PRED_UNITS``), their ``pred + res`` roundings, their dequantization
+    error against the exact ``q * 2 eb`` (at most 3u of the level's
+    largest residual, read off the delta table) and their flushes.  Each
+    phase's discrepancy then propagates like truncation loss under the
+    SAFE model: by ``p`` per later phase.  ``M`` bounds every value either
+    sweep holds — ``vmax + eb + T`` plus the allowance itself, solved in
+    closed form.  Infinite when the ``M`` coefficient reaches 1.
+    """
+    if meta.work_dtype != np.float32:
+        return 0.0
+    cut = [lv.level for li, lv in enumerate(meta.levels)
+           if keep[li] < lv.nbits]
+    if not cut:
+        return 0.0
+    lstar = max(cut)
+    p = PRED_NORM[meta.interp]
+    nd = len(meta.shape)
+    u = arith.U32
+    c_m = 0.0     # coefficient of M
+    const = 0.0   # absolute part
+    for lv in meta.levels:
+        if lv.level > lstar:
+            continue
+        dt = lv.delta_table
+        deq = 3.0001 * u * (2.0 * dt[lv.nbits] + max(dt))
+        for d in range(nd):
+            w = p ** (nd - 1 - d + nd * (lv.level - 1))
+            pred = 0.0 if (lv.level == lstar and d == 0) \
+                else 2.0 * PRED_UNITS[meta.interp]
+            c_m += w * (pred + 2.0) * u
+            const += w * (deq + FLUSH_UNITS * arith.TINY32)
+    if c_m >= 1.0:
+        return math.inf
+    M = (float(meta.vmax) + meta.eb + T + const) / (1.0 - c_m)
+    return c_m * M + const
+
+
+def plan_bound(meta: ArchiveMeta, keep: Sequence[int], errs,
+               propagation: str) -> float:
+    """Guaranteed L_inf bound of reading ``keep[li]`` MSB planes per level
+    (``errs`` from :func:`_level_cost_tables` under ``propagation``).
+
+    ``eb`` plus the summed propagated truncation loss; a float32 archive
+    adds a relative ``2u`` on the loss (its bin width is ``f32(2 eb)``)
+    and the :func:`rounding_allowance`.  The one formula behind every
+    planner's reported bound and the session's achieved bound, so the two
+    agree to the bit.
+    """
+    T = sum(float(errs[li][lv.nbits - keep[li]])
+            for li, lv in enumerate(meta.levels))
+    if meta.work_dtype != np.float32:
+        return meta.eb + T
+    T *= 1.0 + 2.0 * arith.U32
+    return meta.eb + T + rounding_allowance(meta, keep, T)
+
+
+def _dp_error(meta: ArchiveMeta, errs, sizes, budget: float,
+              max_discard: Sequence[int]) -> Optional[List[int]]:
+    """Knapsack core of :func:`plan_error_mode`: per-level discard counts
+    ``b_l <= max_discard[l]`` with summed ``errs`` within ``budget`` and
+    the fewest bytes, or None when nothing fits."""
     nl = len(meta.levels)
-    if budget <= 0:
-        keep = [meta.levels[i].nbits for i in range(nl)]
-        return _finish(meta, keep, errs, mode="error")
     unit = budget / NBUCKETS
     # err in integer units, rounded UP => conservative
     err_units = [np.minimum(np.ceil(e / unit), NBUCKETS + 1).astype(np.int64)
                  for e in errs]
     # DP[u] = min bytes with total err units <= u, processed levels so far
     INF = np.int64(1 << 60)
-    dp = np.full(NBUCKETS + 1, INF, np.int64)
-    dp[:] = 0  # zero levels processed: zero bytes whatever the budget
+    dp = np.zeros(NBUCKETS + 1, np.int64)  # zero levels: zero bytes
     choice = np.zeros((nl, NBUCKETS + 1), np.int16)
     for li in range(nl):
         ndp = np.full(NBUCKETS + 1, INF, np.int64)
         nch = np.zeros(NBUCKETS + 1, np.int16)
-        for b in range(meta.levels[li].nbits + 1):
+        for b in range(max_discard[li] + 1):
             eu = int(err_units[li][b])
             if eu > NBUCKETS:
                 continue  # this choice alone blows the budget
@@ -120,17 +197,60 @@ def plan_error_mode(meta: ArchiveMeta, E: float,
             nch[upd] = b
         dp = ndp
         choice[li] = nch
+    if dp[NBUCKETS] >= INF:
+        return None
     # backtrack from the full budget
     u = NBUCKETS
-    keep = []
     discard = []
     for li in range(nl - 1, -1, -1):
         b = int(choice[li][u])
         discard.append(b)
         u -= int(err_units[li][b])
     discard.reverse()
-    keep = [meta.levels[i].nbits - discard[i] for i in range(nl)]
-    return _finish(meta, keep, errs, mode="error")
+    return discard
+
+
+def plan_error_mode(meta: ArchiveMeta, E: float,
+                    propagation: str = PAPER) -> LoadPlan:
+    """Minimum-volume plan with guaranteed L_inf error <= E (requires E >= eb).
+
+    Float32 archives pay a rounding allowance that depends on the
+    coarsest truncated level (:func:`rounding_allowance`, which grows with
+    it), so the knapsack
+    runs once per candidate coarsest level — coarser levels kept whole,
+    the budget net of that candidate's allowance — and the cheapest
+    feasible plan wins; the full plan is always feasible.
+    """
+    if E < meta.eb:
+        raise ValueError(f"requested bound {E} < compression bound {meta.eb}")
+    errs, sizes = _level_cost_tables(meta, propagation)
+    nl = len(meta.levels)
+    full = [lv.nbits for lv in meta.levels]
+    budget = E - meta.eb
+    if budget <= 0:
+        return _finish(meta, full, errs, mode="error", propagation=propagation)
+    if meta.work_dtype != np.float32:
+        discard = _dp_error(meta, errs, sizes, budget, full)
+        keep = [full[i] - discard[i] for i in range(nl)]
+        return _finish(meta, keep, errs, mode="error", propagation=propagation)
+    best = full
+    best_bytes = _loaded_bytes(meta, full)
+    scale = 1.0 + 2.0 * arith.U32
+    for lstar in sorted({lv.level for lv in meta.levels}):
+        keep_one = [lv.nbits - (lv.level <= lstar) for lv in meta.levels]
+        rho = rounding_allowance(meta, keep_one, budget)
+        room = (budget - rho) / scale
+        if not room > 0:
+            continue
+        cap = [lv.nbits if lv.level <= lstar else 0 for lv in meta.levels]
+        discard = _dp_error(meta, errs, sizes, room, cap)
+        if discard is None:
+            continue
+        keep = [full[i] - discard[i] for i in range(nl)]
+        nbytes = _loaded_bytes(meta, keep)
+        if nbytes < best_bytes:
+            best, best_bytes = keep, nbytes
+    return _finish(meta, best, errs, mode="error", propagation=propagation)
 
 
 def plan_bitrate_mode(meta: ArchiveMeta, max_bytes: int,
@@ -157,7 +277,8 @@ def plan_bitrate_mode(meta: ArchiveMeta, max_bytes: int,
             "bytes or use an error-bound target")
     budget = max_bytes - min_bytes
     if budget <= 0:  # exactly the escape-channel floor: load the minimum
-        return _finish(meta, [0] * nl, errs, mode="bitrate")
+        return _finish(meta, [0] * nl, errs, mode="bitrate",
+                       propagation=propagation)
     # ceil-rounded units guarantee sum(sizes) <= NBUCKETS*unit = budget
     unit = budget / NBUCKETS
     size_units = [np.minimum(np.ceil((s - s[-1]) / unit), NBUCKETS + 1).astype(np.int64)
@@ -188,7 +309,7 @@ def plan_bitrate_mode(meta: ArchiveMeta, max_bytes: int,
         u -= int(size_units[li][b])
     discard.reverse()
     keep = [meta.levels[i].nbits - discard[i] for i in range(nl)]
-    return _finish(meta, keep, errs, mode="bitrate")
+    return _finish(meta, keep, errs, mode="bitrate", propagation=propagation)
 
 
 def plan_full(meta: ArchiveMeta, propagation: str = PAPER) -> LoadPlan:
@@ -201,7 +322,8 @@ def plan_full(meta: ArchiveMeta, propagation: str = PAPER) -> LoadPlan:
     model than the session's own ``update_achieved_bound`` accounting.
     """
     errs, _ = _level_cost_tables(meta, propagation)
-    return _finish(meta, [lv.nbits for lv in meta.levels], errs, mode="full")
+    return _finish(meta, [lv.nbits for lv in meta.levels], errs, mode="full",
+                   propagation=propagation)
 
 
 # ------------------------------------------------ v3 ladder (plane-major)
@@ -303,21 +425,18 @@ def ladder_error_mode(meta, E: float, propagation: str = PAPER,
     if E < meta.eb:
         raise ValueError(f"requested bound {E} < compression bound {meta.eb}")
     errs = [_level_cost_tables(m, propagation)[0] for m in meta.chunk_metas]
-    cur = [m.eb + sum(float(errs[c][li][lv.nbits])
-                      for li, lv in enumerate(m.levels))
+    keep = [[0] * len(m.levels) for m in meta.chunk_metas]
+    cur = [plan_bound(m, keep[c], errs[c], propagation)
            for c, m in enumerate(meta.chunk_metas)]
     segs = meta.plane_segments
     t = 0
     while t < len(segs) and (t < t_min or max(cur) > E):
         s = segs[t]
         for c, m in enumerate(meta.chunk_metas):
-            if s.level >= len(m.levels):
+            if s.level >= len(m.levels) or s.plane >= m.levels[s.level].nbits:
                 continue
-            nb = m.levels[s.level].nbits
-            if s.plane >= nb:
-                continue
-            cur[c] += float(errs[c][s.level][nb - s.plane - 1]
-                            - errs[c][s.level][nb - s.plane])
+            keep[c][s.level] = s.plane + 1
+            cur[c] = plan_bound(m, keep[c], errs[c], propagation)
         t += 1
     return t
 
@@ -346,12 +465,16 @@ def ladder_bitrate_mode(meta, max_bytes: int, t_min: int = 0) -> int:
     return max(t, t_min)
 
 
-def _finish(meta: ArchiveMeta, keep: List[int], errs, mode: str) -> LoadPlan:
-    total = sum(sum(lv.plane_sizes[: keep[li]]) + lv.esc_size
-                for li, lv in enumerate(meta.levels))
-    # same summation shape as state.update_achieved_bound, so the plan's
-    # reported bound and the session's achieved bound agree to the bit
-    err = meta.eb + sum(float(errs[li][lv.nbits - keep[li]])
-                        for li, lv in enumerate(meta.levels))
-    return LoadPlan(keep_planes=keep, loaded_bytes=int(total),
-                    err_bound=float(err), mode=mode)
+def _loaded_bytes(meta: ArchiveMeta, keep: Sequence[int]) -> int:
+    return int(sum(sum(lv.plane_sizes[: keep[li]]) + lv.esc_size
+                   for li, lv in enumerate(meta.levels)))
+
+
+def _finish(meta: ArchiveMeta, keep: List[int], errs, mode: str,
+            propagation: str) -> LoadPlan:
+    # plan_bound is also state.update_achieved_bound's formula, so the
+    # plan's reported bound and the session's achieved bound agree
+    return LoadPlan(keep_planes=keep, loaded_bytes=_loaded_bytes(meta, keep),
+                    err_bound=float(plan_bound(meta, keep, errs,
+                                               propagation)),
+                    mode=mode)

@@ -11,9 +11,10 @@ not change.
 Primitive contracts (all bit-identical across backends — the parity test
 suites pin this down):
 
-  decorrelate(x_f64, eb, interp) -> (xhat, qs, escs, anchors)
-      compression-side sweep: per-level int64 bin streams + escape records
-      with level-global indices (see ``interpolation.decorrelate``).
+  decorrelate(x, eb, interp) -> (xhat, qs, escs, anchors)
+      compression-side sweep in the field's arithmetic (``core.arith``):
+      per-level int64 bin streams + escape records with level-global
+      indices (see ``interpolation.decorrelate_batch``).
   encode_level(q_int64, nb_uint32) -> (blobs MSB-first, nbits)
       negabinary + XOR-predictive bitplane packing of one level stream;
       both representations of the same values are passed so each substrate
@@ -23,22 +24,24 @@ suites pin this down):
   decode_level(blobs, nbits, n) -> uint32 truncated negabinary
       inverse of encode_level for a loaded MSB-first blob prefix
       (None = not loaded; b'' = loaded, all-zero encoded plane).
-  reconstruct(shape, interp, anchors, yhat_per_level, overrides=, out_dtype=)
-      decompression-side sweep (Algorithm 1 core); linear in (anchors,
-      yhat), which Algorithm 2's zero-anchor delta cascade relies on.
+  reconstruct(shape, interp, anchors, yhat_per_level, overrides=, out_dtype=,
+              dtype=)
+      decompression-side sweep (Algorithm 1 core) in working dtype
+      ``dtype``; linear in (anchors, yhat) up to rounding, which
+      Algorithm 2's zero-anchor delta cascade relies on.
 
 Each primitive may also ship an OPTIONAL batched twin (``*_batch``) that
 processes a stack of equal-shaped chunk problems in one kernel dispatch —
 the unit the v2 chunk scheduler feeds (see ``encode``/``decode`` shape-group
 scheduling and ``docs/architecture.md`` for the full dataflow):
 
-  decorrelate_batch(xs_f64 (B, *shape), eb, interp) -> B-list of the
+  decorrelate_batch(xs (B, *shape), eb, interp) -> B-list of the
       scalar tuples;
   encode_level_batch(q2 (B, n), nb2 (B, n)) -> B-list of (blobs, nbits);
   decode_level_batch(B blob-prefix lists w/ equal nbits AND equal loaded
       prefix, nbits, n) -> B-list of truncated negabinary arrays;
   reconstruct_batch(shape, interp, anchors (B, ...), yhat [(B, n_l)],
-      overrides=per-item list, out_dtype=) -> (B, *shape).
+      overrides=per-item list, out_dtype=, dtype=) -> (B, *shape).
 
 And each batched twin may ship an OPTIONAL *sharded* twin (``*_sharded``)
 — identical contract plus one trailing required argument, a 1-D device
@@ -49,7 +52,7 @@ every mesh device executes the batched primitive on its local chunks:
   encode_level_sharded(q2, nb2, mesh)              -> as encode_level_batch
   decode_level_sharded(blob_lists, nbits, n, mesh) -> as decode_level_batch
   reconstruct_sharded(shape, interp, anchors, yhat, mesh, overrides=,
-      out_dtype=)                                  -> as reconstruct_batch
+      out_dtype=, dtype=)                          -> as reconstruct_batch
 
 Decode-side FUSED slots (all optional, adopted by the progressive session
 scheduler in ``pipeline/state.py`` when present):
@@ -58,15 +61,17 @@ scheduler in ``pipeline/state.py`` when present):
       host-side zlib inflate + word packing of one level's loaded blob
       prefix — the CPU half the scheduler can overlap with device work;
   inflate_level_batch(blob_lists, nbits, n) -> ((B, 32, nw) words, wants)
-  decode_level_fused(blobs, nbits, n, nb_old, eb, words=) ->
-      (nb_new uint32, delta f64): ONE launch fusing plane-unpack +
-      negabinary dequantize + the Algorithm 2 delta against the session's
-      previous truncation ``nb_old`` (delta = (q_new - q_old) * 2 * eb,
-      bit-identical to the host spelling); ``words=`` accepts a prefetched
-      ``inflate_level`` result so the zlib work can run ahead of time;
-  decode_level_fused_batch(blob_lists, nbits, n, nb_olds, ebs, words=)
-      -> B-list of (nb_new, delta) with PER-CHUNK loaded prefixes and
-      per-chunk error bounds (mixed prefixes in one dispatch);
+  decode_level_fused(blobs, nbits, n, nb_old, eb, words=, dtype=) ->
+      (nb_new uint32, out): ONE launch fusing plane-unpack + negabinary
+      dequantize; ``out`` is, for a float64 field, the Algorithm 2 delta
+      against the session's previous truncation ``nb_old`` (delta =
+      (q_new - q_old) * 2 * eb) and, for a float32 field, the level's
+      full float32 residual — either bit-identical to the host
+      arithmetic; ``words=`` accepts a prefetched ``inflate_level``
+      result so the zlib work can run ahead of time;
+  decode_level_fused_batch(blob_lists, nbits, n, nb_olds, ebs, words=,
+      dtype=) -> B-list of (nb_new, out) with PER-CHUNK loaded prefixes
+      and per-chunk error bounds (mixed prefixes in one dispatch);
   decode_level_fused_sharded(..., mesh=) — same over the 1-D codec mesh.
 
 ``dynamic_low_zero=True`` declares that the batched decode paths accept
@@ -90,9 +95,10 @@ Selection: ``"numpy"`` | ``"jax"`` | ``"jax_unfused"`` | ``"auto"``/None.
 "auto" picks jax only where the kernels actually compile (TPU); on GPU/CPU
 they would run in the (slow) Pallas interpreter — valid for parity testing,
 so request it explicitly with ``backend="jax"`` rather than have "auto"
-silently emulate.  ``"jax_unfused"`` is the pre-fusion jax path (per-phase
-reconstruction, per-prefix decode grouping, no fused decode slots), kept
-registered as the benchmark baseline the fused path is measured against.
+silently emulate.  ``"jax_unfused"`` is the pre-fusion jax path (separate
+unpack launch + host dequantize, per-prefix decode grouping, no fused
+decode slots), kept registered as the benchmark baseline the fused path is
+measured against.
 """
 from __future__ import annotations
 
@@ -101,7 +107,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .. import bitplane, interpolation, jax_backend, negabinary, quantize
+from .. import bitplane, interpolation, jax_backend
 # single source for the backend-name constants (the reverse import would be
 # circular: jax_backend.resolve delegates here function-locally)
 from ..jax_backend import AUTO, JAX, JAX_UNFUSED, NUMPY
@@ -197,24 +203,6 @@ def get(choice) -> CodecBackend:
 
 # ---------------------------------------------------------- numpy reference
 
-def _numpy_decorrelate(x: np.ndarray, eb: float, interp: str):
-    """Reference sweep: ``interpolation.decorrelate`` with the linear-scale
-    quantizer + lossless escape channel (paper §4.2)."""
-
-    def quantizer(res: np.ndarray, tvals: np.ndarray):
-        q = quantize.quantize(res, eb)
-        esc = quantize.escape_mask(q)
-        recon = quantize.dequantize(q, eb)
-        if esc.any():
-            flat = np.flatnonzero(esc.ravel())
-            vals = tvals.ravel()[flat].astype(np.float64)  # absolute values
-            q.ravel()[flat] = 0
-            return q, recon, (flat, vals)
-        return q, recon, (np.zeros(0, np.int64), np.zeros(0, np.float64))
-
-    return interpolation.decorrelate(x, eb, interp, quantizer)
-
-
 def _numpy_encode_level(q: np.ndarray, nb: np.ndarray) -> Tuple[List[bytes], int]:
     return bitplane.encode_level(nb)
 
@@ -235,7 +223,7 @@ def _jax_encode_level_sharded(q2: np.ndarray, nb2: np.ndarray, mesh,
 
 register(CodecBackend(
     name=NUMPY,
-    decorrelate=_numpy_decorrelate,
+    decorrelate=interpolation.decorrelate,
     encode_level=_numpy_encode_level,
     decode_level=bitplane.decode_level,
     reconstruct=interpolation.reconstruct,
@@ -264,22 +252,22 @@ register(CodecBackend(
     dynamic_low_zero=True,
 ))
 
-# the pre-fusion jax path: identical encode side and archives, but decode
-# runs the separate unpack / host-dequantize / per-phase recon pipeline with
-# per-prefix dispatch grouping.  Kept registered (and so selectable through
+# the pre-fusion jax path: identical encode side, archives and sweep, but
+# decode runs the separate unpack launch + host dequantize with per-prefix
+# dispatch grouping.  Kept registered (and so selectable through
 # ExecPolicy) as the measured baseline for the fused megakernel benchmarks.
 register(CodecBackend(
     name=JAX_UNFUSED,
     decorrelate=jax_backend.decorrelate,
     encode_level=_jax_encode_level,
     decode_level=jax_backend.decode_level,
-    reconstruct=jax_backend.reconstruct_unfused,
+    reconstruct=jax_backend.reconstruct,
     decorrelate_batch=jax_backend.decorrelate_batch,
     encode_level_batch=_jax_encode_level_batch,
     decode_level_batch=jax_backend.decode_level_batch,
-    reconstruct_batch=jax_backend.reconstruct_batch_unfused,
+    reconstruct_batch=jax_backend.reconstruct_batch,
     decorrelate_sharded=jax_backend.decorrelate_sharded,
     encode_level_sharded=_jax_encode_level_sharded,
     decode_level_sharded=jax_backend.decode_level_sharded,
-    reconstruct_sharded=jax_backend.reconstruct_sharded_unfused,
+    reconstruct_sharded=jax_backend.reconstruct_sharded,
 ))

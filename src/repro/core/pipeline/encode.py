@@ -45,7 +45,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import bitplane, container, interpolation, negabinary
+from .. import arith, bitplane, container, interpolation, negabinary
 from . import backends, spec
 from .spec import ExecPolicy
 
@@ -95,6 +95,7 @@ def encode_array(x: np.ndarray, eb: float,
         eb = eb * (float(x.max()) - float(x.min()) or 1.0)
     if eb <= 0:
         raise ValueError("error bound must be positive")
+    arith.consts(eb, x.dtype)  # rejects bounds the field's arithmetic cannot honour
     ctx = policy.bind(chunked=version != 1, encode=True)
     if version == 1:
         return _compress_single(x, eb, interp, ctx.bk)
@@ -201,7 +202,7 @@ def _compress_single(x: np.ndarray, eb: float, interp: str,
     """One (chunk-sized) array -> one v1 archive, via the chosen backend."""
     shape, dtype = x.shape, x.dtype
     L = interpolation.num_levels(shape)
-    _, qs, escs, anchors = bk.decorrelate(x.astype(np.float64), eb, interp)
+    _, qs, escs, anchors = bk.decorrelate(x, eb, interp)
 
     level_blobs, level_meta, esc_blobs = [], [], []
     for li in range(L):
@@ -214,7 +215,8 @@ def _compress_single(x: np.ndarray, eb: float, interp: str,
                                delta_table=delta.tolist()))
         esc_blobs.append(_pack_escapes(escs[li]))
     return container.write_archive(shape, dtype, eb, interp, L, anchors,
-                                   level_blobs, level_meta, esc_blobs)
+                                   level_blobs, level_meta, esc_blobs,
+                                   vmax=_vmax(x))
 
 
 def _compress_batch(xs: np.ndarray, eb: float, interp: str,
@@ -234,10 +236,9 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
     shape, dtype = xs.shape[1:], xs.dtype
     L = interpolation.num_levels(shape)
     if mesh is not None:
-        results = bk.decorrelate_sharded(xs.astype(np.float64), eb, interp,
-                                         mesh)
+        results = bk.decorrelate_sharded(xs, eb, interp, mesh)
     else:
-        results = bk.decorrelate_batch(xs.astype(np.float64), eb, interp)
+        results = bk.decorrelate_batch(xs, eb, interp)
 
     blobs_pc: List[List[List[bytes]]] = [[] for _ in range(B)]
     meta_pc: List[List[dict]] = [[] for _ in range(B)]
@@ -258,7 +259,17 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
             escb_pc[b].append(_pack_escapes(results[b][2][li]))
     return [container.write_archive(shape, dtype, eb, interp, L,
                                     results[b][3], blobs_pc[b], meta_pc[b],
-                                    escb_pc[b]) for b in range(B)]
+                                    escb_pc[b], vmax=_vmax(xs[b]))
+            for b in range(B)]
+
+
+def _vmax(x: np.ndarray) -> Optional[float]:
+    """Largest finite |x| of a float32 field (the header field the
+    reader's rounding allowance needs); None under the float64 contract."""
+    if arith.work_dtype(x.dtype) != np.float32:
+        return None
+    a = np.abs(x[np.isfinite(x)])
+    return float(a.max()) if a.size else 0.0
 
 
 def _pack_escapes(phase_escs) -> bytes:

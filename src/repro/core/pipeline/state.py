@@ -11,12 +11,23 @@ top of the previous reconstruction instead of decoding from scratch:
     (escaped points are exact from the very first pass);
   * ``xhat`` — the current reconstruction the next delta lands on.
 
-The cascade itself (:func:`load_level_deltas` + :func:`push_delta`) is the
-paper's Algorithm 2: residual *differences* are reconstructed through the
-same interpolation sweep with zero anchors — valid because the sweep is
-linear in (anchors, residuals) — and added to ``xhat``.  Both steps take
-the resolved :class:`~.backends.CodecBackend`, so refinement runs on the
-Pallas kernels exactly like a cold retrieval.
+The update (:func:`load_level_deltas` + :func:`push_delta`) follows the
+archive's arithmetic contract (``core.arith``):
+
+  * float64 archives run the paper's Algorithm 2 cascade: residual
+    *differences* are reconstructed through the same interpolation sweep
+    with zero anchors — valid because the sweep is linear in (anchors,
+    residuals) — and added to ``xhat``;
+  * float32 archives keep the Algorithm 2 delta in the integer domain —
+    the loaded planes only ever extend each level's truncated bins — and
+    re-sweep from the anchors with the current residuals.  A float32
+    delta cascade would accumulate rounding with every refinement; the
+    re-sweep makes every rung path-independent, and the full read
+    bit-identical to the reconstruction the encoder verified against
+    ``eb`` (``arith.screen``).
+
+Both steps take the resolved :class:`~.backends.CodecBackend`, so
+refinement runs on the Pallas kernels exactly like a cold retrieval.
 
 :class:`ChunkedRetrievalState` is the v2-archive twin: one per-chunk state
 plus aggregated accounting.
@@ -50,7 +61,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from .. import bitplane, loader, negabinary
+from .. import arith, bitplane, loader, negabinary
 from ..container import ArchiveReader, ChunkedArchiveReader
 from .backends import CodecBackend
 from .spec import ExecContext
@@ -66,6 +77,11 @@ class RetrievalState:
     xhat: np.ndarray                      # current reconstruction
     err_bound: float
     bytes_read: int = 0
+    # float32 archives re-sweep from scratch (module docstring): the exact
+    # anchors, per-level escape values and current float32 residuals
+    anchors: Optional[np.ndarray] = None
+    esc_val: Optional[List[np.ndarray]] = None
+    res: Optional[List[np.ndarray]] = None
 
 
 @dataclass
@@ -91,8 +107,8 @@ def fork_state(state):
     branch off one finished session concurrently, each fetching only the
     planes its own target adds, without sharing a mutable state or
     ledger.  Cheap: ``nb_partial`` streams are immutable-by-contract
-    (replaced, never written in place) and ``xhat`` is only ever
-    reassigned, so the arrays themselves are shared.
+    (replaced, never written in place) and ``xhat``/``res`` entries are
+    only ever reassigned, so the arrays themselves are shared.
     """
     if isinstance(state, ChunkedRetrievalState):
         reader = state.reader.fork()
@@ -103,7 +119,9 @@ def fork_state(state):
                 nb_partial=list(cs.nb_partial),
                 esc_idx=list(cs.esc_idx),
                 xhat=cs.xhat, err_bound=cs.err_bound,
-                bytes_read=cs.bytes_read)
+                bytes_read=cs.bytes_read, anchors=cs.anchors,
+                esc_val=cs.esc_val,
+                res=None if cs.res is None else list(cs.res))
             for i, cs in enumerate(state.chunk_states)]
         return ChunkedRetrievalState(reader=reader,
                                      chunk_states=chunk_states,
@@ -116,7 +134,9 @@ def fork_state(state):
                           nb_partial=list(state.nb_partial),
                           esc_idx=list(state.esc_idx),
                           xhat=state.xhat, err_bound=state.err_bound,
-                          bytes_read=state.bytes_read)
+                          bytes_read=state.bytes_read, anchors=state.anchors,
+                          esc_val=state.esc_val,
+                          res=None if state.res is None else list(state.res))
 
 
 def _count(counters, name: str, k: int = 1) -> None:
@@ -176,29 +196,64 @@ def _inflate_pool():
     return _INFLATE_POOL
 
 
+def _coarsest_bound(m) -> float:
+    """Guaranteed bound with no planes loaded (SAFE propagation)."""
+    errs, _ = loader._level_cost_tables(m, loader.SAFE)
+    return loader.plan_bound(m, [0] * len(m.levels), errs, loader.SAFE)
+
+
+def _new_state(reader, anchors, overrides, xhat) -> RetrievalState:
+    """State after the coarsest pass (anchors + escapes, zero planes)."""
+    m = reader.meta
+    f32 = m.work_dtype == np.float32
+    return RetrievalState(
+        reader=reader, planes_loaded=[0] * len(m.levels),
+        nb_partial=[np.zeros(lv.n, np.uint32) for lv in m.levels],
+        esc_idx=[o[0] for o in overrides], xhat=xhat,
+        err_bound=_coarsest_bound(m), bytes_read=reader.bytes_read,
+        anchors=anchors if f32 else None,
+        esc_val=[o[1] for o in overrides] if f32 else None,
+        res=[np.zeros(lv.n, np.float32) for lv in m.levels] if f32
+        else None)
+
+
 def initial_state(reader: ArchiveReader, bk: CodecBackend,
                   counters=None) -> RetrievalState:
     """Coarsest approximation: anchors + escapes only, zero bitplanes."""
     m = reader.meta
+    dt = m.work_dtype
+    yhat = [np.zeros(lv.n, dt) for lv in m.levels]
     anchors = reader.anchors()
-    yhat, overrides = [], []
-    for li, lv in enumerate(m.levels):
-        yhat.append(np.zeros(lv.n, np.float64))
-        idx, val = _unpack_escapes(reader.escapes(li))
-        overrides.append((idx, val))
+    overrides = [_unpack_escapes(reader.escapes(li))
+                 for li in range(len(m.levels))]
     xhat = bk.reconstruct(m.shape, m.interp, anchors, yhat,
-                          overrides=overrides)
+                          overrides=overrides, out_dtype=dt, dtype=dt)
     _count(counters, "reconstruct")
-    full_err = m.eb + sum(
-        float(lv.delta_table[lv.nbits]) *
-        loader._prop_factor(m, lv.level, loader.SAFE)
-        for lv in m.levels)
-    return RetrievalState(reader=reader,
-                          planes_loaded=[0] * len(m.levels),
-                          nb_partial=[np.zeros(lv.n, np.uint32) for lv in m.levels],
-                          esc_idx=[o[0] for o in overrides],
-                          xhat=xhat, err_bound=full_err,
-                          bytes_read=reader.bytes_read)
+    return _new_state(reader, anchors, overrides, xhat)
+
+
+def _level_update(m, nb_new: np.ndarray, nb_old: np.ndarray, dy):
+    """A level's contribution in the archive's arithmetic: the float64
+    Algorithm 2 delta against ``nb_old``, or the float32 residual of
+    ``nb_new``.  ``dy`` is the fused kernel's result (same bits), or None
+    to compute it on the host."""
+    if dy is not None:
+        return dy
+    if m.work_dtype == np.float32:
+        return arith.dequantize(negabinary.from_negabinary(nb_new),
+                                arith.consts(m.eb, np.float32))
+    dq = negabinary.from_negabinary(nb_new) - \
+        negabinary.from_negabinary(nb_old)
+    return dq.astype(np.float64) * 2.0 * m.eb
+
+
+def _apply_level(state: RetrievalState, li: int, dy, delta_y) -> None:
+    """File a level's contribution: float32 sessions replace the level's
+    residual, float64 sessions queue the delta for the cascade."""
+    if state.res is not None:
+        state.res[li] = dy
+    else:
+        delta_y[li] = dy
 
 
 def load_level_deltas(state: RetrievalState, keep_planes: List[int],
@@ -211,7 +266,8 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
     re-decoded from the already-fetched blobs (the reader caches fetched
     ranges; re-reads of the same tag are not double-counted).  The returned
     stream is the *difference* of dequantized residuals — the input of the
-    zero-anchor cascade in :func:`push_delta`.
+    zero-anchor cascade in :func:`push_delta`; float32 sessions instead
+    update ``state.res`` in place (their deltas stay zero).
 
     With a ``cache`` and a cache-scoped reader, the decoded prefix is
     looked up under ``(scope, level, prefix)`` first: a hit skips the
@@ -220,16 +276,17 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
     result for other sessions.
 
     Backends shipping the fused decode slots get two upgrades here: each
-    level's unpack + dequantize + delta runs as ONE
-    ``decode_level_fused`` launch (no host negabinary passes), and the
-    next level's zlib inflate (``inflate_level``) is prefetched on a
-    worker thread while the current level's kernel runs.  Bits are
-    unchanged either way — the fused delta arithmetic is pinned identical
-    to the host spelling by the parity suite.
+    level's unpack + dequantize runs as ONE ``decode_level_fused`` launch
+    (no host negabinary passes), and the next level's zlib inflate
+    (``inflate_level``) is prefetched on a worker thread while the current
+    level's kernel runs.  Bits are unchanged either way — the fused
+    arithmetic is pinned identical to the host spelling by the parity
+    suite.
     """
     m = state.reader.meta
     L = len(m.levels)
-    delta_y: List[Optional[np.ndarray]] = [None] * L
+    dt = m.work_dtype
+    delta_y: List[np.ndarray] = [np.zeros(lv.n, dt) for lv in m.levels]
     any_new = False
     fused = bk.decode_level_fused is not None
     djobs: List[Tuple[int, object, int, object, list]] = []
@@ -237,7 +294,6 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
         have = state.planes_loaded[li]
         want = max(have, keep_planes[li])
         if want <= have:
-            delta_y[li] = np.zeros(lv.n, np.float64)
             continue
         any_new = True
         key = _cache_key(state.reader, li, want) \
@@ -247,9 +303,8 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
             cache.saved_fetch(sum(
                 lv.plane_sizes[i] for i in range(want)
                 if not state.reader.plane_fetched(li, i)))
-            dq = negabinary.from_negabinary(nb_new) - \
-                negabinary.from_negabinary(state.nb_partial[li])
-            delta_y[li] = dq.astype(np.float64) * 2.0 * m.eb
+            _apply_level(state, li, _level_update(
+                m, nb_new, state.nb_partial[li], None), delta_y)
             state.nb_partial[li] = nb_new
             state.planes_loaded[li] = want
             continue
@@ -270,20 +325,20 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
                                              nlv.nbits, nlv.n)
             else:
                 fut = None
+        dy = None
         if fused:
             nb_new, dy = bk.decode_level_fused(blobs, lv.nbits, lv.n,
                                                state.nb_partial[li], m.eb,
-                                               words=words)
+                                               words=words, dtype=dt)
         else:
             nb_new = bk.decode_level(blobs, lv.nbits, lv.n)
-            dq = negabinary.from_negabinary(nb_new) - \
-                negabinary.from_negabinary(state.nb_partial[li])
-            dy = dq.astype(np.float64) * 2.0 * m.eb
         _count(counters, "decode_level")
         nb_new = np.asarray(nb_new)
         if key is not None:
             cache.put(key, _freeze(nb_new))
-        delta_y[li] = dy
+        _apply_level(state, li, _level_update(m, nb_new,
+                                              state.nb_partial[li], dy),
+                     delta_y)
         state.nb_partial[li] = nb_new
         state.planes_loaded[li] = want
     return delta_y, any_new
@@ -291,10 +346,21 @@ def load_level_deltas(state: RetrievalState, keep_planes: List[int],
 
 def push_delta(state: RetrievalState, delta_y: List[np.ndarray],
                bk: CodecBackend, counters=None) -> None:
-    """Algorithm 2 core: reconstruct the residual deltas through the sweep
-    with zero anchors (linearity) and add onto the previous ``xhat``.
-    Escaped points are exact from the first pass: their delta is pinned 0."""
+    """Apply a load step to ``xhat``.  Float64: Algorithm 2 core —
+    reconstruct the residual deltas through the sweep with zero anchors
+    (linearity) and add onto the previous ``xhat``; escaped points are
+    exact from the first pass, so their delta is pinned 0.  Float32:
+    re-sweep from the anchors with the current residuals and the exact
+    escape values."""
     m = state.reader.meta
+    dt = m.work_dtype
+    if state.res is not None:
+        state.xhat = bk.reconstruct(
+            m.shape, m.interp, state.anchors, state.res,
+            overrides=list(zip(state.esc_idx, state.esc_val)),
+            out_dtype=dt, dtype=dt)
+        _count(counters, "reconstruct")
+        return
     zero_anchors = np.zeros(m.anchors_shape, np.float64)
     zero_ovr = [(idx, np.zeros(idx.size)) for idx in state.esc_idx]
     delta = bk.reconstruct(m.shape, m.interp, zero_anchors, delta_y,
@@ -307,9 +373,8 @@ def update_achieved_bound(state: RetrievalState, propagation: str) -> None:
     """Recompute the guaranteed bound from the *union* of loaded planes."""
     m = state.reader.meta
     errs, _ = loader._level_cost_tables(m, propagation)
-    state.err_bound = m.eb + sum(
-        float(errs[li][lv.nbits - state.planes_loaded[li]])
-        for li, lv in enumerate(m.levels))
+    state.err_bound = loader.plan_bound(m, state.planes_loaded, errs,
+                                        propagation)
     state.bytes_read = state.reader.bytes_read
 
 
@@ -337,15 +402,17 @@ def update_achieved_bound(state: RetrievalState, propagation: str) -> None:
 # unsharded and vice versa.
 
 def _stack_reconstruct(ctx: ExecContext, shape, interp, anchors, yhat,
-                       overrides):
+                       overrides, dtype):
     """Group reconstruct through the sharded slot when a mesh is active,
     the batched slot otherwise (callers have already ruled out B == 1)."""
     bk = ctx.bk
     if ctx.mesh is not None and bk.reconstruct_sharded is not None:
         return bk.reconstruct_sharded(shape, interp, anchors, yhat,
-                                      ctx.mesh, overrides=overrides)
+                                      ctx.mesh, overrides=overrides,
+                                      out_dtype=dtype, dtype=dtype)
     return bk.reconstruct_batch(shape, interp, anchors, yhat,
-                                overrides=overrides)
+                                overrides=overrides, out_dtype=dtype,
+                                dtype=dtype)
 
 
 def initial_state_batch(readers: List[ArchiveReader],
@@ -358,26 +425,16 @@ def initial_state_batch(readers: List[ArchiveReader],
             or len(readers) == 1):
         return [initial_state(r, bk, counters=counters) for r in readers]
     m0 = readers[0].meta
-    anchors = np.stack([r.anchors() for r in readers])
-    yhat = [np.zeros((len(readers), lv.n), np.float64) for lv in m0.levels]
+    dt = m0.work_dtype
+    anchors = [r.anchors() for r in readers]
+    yhat = [np.zeros((len(readers), lv.n), dt) for lv in m0.levels]
     overrides = [[_unpack_escapes(r.escapes(li))
                   for li in range(len(r.meta.levels))] for r in readers]
-    xhat = _stack_reconstruct(ctx, m0.shape, m0.interp, anchors, yhat,
-                              overrides)
+    xhat = _stack_reconstruct(ctx, m0.shape, m0.interp, np.stack(anchors),
+                              yhat, overrides, dt)
     _count(counters, "reconstruct")
-    states = []
-    for b, r in enumerate(readers):
-        m = r.meta
-        full_err = m.eb + sum(
-            float(lv.delta_table[lv.nbits]) *
-            loader._prop_factor(m, lv.level, loader.SAFE)
-            for lv in m.levels)
-        states.append(RetrievalState(
-            reader=r, planes_loaded=[0] * len(m.levels),
-            nb_partial=[np.zeros(lv.n, np.uint32) for lv in m.levels],
-            esc_idx=[o[0] for o in overrides[b]],
-            xhat=xhat[b], err_bound=full_err, bytes_read=r.bytes_read))
-    return states
+    return [_new_state(r, anchors[b], overrides[b], xhat[b])
+            for b, r in enumerate(readers)]
 
 
 def load_level_deltas_batch(states: List[RetrievalState],
@@ -417,6 +474,7 @@ def load_level_deltas_batch(states: List[RetrievalState],
     delta_ys: List[List[Optional[np.ndarray]]] = \
         [[None] * L for _ in range(B)]
     any_new = [False] * B
+    dt = m0.work_dtype
     fused = bk.decode_level_fused_batch is not None
     jobs_per_level: List[List[Tuple[int, int]]] = [[] for _ in range(L)]
     resolved: dict = {}        # (level, chunk pos) -> (nb_new, delta|None)
@@ -430,7 +488,7 @@ def load_level_deltas_batch(states: List[RetrievalState],
             if want > have:
                 jobs.append((b, want))
             else:
-                delta_ys[b][li] = np.zeros(lv0.n, np.float64)
+                delta_ys[b][li] = np.zeros(lv0.n, dt)
         jobs_per_level[li] = jobs
         # resolve cache hits and dedupe same-(scope, prefix) decode jobs
         decode_jobs: List[Tuple[int, int]] = []
@@ -491,10 +549,11 @@ def load_level_deltas_batch(states: List[RetrievalState],
                     and len(bs) > 1):
                 outs = bk.decode_level_fused_sharded(blob_lists, nbits, n,
                                                      nb_olds, ebs, mesh,
-                                                     words=words)
+                                                     words=words, dtype=dt)
             else:
                 outs = bk.decode_level_fused_batch(blob_lists, nbits, n,
-                                                   nb_olds, ebs, words=words)
+                                                   nb_olds, ebs, words=words,
+                                                   dtype=dt)
             _count(counters, "decode_level")
         elif (mesh is not None and bk.decode_level_sharded is not None
                 and len(bs) > 1):
@@ -522,10 +581,10 @@ def load_level_deltas_batch(states: List[RetrievalState],
         for b, want in jobs_per_level[li]:
             nb_new, dy = resolved[(li, b)]
             st = states[b]
-            if dy is None:
-                dq = negabinary.from_negabinary(nb_new) - \
-                    negabinary.from_negabinary(st.nb_partial[li])
-                dy = dq.astype(np.float64) * 2.0 * st.reader.meta.eb
+            dy = _level_update(st.reader.meta, nb_new, st.nb_partial[li], dy)
+            if st.res is not None:
+                st.res[li] = dy
+                dy = np.zeros(dy.size, dt)
             delta_ys[b][li] = dy
             st.nb_partial[li] = nb_new
             st.planes_loaded[li] = want
@@ -536,10 +595,10 @@ def load_level_deltas_batch(states: List[RetrievalState],
 def push_delta_batch(states: List[RetrievalState],
                      delta_ys: List[List[np.ndarray]],
                      ctx: ExecContext, counters=None) -> None:
-    """Batched :func:`push_delta`: one zero-anchor cascade reconstructs
-    every chunk's delta in a single stack (escape deltas pinned 0 per
-    chunk, as in the scalar path), mesh-sharded when the context carries
-    a mesh."""
+    """Batched :func:`push_delta`: one sweep updates every chunk of the
+    stack — the zero-anchor delta cascade (float64, escape deltas pinned 0
+    per chunk) or the from-anchors re-sweep (float32) — mesh-sharded when
+    the context carries a mesh."""
     bk = ctx.bk
     if ((bk.reconstruct_batch is None and bk.reconstruct_sharded is None)
             or len(states) == 1):
@@ -547,14 +606,25 @@ def push_delta_batch(states: List[RetrievalState],
             push_delta(st, dy, bk, counters=counters)
         return
     m0 = states[0].reader.meta
+    dt = m0.work_dtype
     B = len(states)
+    nl = len(m0.levels)
+    if states[0].res is not None:
+        xhat = _stack_reconstruct(
+            ctx, m0.shape, m0.interp, np.stack([st.anchors for st in states]),
+            [np.stack([st.res[li] for st in states]) for li in range(nl)],
+            [list(zip(st.esc_idx, st.esc_val)) for st in states], dt)
+        _count(counters, "reconstruct")
+        for b, st in enumerate(states):
+            st.xhat = xhat[b]
+        return
     zero_anchors = np.zeros((B,) + tuple(m0.anchors_shape), np.float64)
     yhat = [np.stack([delta_ys[b][li] for b in range(B)])
-            for li in range(len(m0.levels))]
+            for li in range(nl)]
     overrides = [[(idx, np.zeros(idx.size)) for idx in st.esc_idx]
                  for st in states]
     delta = _stack_reconstruct(ctx, m0.shape, m0.interp, zero_anchors,
-                               yhat, overrides)
+                               yhat, overrides, dt)
     _count(counters, "reconstruct")
     for b, st in enumerate(states):
         st.xhat = st.xhat + delta[b]

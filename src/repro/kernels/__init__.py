@@ -6,14 +6,12 @@ metadata-sized):
 
   interp_quant    — fused interpolation-predict + quantize for one dimension
                     sweep (the O(n) inner loop of §4.1); returns (q, pred) so
-                    the archive-canonical dequant-writeback stays in numpy.
+                    the escape screen and writeback stay with the host
+                    (``core.arith.screen``, shared by every backend).
   interp_recon    — its exact inverse: fused predict + add-residual for one
                     reconstruction sweep (the hot loop of retrieval,
                     Algorithms 1–2); shares the prediction code with
-                    interp_quant so both directions are bit-identical.  Its
-                    ``interp_recon_level`` entry runs BOTH (level, dim)
-                    phases of a 2-D level plus the escape overrides in one
-                    launch on the level's stride-s subgrid.
+                    interp_quant so both directions are bit-identical.
   bitplane_pack   — negabinary conversion + 2-bit-prefix XOR predictive
                     coding + cross-lane bitplane packing (§4.4) in a single
                     VMEM pass (three integer ops per element).
@@ -23,9 +21,10 @@ metadata-sized):
                     (``low_zero``) is a RUNTIME operand, so batched streams
                     with different loaded-plane prefixes share one launch.
   decode_fused    — the progressive-decode megakernel: bitplane_unpack +
-                    negabinary dequantize + Algorithm 2's delta against the
-                    session's previous truncation, one launch per level;
-                    ``low_zero`` and the error bound ride along as runtime
+                    negabinary dequantize in the field's arithmetic (the
+                    float32 residual, or Algorithm 2's float64 delta against
+                    the session's previous truncation), one launch per
+                    level; ``low_zero`` and the scale ride along as runtime
                     per-row operands.
 
 All five are wired into ``core.jax_backend`` behind the
@@ -51,6 +50,10 @@ the parity sweeps).  ``kernels.mode`` selects the substrate per call:
 ``IPCOMP_KERNEL_MODE=xla`` routes every wrapper to its jitted pure-jnp
 twin — the same core functions, compiled by XLA on any backend — which is
 what CI's ``compiled`` lane runs on CPU, where Pallas itself is
-interpret-only.  BlockSpecs are written for TPU v5e VMEM tiling
-(8x128-aligned).
+interpret-only; on a TPU that mode raises.  Every kernel body is built
+from operations the chip's compiler (Mosaic) accepts — elementwise math
+over (rows, lanes) blocks, no strided or unaligned lane slices, no lane
+reshapes, no unsigned reductions, no float64 — and
+``tests/test_tpu_compile.py`` compiles each for a v5e at real widths.
+Arithmetic follows ``core.arith``: a float32 field computes in float32.
 """

@@ -8,7 +8,15 @@ so the kernel converts q -> negabinary -> XOR-encoded word in O(1) VPU ops
 per element, then bit-transposes lanes into packed uint32 plane words
 (32 lanes -> one word per plane, MSB-first within the word).
 
-Block layout: (ROWS_B, LANES) int32 in VMEM; output (32, ROWS_B, LANES/32).
+Block layout: the wrapper transposes each row of 32*W bins so that
+element j of every 32-element group sits on the leading axis — (32, R, W)
+int32, ``qt[j, r, w] = q[r, 32*w + j]`` — and the kernel transposes each
+32 x 32 bit matrix (rows = the 32 elements' encoded words) so that bit k
+of slab j lands at bit (31 - j) of plane k's word: shifts, XORs and ANDs
+over (ROWS_B, W) tiles, with no lane reshape, no reversal and no
+reduction (the chip's compiler supports neither a lane-splitting reshape
+nor an unsigned reduction).  Output (32, R, W): plane k, MSB-first within
+each word.
 
 The decode direction (``bitplane_unpack_pallas``) is the exact inverse with
 the same collapsed-word trick: unpacked plane bits are OR-merged back into
@@ -42,21 +50,37 @@ GROUP = 32          # lanes packed per output word
 NEG_M = np.uint32(0xAAAAAAAA)
 
 
-def _kernel(q_ref, out_ref, *, C: int):
-    q = q_ref[...]
-    u = q.astype(jnp.uint32)
-    nb = (u + NEG_M) ^ NEG_M                        # negabinary (§4.4.2)
-    enc = nb ^ (nb >> jnp.uint32(1)) ^ (nb >> jnp.uint32(2))  # 2-bit-prefix XOR
-    R = enc.shape[0]
-    g = enc.reshape(R, C // GROUP, GROUP)
-    # pack bit k of 32 consecutive lanes into one uint32 word, lane 0 = MSB.
-    # weight exponents come from an in-kernel iota (vector constants cannot
-    # be captured by a Pallas kernel body).
-    j = jax.lax.broadcasted_iota(jnp.uint32, g.shape, dimension=2)
-    shift = jnp.uint32(GROUP - 1) - j
-    for k in range(32):
-        bits = (g >> jnp.uint32(k)) & jnp.uint32(1)
-        out_ref[k, :, :] = jnp.sum(bits << shift, axis=-1, dtype=jnp.uint32)
+def pack_core(qt):
+    """(32, R, W) int32 element-major bins -> 32 XOR-coded plane word
+    arrays (R, W) uint32, plane k first.  Shared by the Pallas kernel
+    body and the XLA twin.
+
+    Packing is a 32x32 bit-matrix transpose per word position: row j is
+    element j's encoded word, and plane k's word holds bit k of every row
+    (row j at bit 31 - j).  The transpose runs as the five masked swap
+    stages of Hacker's Delight's transpose32, each one vectorized over
+    the stacked rows (a leading-axis reshape pairs row i with row i + j).
+    """
+    u32 = jnp.uint32
+    nb = (qt.astype(u32) + NEG_M) ^ NEG_M           # negabinary (§4.4.2)
+    a = nb ^ (nb >> u32(1)) ^ (nb >> u32(2))        # 2-bit-prefix XOR
+    R, W = a.shape[1:]
+    j, m = 16, 0x0000FFFF
+    while j:
+        x = a.reshape(GROUP // (2 * j), 2, j, R, W)
+        lo, hi = x[:, 0], x[:, 1]
+        t = (lo ^ (hi >> u32(j))) & u32(m)
+        a = jnp.stack([lo ^ t, hi ^ (t << u32(j))], axis=1)
+        a = a.reshape(GROUP, R, W)
+        j >>= 1
+        m ^= (m << j) & 0xFFFFFFFF
+    # row r now holds bit (31 - r) of every input word
+    return [a[GROUP - 1 - k] for k in range(32)]
+
+
+def _kernel(q_ref, out_ref):
+    for k, p in enumerate(pack_core(q_ref[...])):
+        out_ref[k, :, :] = p
 
 
 def unpack_words(planes, lz, *, W: int):
@@ -118,6 +142,7 @@ def bitplane_unpack_pallas(planes: jax.Array, low_zero: jax.Array, *,
     bspec_out = pl.BlockSpec((ROWS_B, W * GROUP), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_unpack_kernel, W=W),
+        name="bitplane_unpack",
         grid=grid,
         in_specs=[pl.BlockSpec((32, ROWS_B, W), lambda i: (0, i, 0)),
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
@@ -137,28 +162,17 @@ def bitplane_unpack_xla(planes: jax.Array, low_zero: jax.Array):
     return unpack_words(planes, low_zero[0, 0], W=W)
 
 
-def pack_words(q, *, C: int):
-    """Pure core of the pack direction: (R, C) int32 -> (32, R, C//GROUP)
-    uint32 XOR-coded plane words (the XLA twin of ``_kernel``; same
-    arithmetic, stacked output instead of per-plane ref writes)."""
-    u = q.astype(jnp.uint32)
-    nb = (u + NEG_M) ^ NEG_M
-    enc = nb ^ (nb >> jnp.uint32(1)) ^ (nb >> jnp.uint32(2))
-    R = enc.shape[0]
-    g = enc.reshape(R, C // GROUP, GROUP)
-    j = jax.lax.broadcasted_iota(jnp.uint32, g.shape, dimension=2)
-    shift = jnp.uint32(GROUP - 1) - j
-    return jnp.stack([
-        jnp.sum(((g >> jnp.uint32(k)) & jnp.uint32(1)) << shift, axis=-1,
-                dtype=jnp.uint32)
-        for k in range(32)])
+def element_major(q):
+    """(R, C) int32 bins, C % GROUP == 0 -> the kernel's (32, R, C//GROUP)
+    element-major layout (an XLA transpose in the wrapper)."""
+    R, C = q.shape
+    return jnp.transpose(q.reshape(R, C // GROUP, GROUP), (2, 0, 1))
 
 
 @jax.jit
 def bitplane_pack_xla(q: jax.Array):
     """Jitted XLA twin of :func:`bitplane_pack_pallas`."""
-    R, C = q.shape
-    return pack_words(q, C=C)
+    return jnp.stack(pack_core(element_major(q)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -170,12 +184,14 @@ def bitplane_pack_pallas(q: jax.Array, *, interpret: bool = True):
     """
     R, C = q.shape
     assert R % ROWS_B == 0 and C % GROUP == 0
-    grid = (R // ROWS_B,)
+    W = C // GROUP
+    spec = pl.BlockSpec((32, ROWS_B, W), lambda i: (0, i, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, C=C),
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROWS_B, C), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((32, ROWS_B, C // GROUP), lambda i: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((32, R, C // GROUP), jnp.uint32),
+        _kernel,
+        grid=(R // ROWS_B,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((32, R, W), jnp.uint32),
         interpret=interpret,
-    )(q)
+        name="bitplane_pack",
+    )(element_major(q))

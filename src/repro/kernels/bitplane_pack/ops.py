@@ -35,6 +35,7 @@ def bitplane_pack(q, *, interpret: bool | None = None):
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    xla = mode.use_xla()
     q = jnp.asarray(q, jnp.int32)
     if q.ndim == 1:
         n = q.shape[0]
@@ -47,8 +48,9 @@ def bitplane_pack(q, *, interpret: bool | None = None):
     pr, pc = (-R) % ROWS_B, (-C) % GROUP
     if pr or pc:
         q = jnp.pad(q, ((0, pr), (0, pc)))
-    dispatch.record("bitplane_pack", nbytes=2 * q.size * 4)
-    if mode.use_xla():
+    dispatch.record("bitplane_pack", interpret=interpret and not xla,
+                    nbytes=2 * q.size * 4)
+    if xla:
         packed = bitplane_pack_xla(q)
     else:
         packed = bitplane_pack_pallas(q, interpret=interpret)
@@ -71,6 +73,7 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    xla = mode.use_xla()
     q = jnp.asarray(q, jnp.int32)
     B, n = q.shape
     C = 128 * GROUP
@@ -84,7 +87,7 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
     if pr:
         q = jnp.pad(q, ((0, 0), (0, pr), (0, 0)))
 
-    if mode.use_xla():
+    if xla:
         def kernel(a):
             return bitplane_pack_xla(a)
     else:
@@ -93,11 +96,13 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
 
     nbytes = 2 * q.size * 4
     if mesh is None:
-        dispatch.record("bitplane_pack", batch=B, nbytes=nbytes)
+        dispatch.record("bitplane_pack", interpret=interpret and not xla,
+                        batch=B, nbytes=nbytes)
         packed = jax.vmap(kernel)(q)
     else:
-        dispatch.record("bitplane_pack", batch=B,
-                        devices=codec_mesh.shard_count(mesh), nbytes=nbytes)
+        dispatch.record("bitplane_pack", interpret=interpret and not xla,
+                        batch=B, devices=codec_mesh.shard_count(mesh),
+                        nbytes=nbytes)
         packed = codec_mesh.shard_vmap(kernel, mesh)(q)
     return packed[:B], n
 
@@ -126,6 +131,7 @@ def bitplane_unpack(plane_words, n: int, *, low_zero: int = 0,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    xla = mode.use_xla()
     pw = jnp.asarray(plane_words, jnp.uint32)
     P, NW = pw.shape
     assert P == 32, "expect one row per negabinary digit"
@@ -137,9 +143,9 @@ def bitplane_unpack(plane_words, n: int, *, low_zero: int = 0,
     pw = pw.reshape(32, R, _UNPACK_W)
     lz = _lz_array(low_zero)
     # traffic: packed planes in + (q, nb) out
-    dispatch.record("bitplane_unpack",
+    dispatch.record("bitplane_unpack", interpret=interpret and not xla,
                     nbytes=(pw.size + 2 * R * _UNPACK_W * GROUP) * 4)
-    if mode.use_xla():
+    if xla:
         q, nb = bitplane_unpack_xla(pw, lz)
     else:
         q, nb = bitplane_unpack_pallas(pw, lz, interpret=interpret)
@@ -169,6 +175,7 @@ def bitplane_unpack_batch(plane_words, n: int, *, low_zero=0,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    xla = mode.use_xla()
     pw = jnp.asarray(plane_words, jnp.uint32)
     B, P, NW = pw.shape
     assert P == 32, "expect one row per negabinary digit"
@@ -186,7 +193,7 @@ def bitplane_unpack_batch(plane_words, n: int, *, low_zero=0,
     if padb:
         lz = jnp.pad(lz, ((0, padb), (0, 0), (0, 0)))
 
-    if mode.use_xla():
+    if xla:
         def kernel(a, z):
             return bitplane_unpack_xla(a, z)
     else:
@@ -195,11 +202,13 @@ def bitplane_unpack_batch(plane_words, n: int, *, low_zero=0,
 
     nbytes = (pw.size + 2 * (B + padb) * R * _UNPACK_W * GROUP) * 4
     if mesh is None:
-        dispatch.record("bitplane_unpack", batch=B, nbytes=nbytes)
+        dispatch.record("bitplane_unpack", interpret=interpret and not xla,
+                        batch=B, nbytes=nbytes)
         q, nb = jax.vmap(kernel)(pw, lz)
     else:
-        dispatch.record("bitplane_unpack", batch=B,
-                        devices=codec_mesh.shard_count(mesh), nbytes=nbytes)
+        dispatch.record("bitplane_unpack", interpret=interpret and not xla,
+                        batch=B, devices=codec_mesh.shard_count(mesh),
+                        nbytes=nbytes)
         q, nb = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(pw, lz)
     q = q.reshape(B + padb, -1)[:B, :n]
     nb = nb.reshape(B + padb, -1)[:B, :n]

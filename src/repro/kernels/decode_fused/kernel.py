@@ -1,23 +1,23 @@
-"""Fused progressive-decode megakernel (unpack + dequantize-delta).
+"""Fused progressive-decode megakernel (unpack + dequantize).
 
-The retrieval hot path (Algorithm 2 delta cascade) previously did, per
-level: one ``bitplane_unpack`` launch, then THREE host passes over the
-level stream — negabinary-decode the new word, negabinary-decode the old
-word, subtract and scale by ``2 * eb``.  This kernel fuses all of it into
-ONE launch: packed plane words + the previous progressive state (the
-truncated negabinary words the session already holds) go in, the new
-negabinary words and the ready-to-apply f64 residual delta come out.  The
-host never touches the int bins again.
+Per level, ONE launch takes the packed plane words of a loaded prefix and
+returns the new truncated negabinary words plus the level's dequantized
+contribution in the field's arithmetic (``core.arith``):
 
-Bit parity with the host pipeline is exact, not approximate: both old and
-new bins are int32-valued, so their f64 difference is exact (< 2^33), the
-``* 2.0`` is exact, and the single rounding happens at ``* eb`` — the same
-one rounding the host's ``(q_new - q_old).astype(f64) * 2.0 * eb``
-performs.  The spelling ``(dq * 2.0) * eb`` pins the association.
+* float32 fields: the full residual of the new truncation,
+  ``f32(bin(nb_new)) * w`` — the progressive session re-sweeps from the
+  current residuals, so the previous words are not an input;
+* float64 fields (CPU only): Algorithm 2's residual *delta*
+  ``(bin(nb_new) - bin(nb_old)) * 2.0 * eb`` against the session's
+  previous truncation; both bins are int32-valued, so their f64
+  difference is exact and the single rounding is the ``* eb`` the host
+  spelling ``(dq * 2.0) * eb`` performs.
 
-``low_zero`` (plane-prefix truncation) and ``eb`` (level error bound) are
-RUNTIME operands — (1, 1) arrays — so one trace serves every prefix depth
-and every level, and vmapping gives each batched chunk its own pair.
+Either output equals the host arithmetic bit for bit.  ``low_zero``
+(plane-prefix truncation) and the scale (``w`` or ``eb``) are RUNTIME
+(1, 1) operands, so one trace serves every prefix depth and every level,
+and vmapping gives each batched chunk its own pair.  The scale's dtype
+selects the output.
 
 ``decode_fused_core`` is the pure-jnp core shared by the Pallas body and
 the jitted XLA twin (``IPCOMP_KERNEL_MODE=xla``); it builds on
@@ -35,73 +35,73 @@ from jax.experimental import pallas as pl
 from ..bitplane_pack.kernel import GROUP, NEG_M, ROWS_B, unpack_words
 
 
-def decode_fused_core(planes, nb_old, lz, eb, *, W: int):
-    """(32, R, W) packed planes + (R, W*GROUP) previous negabinary words +
-    runtime (lz, eb) -> (nb_new uint32, delta f64), both (R, W*GROUP).
-
-    ``delta`` is the dequantized residual increment the level sweep adds:
-    ``(bin(nb_new) - bin(nb_old)) * 2 * eb``.
+def decode_fused_core(planes, nb_old, lz, scale, *, W: int):
+    """(32, R, W) packed planes + runtime (lz, scale) -> (nb_new uint32,
+    out), both (R, W*GROUP).  ``scale`` float32 = the bin width ``w``:
+    ``out`` is the float32 residual ``f32(bin(nb_new)) * w`` (``nb_old``
+    unused, may be None).  ``scale`` float64 = ``eb``: ``out`` is the f64
+    delta ``(bin(nb_new) - bin(nb_old)) * 2 * eb``.
     """
     q_new, nb_new = unpack_words(planes, lz, W=W)
+    if scale.dtype == jnp.float32:
+        return nb_new, q_new.astype(jnp.float32) * scale
     u_old = (nb_old ^ NEG_M) - NEG_M
     q_old = jax.lax.bitcast_convert_type(u_old, jnp.int32)
     dq = q_new.astype(jnp.float64) - q_old.astype(jnp.float64)
     # one rounding, at * eb — matches the host reference's association
-    delta = (dq * 2.0) * eb.astype(jnp.float64)
-    return nb_new, delta
+    return nb_new, (dq * 2.0) * scale
 
 
-def _fused_kernel(p_ref, old_ref, lz_ref, eb_ref, nb_ref, d_ref, *, W: int):
-    nb_new, delta = decode_fused_core(p_ref[...], old_ref[...],
-                                      lz_ref[0, 0], eb_ref[0, 0], W=W)
+def _fused_kernel(*refs, W: int, with_old: bool):
+    it = iter(refs)
+    p_ref = next(it)
+    old = next(it)[...] if with_old else None
+    lz_ref, sc_ref, nb_ref, out_ref = it
+    nb_new, out = decode_fused_core(p_ref[...], old, lz_ref[0, 0],
+                                    sc_ref[0, 0], W=W)
     nb_ref[...] = nb_new
-    d_ref[...] = delta
-
-
-def _rows_block(R: int) -> int:
-    """Row-block size: whole array when small, else the largest multiple of
-    ROWS_B that divides R and stays <= 64 — fewer grid steps than the
-    unfused unpack's fixed ROWS_B, which matters in interpret mode where
-    every grid step is a Python-level iteration."""
-    if R <= 64:
-        return R
-    for rb in (64, 32, 16):
-        if R % rb == 0:
-            return rb
-    return ROWS_B
+    out_ref[...] = out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_fused_pallas(planes: jax.Array, nb_old: jax.Array,
-                        low_zero: jax.Array, eb: jax.Array, *,
-                        interpret: bool = True):
+def decode_fused_pallas(planes: jax.Array, nb_old, low_zero: jax.Array,
+                        scale: jax.Array, *, interpret: bool = True):
     """planes: (32, R, W) uint32; nb_old: (R, W*32) uint32 previous
-    progressive words; low_zero, eb: (1, 1) runtime operands.  Returns
-    (nb_new (R, W*32) uint32, delta (R, W*32) f64).
-    """
+    progressive words (float64 delta mode) or None (float32 mode);
+    low_zero, scale: (1, 1) runtime operands.  Returns (nb_new (R, W*32)
+    uint32, out (R, W*32) in ``scale``'s dtype)."""
     P, R, W = planes.shape
     assert P == 32 and R % ROWS_B == 0
-    assert nb_old.shape == (R, W * GROUP)
-    RB = _rows_block(R)
-    grid = (R // RB,)
+    with_old = scale.dtype != jnp.float32
+    # ROWS_B rows per step, like bitplane_unpack: the unpack's (rows, W,
+    # 32) intermediates are lane-padded 4x in VMEM, and 16-row blocks
+    # already overflow it on a v5e
+    RB = ROWS_B
     bspec_sc = pl.BlockSpec((1, 1), lambda i: (0, 0))
     bspec_row = pl.BlockSpec((RB, W * GROUP), lambda i: (i, 0))
+    in_specs = [pl.BlockSpec((32, RB, W), lambda i: (0, i, 0))]
+    args = [planes]
+    if with_old:
+        assert nb_old.shape == (R, W * GROUP)
+        in_specs.append(bspec_row)
+        args.append(nb_old)
     return pl.pallas_call(
-        functools.partial(_fused_kernel, W=W),
-        grid=grid,
-        in_specs=[pl.BlockSpec((32, RB, W), lambda i: (0, i, 0)),
-                  bspec_row, bspec_sc, bspec_sc],
+        functools.partial(_fused_kernel, W=W, with_old=with_old),
+        grid=(R // RB,),
+        in_specs=in_specs + [bspec_sc, bspec_sc],
         out_specs=[bspec_row, bspec_row],
         out_shape=[jax.ShapeDtypeStruct((R, W * GROUP), jnp.uint32),
-                   jax.ShapeDtypeStruct((R, W * GROUP), jnp.float64)],
+                   jax.ShapeDtypeStruct((R, W * GROUP), scale.dtype)],
         interpret=interpret,
-    )(planes, nb_old, low_zero, eb)
+        name="decode_fused",
+    )(*args, low_zero, scale)
 
 
 @jax.jit
-def decode_fused_xla(planes: jax.Array, nb_old: jax.Array,
-                     low_zero: jax.Array, eb: jax.Array):
+def decode_fused_xla(planes: jax.Array, nb_old, low_zero: jax.Array,
+                     scale: jax.Array):
     """Jitted XLA twin of :func:`decode_fused_pallas` (same core, whole
     array, compiled on any backend)."""
     P, R, W = planes.shape
-    return decode_fused_core(planes, nb_old, low_zero[0, 0], eb[0, 0], W=W)
+    return decode_fused_core(planes, nb_old, low_zero[0, 0], scale[0, 0],
+                             W=W)

@@ -44,23 +44,30 @@ _device_counts: Counter = Counter()
 #: array bytes, as accounted by its wrapper) — the numerator of the
 #: roofline report's achieved-bytes/s (``benchmarks/roofline_report.py``)
 _bytes: Counter = Counter()
+#: cumulative launches that ran in the Pallas interpreter (the CPU path);
+#: a run on the chip must record none — ``chip_smoke.py`` checks this
+_interpreted: Counter = Counter()
 
 
 def record(name: str, batch: int = 1, devices: int = 1,
-           nbytes: int = 0) -> None:
+           nbytes: int = 0, interpret: bool = False) -> None:
     """Count one kernel launch covering ``batch`` chunk-sized problems.
 
     ``devices`` is the mesh fan-out of the launch: a ``shard_map``-ed call
     is one *logical* dispatch that runs on ``devices`` devices at once
     (1 = unsharded, the default).  ``nbytes`` is the launch's memory
     traffic (input + output array bytes, pad included — what the launch
-    actually moves), accumulated for roofline accounting.
+    actually moves), accumulated for roofline accounting.  ``interpret``
+    marks a launch that runs in the Pallas interpreter instead of a
+    compiled kernel.
     """
     _counts[name] += 1
     _elements[name] += batch
     _device_counts[name] += devices
     if nbytes:
         _bytes[name] += nbytes
+    if interpret:
+        _interpreted[name] += 1
 
 
 def counts() -> Dict[str, int]:
@@ -87,7 +94,13 @@ def bytes_counts() -> Dict[str, int]:
     return dict(_bytes)
 
 
+def interpreted_counts() -> Dict[str, int]:
+    """Interpreted (non-compiled) launches per kernel since start/reset."""
+    return dict(_interpreted)
+
+
 def reset() -> None:
+    _interpreted.clear()
     _counts.clear()
     _elements.clear()
     _device_counts.clear()

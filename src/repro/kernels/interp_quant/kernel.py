@@ -1,148 +1,142 @@
 """Fused interpolation-predict + quantize Pallas TPU kernel.
 
-One (level, dim) sweep of §4.1 with the sweep axis laid out on lanes:
-for a row-block in VMEM, predict target columns (odd multiples of stride s)
-from neighbour columns at +-s / +-3s, quantize the residual against the
-original values, and emit both the int32 bins and the predictions — one
-HBM round-trip for what the CPU reference does in two gather-heavy passes
-(predict, quantize).  The dequantized writeback ``pred + 2*eb*q`` is left
-to the caller: emitting pred instead of recon keeps the kernel bit-exact
-against the numpy reference regardless of FMA contraction (see below).
+One (level, dim) sweep of §4.1 with the sweep axis laid out on lanes: for
+a row block in VMEM, predict the target columns (odd multiples of stride
+s) from their neighbours at +-s / +-3s and quantize the residual against
+the original values, emitting the int32 bins and the predictions.  The
+dequantized writeback and the escape screen are left to the caller
+(``arith.screen`` on the host), so every backend shares one definition of
+the values that reach the archive.
 
-TPU adaptation (DESIGN.md §3): neighbour access uses *static strided
-slices* (lane-aligned, no gathers); boundary fallback masks are trace-time
-constants; blocks are (ROWS_B x C) so the whole sweep axis sits in VMEM —
-C up to ~16k f32 fits comfortably (8 x 16k x 4B = 512 KiB).
+Layout.  The wrapper de-interleaves the sweep axis before the launch: the
+known points are the even multiples of s, gathered once and padded by one
+column on the left and two on the right (:func:`known`, an XLA strided
+gather outside the kernel, as is the gather of the target columns).
+Target j then sits between known points j and j+1, and the kernel forms
+its four neighbours as contiguous lane slices at offsets 0..3 of that one
+block (:func:`split_known`) — unit-offset slices, which the chip's
+compiler accepts, where the strided lane slices of the interleaved axis
+are refused.  The body is elementwise over (rows, T) blocks.  Boundary
+fallback (cubic -> linear -> copy-left) is a lane-index mask from an
+in-kernel iota, compared against static thresholds.
 
-Bit-exactness vs the numpy backend (backend parity tests): XLA freely
-contracts ``a*b + c`` into fma, which rounds differently from numpy's
-separate mul+add.  Every mul+add pair here is therefore written so that
-contraction cannot change the result: ``9*x`` is computed as ``8*x + x``
-(8*x is exact, so fma(8, x, x) == round(9x) == round(8x + x)), and the
-remaining adds have no adjacent multiply to fuse with.  The final quantize
-uses a divide, which XLA never contracts.
+Arithmetic: ``arith.predict`` / ``arith.bins`` — the same functions the
+numpy reference runs, in the field's dtype (float32 with subnormal
+flushing, or float64).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-ROWS_B = 8  # sublane-aligned row block
+from ...core import arith
+
+ROWS_B = 8  # sublane tile; row blocks are multiples of it
+
+#: VMEM elements per operand block (per buffer): 64 Ki lanes-padded f32
+_BLOCK_ELEMS = 1 << 16
 
 
-def _neighbors(xh, s: int, C: int, T: int):
-    """l3,l1,r1,r3 columns for targets idx=s+2s*j, j<T, via static slices."""
-    # l1: idx-s = 0, 2s, 4s, ...            always valid
-    l1 = xh[:, 0:2 * s * T:2 * s]
-    # r1: idx+s = 2s, 4s, ...               last may exceed C-1
-    r1_valid = [c for c in range(2 * s, C, 2 * s)][:T]
-    r1 = xh[:, 2 * s:2 * s * (len(r1_valid)) + 1:2 * s]
-    if len(r1_valid) < T:  # clamp: reuse l1's last column (copy-left fallback)
-        r1 = jnp.concatenate([r1, l1[:, len(r1_valid):T]], axis=1)
-    # l3: idx-3s = -2s, 0, 2s, ...          first invalid -> clamp to col 0
-    l3 = jnp.concatenate([xh[:, 0:1], xh[:, 0:2 * s * (T - 1):2 * s]], axis=1) \
-        if T > 1 else xh[:, 0:1]
-    # r3: idx+3s = 4s, 6s, ...              tail may exceed -> clamp to last valid
-    r3_cols = [min(c, C - 1) for c in range(4 * s, 4 * s + 2 * s * T, 2 * s)]
-    # static slices where possible, then patch the clamped tail
-    n_ok = sum(1 for c in range(4 * s, 4 * s + 2 * s * T, 2 * s) if c <= C - 1)
-    r3_main = xh[:, 4 * s:4 * s + 2 * s * n_ok:2 * s]
-    if n_ok < T:
-        r3 = jnp.concatenate([r3_main,
-                              jnp.repeat(xh[:, C - 1:C], T - n_ok, axis=1)], axis=1)
-    else:
-        r3 = r3_main
-    return l3, l1, r1, r3
+def sweep_geometry(C: int, s: int):
+    """(T, Ne): target count and known-point count of a stride-s sweep
+    over an axis of length C."""
+    return len(range(s, C, 2 * s)), len(range(0, C, 2 * s))
 
 
-def _masks(s: int, C: int, T: int) -> Tuple[np.ndarray, np.ndarray]:
-    idx = np.arange(s, C, 2 * s)[:T]
-    r_ok = idx + s <= C - 1
-    cubic_ok = (idx - 3 * s >= 0) & (idx + 3 * s <= C - 1) & r_ok
-    return cubic_ok, r_ok
+def row_block(R: int, T: int) -> int:
+    """Rows per grid step: about ``_BLOCK_ELEMS`` lane-padded elements per
+    operand block, a multiple of ``ROWS_B``, at most R rounded up."""
+    lanes = -(-T // 128) * 128
+    rb = max(ROWS_B, min(512, _BLOCK_ELEMS // lanes) // ROWS_B * ROWS_B)
+    return min(rb, -(-R // ROWS_B) * ROWS_B)
 
 
-def _select_runs(parts_by_choice, choice: np.ndarray):
-    """Assemble pred from static runs of identical boundary choice.
-
-    Boundary fallback only happens at the edges, so ``choice`` has <= 4 runs;
-    static concatenation of slices avoids both vector-constant captures
-    (disallowed in Pallas kernels) and per-lane selects.
-    """
-    T = choice.size
-    runs, start = [], 0
-    for j in range(1, T + 1):
-        if j == T or choice[j] != choice[start]:
-            runs.append((start, j, int(choice[start])))
-            start = j
-    parts = [parts_by_choice[c][:, a:b] for a, b, c in runs]
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+def known(xh, s: int):
+    """(R, C) surface -> (R, Ne + 3): its known points (even multiples of
+    s), padded by one copy of the first on the left and two of the last on
+    the right."""
+    e = xh[..., ::2 * s]
+    return jnp.concatenate([e[..., :1], e, e[..., -1:], e[..., -1:]],
+                           axis=-1)
 
 
-def _predict(xh, *, s: int, interp: str, C: int, T: int):
-    """Phase-sweep prediction for target columns, shared by the encode
-    (interp_quant) and decode (interp_recon) kernels — one definition so the
-    fma-contraction-proof spelling below stays bit-identical on both sides.
-    """
-    l3, l1, r1, r3 = _neighbors(xh, s, C, T)
-    lin = 0.5 * (l1 + r1)
-    cubic_ok, r_ok = _masks(s, C, T)
-    if interp == "linear":
-        return _select_runs({1: lin, 0: l1}, r_ok.astype(np.int8))
-    # 9*x spelled 8*x + x: fma-contraction-proof (8*x is exact), same
-    # association as the numpy reference ((-l3 + 9l1) + 9r1) - r3
-    cub = (-l3 + (8.0 * l1 + l1) + (8.0 * r1 + r1) - r3) * (1.0 / 16.0)
-    choice = np.where(cubic_ok, 2, np.where(r_ok, 1, 0))
-    return _select_runs({2: cub, 1: lin, 0: l1}, choice)
+def split_known(ep, T: int):
+    """:func:`known` points -> (l3, l1, r1, r3), each (R, T): the known
+    points at -3s, -s, +s, +3s of every target, out-of-range neighbours
+    clamped to the nearest known point (masked off by
+    :func:`predict_core`)."""
+    return tuple(ep[..., k:k + T] for k in range(4))
 
 
-def _kernel(x_ref, xh_ref, q_ref, pred_ref, *, s: int, eb: float,
-            interp: str, C: int, T: int):
-    xh = xh_ref[...]
-    x = x_ref[...]
-    pred = _predict(xh, s=s, interp=interp, C=C, T=T)
-    tgt = x[:, s:s + 2 * s * T:2 * s]
-    # divide (not multiply-by-reciprocal): bit-identical rounding vs the oracle
-    q_ref[...] = jnp.rint((tgt - pred) / (2.0 * eb)).astype(jnp.int32)
-    pred_ref[...] = pred.astype(x.dtype)
+def targets(x, s: int):
+    """(R, C) field -> (R, T) values at the target columns."""
+    T, _ = sweep_geometry(x.shape[-1], s)
+    return x[..., s::2 * s][..., :T]
 
 
-@functools.partial(jax.jit, static_argnames=("s", "eb", "interp"))
-def interp_quant_xla(x: jax.Array, xhat: jax.Array, *, s: int, eb: float,
-                     interp: str = "cubic"):
-    """Jitted XLA twin of :func:`interp_quant_pallas`: the shared
-    ``_predict`` core + the same divide-based quantize, compiled on any
-    backend (the ``IPCOMP_KERNEL_MODE=xla`` path)."""
+def predict_core(l3, l1, r1, r3, *, Ne: int, interp: str, ftz: bool):
+    """``arith.predict`` with the boundary masks of a sweep whose known
+    points number ``Ne``: target j has a right neighbour iff j+1 < Ne and
+    the cubic stencil iff also j >= 1 and j+2 < Ne."""
+    j = jax.lax.broadcasted_iota(jnp.int32, l1.shape, l1.ndim - 1)
+    r_ok = j < Ne - 1
+    cubic_ok = (j >= 1) & (j < Ne - 2)
+    return arith.predict(jnp, l3, l1, r1, r3, cubic_ok, r_ok, interp, ftz)
+
+
+def quant_core(l3, l1, r1, r3, tgt, *, Ne: int, interp: str,
+               c: arith.Consts):
+    """(q int32, pred) of one phase block; shared by the kernel body and
+    the XLA twin."""
+    pred = predict_core(l3, l1, r1, r3, Ne=Ne, interp=interp, ftz=c.f32)
+    return arith.bins(jnp, tgt, pred, c, jnp.int32), pred
+
+
+def _kernel(e_ref, t_ref, q_ref, p_ref, *, Ne: int, interp: str,
+            c: arith.Consts):
+    q, pred = quant_core(*split_known(e_ref[...], t_ref.shape[-1]),
+                         t_ref[...], Ne=Ne, interp=interp, c=c)
+    q_ref[...] = q
+    p_ref[...] = pred
+
+
+@functools.partial(jax.jit, static_argnames=("s", "c", "interp"))
+def interp_quant_xla(x: jax.Array, xhat: jax.Array, *, s: int,
+                     c: arith.Consts, interp: str = "cubic"):
+    """Jitted XLA twin of :func:`interp_quant_pallas` (the
+    ``IPCOMP_KERNEL_MODE=xla`` path on CPU): the same gather and core."""
+    T, Ne = sweep_geometry(x.shape[-1], s)
+    return quant_core(*split_known(known(xhat, s), T), targets(x, s),
+                      Ne=Ne, interp=interp, c=c)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("s", "c", "interp", "interpret"))
+def interp_quant_pallas(x: jax.Array, xhat: jax.Array, *, s: int,
+                        c: arith.Consts, interp: str = "cubic",
+                        interpret: bool = True):
+    """x, xhat: (R, C) in the working dtype.  Returns (q (R, T) int32,
+    pred (R, T)) for the targets at odd multiples of s."""
     R, C = x.shape
-    T = len(range(s, C, 2 * s))
-    pred = _predict(xhat, s=s, interp=interp, C=C, T=T)
-    tgt = x[:, s:s + 2 * s * T:2 * s]
-    q = jnp.rint((tgt - pred) / (2.0 * eb)).astype(jnp.int32)
-    return q, pred.astype(x.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("s", "eb", "interp", "interpret"))
-def interp_quant_pallas(x: jax.Array, xhat: jax.Array, *, s: int, eb: float,
-                        interp: str = "cubic", interpret: bool = True):
-    """x, xhat: (R, C) with R % ROWS_B == 0. Returns (q (R,T) i32, pred (R,T))."""
-    R, C = x.shape
-    T = len(range(s, C, 2 * s))
-    assert R % ROWS_B == 0 and T > 0
-    grid = (R // ROWS_B,)
-    bspec_in = pl.BlockSpec((ROWS_B, C), lambda i: (i, 0))
-    bspec_out = pl.BlockSpec((ROWS_B, T), lambda i: (i, 0))
-    kern = functools.partial(_kernel, s=s, eb=eb, interp=interp, C=C, T=T)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[bspec_in, bspec_in],
-        out_specs=[bspec_out, bspec_out],
-        out_shape=[jax.ShapeDtypeStruct((R, T), jnp.int32),
-                   jax.ShapeDtypeStruct((R, T), x.dtype)],
+    T, Ne = sweep_geometry(C, s)
+    assert T > 0
+    ops = [known(xhat, s), targets(x, s)]
+    rb = row_block(R, Ne + 3)
+    pad = (-R) % rb
+    if pad:
+        ops = [jnp.pad(a, ((0, pad), (0, 0))) for a in ops]
+    bspec = pl.BlockSpec((rb, T), lambda i: (i, 0))
+    q, pred = pl.pallas_call(
+        functools.partial(_kernel, Ne=Ne, interp=interp, c=c),
+        grid=((R + pad) // rb,),
+        in_specs=[pl.BlockSpec((rb, Ne + 3), lambda i: (i, 0)), bspec],
+        out_specs=[bspec, bspec],
+        out_shape=[jax.ShapeDtypeStruct((R + pad, T), jnp.int32),
+                   jax.ShapeDtypeStruct((R + pad, T), x.dtype)],
         interpret=interpret,
-    )(x, xhat)
+        name="interp_quant",
+    )(*ops)
+    return q[:R], pred[:R]

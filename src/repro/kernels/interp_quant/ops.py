@@ -1,12 +1,12 @@
-"""Public jit'd wrappers for the fused interpolate+quantize kernel."""
+"""Public wrappers for the fused interpolate+quantize kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from ...core import arith
 from .. import dispatch, mode
-from .kernel import ROWS_B, interp_quant_pallas, interp_quant_xla
+from .kernel import interp_quant_pallas, interp_quant_xla, sweep_geometry
 
 
 def _on_tpu() -> bool:
@@ -15,31 +15,17 @@ def _on_tpu() -> bool:
 
 def interp_quant(x, xhat, *, s: int, eb: float, interp: str = "cubic",
                  interpret: bool | None = None):
-    """Fused phase sweep for arbitrary (R, C): pads rows to the block size.
+    """Fused phase sweep of one (R, C) problem.
 
     Returns (q int32 (R, T), pred (R, T)) for targets at odd multiples of s
-    along the last axis; the dequantized writeback is ``pred + 2*eb*q``
-    (left to the caller so it can be computed with the archive-canonical
-    numpy rounding — see kernel.py on fma contraction).
+    along the last axis, in the arithmetic of ``x``'s dtype (see
+    ``core.arith``); bins out of range are ``arith.QSENTINEL``.  The
+    writeback and escape screen are the caller's (``arith.screen``).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
-    x = jnp.asarray(x)
-    xhat = jnp.asarray(xhat, x.dtype)
-    R, C = x.shape
-    pad = (-R) % ROWS_B
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        xhat = jnp.pad(xhat, ((0, pad), (0, 0)))
-    dispatch.record("interp_quant",
-                    nbytes=(2 * x.size + 2 * R * (x.shape[1] // (2 * s))) *
-                    x.dtype.itemsize)
-    if mode.use_xla():
-        q, pred = interp_quant_xla(x, xhat, s=s, eb=eb, interp=interp)
-    else:
-        q, pred = interp_quant_pallas(x, xhat, s=s, eb=eb, interp=interp,
-                                      interpret=interpret)
-    return q[:R], pred[:R]
+    q, pred = interp_quant_batch(jnp.asarray(x)[None],
+                                 jnp.asarray(xhat)[None], s=s, eb=eb,
+                                 interp=interp, interpret=interpret)
+    return q[0], pred[0]
 
 
 def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
@@ -47,50 +33,54 @@ def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
     """Batched phase sweep over stacked equal-shape chunks: (B, R, C).
 
     ``jax.vmap`` turns the batch axis into an extra grid dimension of ONE
-    kernel launch, so B chunks cost a single dispatch instead of B.  Each
-    batch element is padded/computed exactly like a lone ``interp_quant``
-    call, so per-chunk results are bit-identical to the unbatched path.
+    kernel launch, so B chunks cost a single dispatch instead of B; every
+    operation is elementwise across the batch, so per-chunk results are
+    bit-identical to B lone calls.
 
     With ``mesh`` (a 1-D codec mesh), the batch axis is zero-padded to a
     mesh multiple (``codec_mesh.pad_to_shards``) and ``shard_map`` places
     consecutive rows on consecutive devices, each running the same vmapped
     kernel — one collective-free launch per device, one *logical* dispatch
     total (recorded with ``devices=mesh size``), pad rows sliced off.
-    One function holds both layouts so the byte-critical padding/reshape
-    math cannot drift between them.
     """
     if interpret is None:
         interpret = not _on_tpu()
+    xla = mode.use_xla()
     x = jnp.asarray(x)
     xhat = jnp.asarray(xhat, x.dtype)
+    c = arith.consts(eb, x.dtype)
     B, R, C = x.shape
-    pad = (-R) % ROWS_B
     padb = 0
     if mesh is not None:
         from ...parallel import codec_mesh
         padb = codec_mesh.pad_to_shards(B, mesh)
-    if pad or padb:
-        x = jnp.pad(x, ((0, padb), (0, pad), (0, 0)))
-        xhat = jnp.pad(xhat, ((0, padb), (0, pad), (0, 0)))
+        if padb:
+            x = jnp.pad(x, ((0, padb), (0, 0), (0, 0)))
+            xhat = jnp.pad(xhat, ((0, padb), (0, 0), (0, 0)))
 
-    if mode.use_xla():
+    if xla:
         def kernel(a, b):
-            return interp_quant_xla(a, b, s=s, eb=eb, interp=interp)
+            return interp_quant_xla(a, b, s=s, c=c, interp=interp)
     else:
         def kernel(a, b):
-            return interp_quant_pallas(a, b, s=s, eb=eb, interp=interp,
+            return interp_quant_pallas(a, b, s=s, c=c, interp=interp,
                                        interpret=interpret)
 
-    nbytes = (2 * x.size + 2 * x.shape[0] * x.shape[1] *
-              (x.shape[2] // (2 * s))) * x.dtype.itemsize
+    # the kernel's operands: known points, targets, bins, predictions (the
+    # wrapper's gathers that build the first two are XLA's, not metered)
+    T, Ne = sweep_geometry(C, s)
+    nbytes = (B + padb) * R * ((Ne + 3 + T) * x.dtype.itemsize
+                               + T * (4 + x.dtype.itemsize))
     if mesh is None:
-        dispatch.record("interp_quant", batch=B, nbytes=nbytes)
+        dispatch.record("interp_quant", interpret=interpret and not xla,
+                        batch=B, nbytes=nbytes)
         q, pred = jax.vmap(kernel)(x, xhat)
     else:
-        dispatch.record("interp_quant", batch=B,
-                        devices=codec_mesh.shard_count(mesh), nbytes=nbytes)
+        dispatch.record("interp_quant", interpret=interpret and not xla,
+                        batch=B, devices=codec_mesh.shard_count(mesh),
+                        nbytes=nbytes)
         q, pred = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(x, xhat)
-    return q[:B, :R], pred[:B, :R]
+    return q[:B], pred[:B]
 
 
 def interp_quant_sharded(x, xhat, *, s: int, eb: float, mesh,

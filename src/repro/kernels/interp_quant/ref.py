@@ -3,12 +3,15 @@
 Mirrors repro.core.interpolation.predict_block for a sweep along the last
 axis with stride s: targets are odd multiples of s, neighbours at +-s/+-3s,
 cubic with linear/copy-left boundary fallback, then linear-scale
-quantization q=round(res/2eb).  Like the kernel, returns (q, pred); the
-dequantized writeback pred + 2eb*q belongs to the caller.
+quantization with the contract's quantizer (``arith.bins``).  Like the
+kernel, returns (q, pred); the writeback and escape screen belong to the
+caller.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+
+from ...core import arith
 
 COEF = (-1.0 / 16.0, 9.0 / 16.0, 9.0 / 16.0, -1.0 / 16.0)
 
@@ -37,6 +40,7 @@ def interp_quant_ref(x: jnp.ndarray, xhat: jnp.ndarray, s: int, eb: float,
     n = x.shape[-1]
     idx = jnp.arange(s, n, 2 * s)
     pred = predict_ref(xhat, s, interp)
-    res = x[..., idx] - pred
-    q = jnp.rint(res / (2.0 * eb)).astype(jnp.int32)
-    return q, pred.astype(x.dtype)
+    pred = pred.astype(x.dtype)
+    q = arith.bins(jnp, x[..., idx], pred, arith.consts(eb, x.dtype),
+                   jnp.int32)
+    return q, pred

@@ -1,180 +1,77 @@
 """Fused interpolation-predict + add-residual Pallas TPU kernel (decode).
 
-The exact inverse of ``interp_quant``'s phase sweep: for a row-block in
-VMEM, predict target columns (odd multiples of stride s) from neighbour
-columns of the partially reconstructed surface at +-s / +-3s, then add the
-dequantized residual — one HBM round-trip for what the CPU reference does
-in two gather-heavy passes (predict, add).  This is the hot loop of
-retrieval (paper Algorithms 1–2): every (level, dim) phase of
-``interpolation.reconstruct`` maps to one launch.
+The exact inverse of ``interp_quant``'s phase sweep: for a row block in
+VMEM, predict the target columns (odd multiples of stride s) from their
+neighbours on the partially reconstructed surface, then add the
+dequantized residual.  This is the hot loop of retrieval (paper
+Algorithms 1–2): every (level, dim) phase of
+``interpolation.reconstruct_batch`` maps to one launch.
 
-Bit-exactness vs the numpy decoder: the prediction reuses the encode
-kernel's ``_predict`` verbatim (fma-contraction-proof spelling — see
-``interp_quant.kernel``), and the residual arrives already dequantized
-(f64) so the final ``pred + res`` is a bare add with no adjacent multiply
-for XLA to contract.  The escape-override writeback (exact values at
-escaped points) is left to the caller: it is a scatter of host-resident
-records, and overwriting after the kernel keeps the kernel oblivious to
-the escape channel — same division of labour as the encode path.
+It shares the encode kernel's layout (the wrapper gathers the known points
+of the sweep axis once; the body slices their four neighbours and is
+elementwise) and its
+prediction core, and computes ``arith.recon`` — the writeback the encoder's
+escape screen verified — so decoded bits equal the encoder's.  The residual
+arrives dequantized by the host (``arith.dequantize``), and the
+escape-override writeback stays with the caller.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..interp_quant.kernel import ROWS_B, _predict
+from ...core import arith
+from ..interp_quant.kernel import (known, predict_core, row_block,
+                                   split_known, sweep_geometry)
 
 
-def _kernel(xh_ref, res_ref, out_ref, *, s: int, interp: str, C: int, T: int):
-    xh = xh_ref[...]
-    res = res_ref[...]
-    pred = _predict(xh, s=s, interp=interp, C=C, T=T)
-    # bare add: numpy computes pred and res separately then adds, and there
-    # is no multiply adjacent to this add, so contraction cannot occur
-    out_ref[...] = (pred + res).astype(xh.dtype)
+def recon_core(l3, l1, r1, r3, res, *, Ne: int, interp: str, ftz: bool):
+    """``pred + res`` of one phase block (shared by kernel and XLA twin)."""
+    pred = predict_core(l3, l1, r1, r3, Ne=Ne, interp=interp, ftz=ftz)
+    return arith.recon(jnp, pred, res, ftz)
+
+
+def _kernel(e_ref, res_ref, out_ref, *, Ne: int, interp: str, ftz: bool):
+    out_ref[...] = recon_core(*split_known(e_ref[...], res_ref.shape[-1]),
+                              res_ref[...], Ne=Ne, interp=interp, ftz=ftz)
 
 
 @functools.partial(jax.jit, static_argnames=("s", "interp", "interpret"))
 def interp_recon_pallas(xhat: jax.Array, res: jax.Array, *, s: int,
                         interp: str = "cubic", interpret: bool = True):
-    """xhat: (R, C), res: (R, T) with R % ROWS_B == 0.  Returns recon (R, T):
-    ``pred + res`` at target columns (odd multiples of s)."""
+    """xhat: (R, C), res: (R, T), both in the working dtype.  Returns
+    recon (R, T): ``pred + res`` at the target columns (odd multiples of
+    s)."""
     R, C = xhat.shape
-    T = len(range(s, C, 2 * s))
-    assert R % ROWS_B == 0 and T > 0 and res.shape == (R, T)
-    grid = (R // ROWS_B,)
-    kern = functools.partial(_kernel, s=s, interp=interp, C=C, T=T)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROWS_B, C), lambda i: (i, 0)),
-                  pl.BlockSpec((ROWS_B, T), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((ROWS_B, T), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, T), xhat.dtype),
+    T, Ne = sweep_geometry(C, s)
+    assert T > 0 and res.shape == (R, T)
+    ops = [known(xhat, s), res]
+    rb = row_block(R, Ne + 3)
+    pad = (-R) % rb
+    if pad:
+        ops = [jnp.pad(a, ((0, pad), (0, 0))) for a in ops]
+    bspec = pl.BlockSpec((rb, T), lambda i: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, Ne=Ne, interp=interp,
+                          ftz=xhat.dtype == jnp.float32),
+        grid=((R + pad) // rb,),
+        in_specs=[pl.BlockSpec((rb, Ne + 3), lambda i: (i, 0)), bspec],
+        out_specs=bspec,
+        out_shape=jax.ShapeDtypeStruct((R + pad, T), xhat.dtype),
         interpret=interpret,
-    )(xhat, res)
+        name="interp_recon",
+    )(*ops)
+    return out[:R]
 
 
 @functools.partial(jax.jit, static_argnames=("s", "interp"))
 def interp_recon_xla(xhat: jax.Array, res: jax.Array, *, s: int,
                      interp: str = "cubic"):
-    """Jitted XLA twin of :func:`interp_recon_pallas`: the shared
-    ``_predict`` core over the whole array, compiled on any backend
-    (the ``IPCOMP_KERNEL_MODE=xla`` path)."""
-    R, C = xhat.shape
-    T = len(range(s, C, 2 * s))
-    pred = _predict(xhat, s=s, interp=interp, C=C, T=T)
-    return (pred + res).astype(xhat.dtype)
-
-
-def level_core(g, res0=None, res1=None, m0=None, v0=None, m1=None, v1=None,
-               *, interp: str = "cubic"):
-    """Whole-level reconstruction on the level's subgrid — BOTH (level, dim)
-    phases of a 2-D level in one pass.
-
-    ``g`` is the stride-s subgrid ``xhat[::s, ::s]`` (Ms, Ns): level-s
-    traversal touches ONLY s-multiples, and on the subgrid the stride
-    becomes 1, so the boundary-fallback masks are the full-array masks
-    verbatim (``floor((M-1)/2s) == floor((Ms-1)/2)`` — the clamp counts
-    coincide, which is what makes the subgrid view bit-identical to the
-    strided-view sweeps the host traversal performs).
-
-    Phase 0 (dim 0): predict odd rows from even rows at even columns —
-    ``res0`` is (T0, Nse), T0 = Ms//2, Nse = ceil(Ns/2), the phase's
-    residual block in stream C-order.  Phase 1 (dim 1): predict odd
-    columns from even columns over all Ms rows — ``res1`` is (Ms, T1),
-    T1 = Ns//2.  Either may be None (degenerate extents skip the phase,
-    mirroring ``iter_phases`` dropping empty target sets).
-
-    ``m0/v0`` and ``m1/v1`` are optional dense escape-override masks and
-    values for each phase block (mask != 0 -> take the exact value instead
-    of pred + res) — the lossless escape channel applied inside the same
-    launch instead of a host writeback between phases.
-
-    Shared by the Pallas kernel body and the jitted XLA twin.
-    """
-    Ms, Ns = g.shape
-    if res0 is not None:
-        T0 = res0.shape[0]
-        ge = g[:, ::2]                        # (Ms, Nse) even columns
-        pred0 = _predict(ge.T, s=1, interp=interp, C=Ms, T=T0).T
-        blk0 = pred0 + res0
-        if m0 is not None:
-            blk0 = jnp.where(m0 != 0, v0, blk0)
-        g = g.at[1::2, ::2].set(blk0)
-    if res1 is not None:
-        T1 = res1.shape[1]
-        pred1 = _predict(g, s=1, interp=interp, C=Ns, T=T1)
-        blk1 = pred1 + res1
-        if m1 is not None:
-            blk1 = jnp.where(m1 != 0, v1, blk1)
-        g = g.at[:, 1::2].set(blk1)
-    return g
-
-
-def _lvl_kernel(*refs, interp: str, has0: bool, ov0: bool, has1: bool,
-                ov1: bool):
-    it = iter(refs)
-    g = next(it)[...]
-    res0 = next(it)[...] if has0 else None
-    m0 = next(it)[...] if ov0 else None
-    v0 = next(it)[...] if ov0 else None
-    res1 = next(it)[...] if has1 else None
-    m1 = next(it)[...] if ov1 else None
-    v1 = next(it)[...] if ov1 else None
-    out_ref = next(it)
-    out_ref[...] = level_core(g, res0, res1, m0, v0, m1, v1, interp=interp)
-
-
-@functools.partial(jax.jit, static_argnames=("interp", "interpret"))
-def interp_recon_level_pallas(g: jax.Array,
-                              res0: Optional[jax.Array] = None,
-                              res1: Optional[jax.Array] = None,
-                              m0: Optional[jax.Array] = None,
-                              v0: Optional[jax.Array] = None,
-                              m1: Optional[jax.Array] = None,
-                              v1: Optional[jax.Array] = None, *,
-                              interp: str = "cubic", interpret: bool = True):
-    """One launch for one whole level: both phase sweeps + escape overrides
-    on the (Ms, Ns) subgrid in a single grid step (the level's working set
-    is the subgrid itself, so the block IS the array).  Returns the updated
-    subgrid; the caller scatters it back with ``xhat[::s, ::s] = out``.
-    """
-    Ms, Ns = g.shape
-    ops, specs = [g], [pl.BlockSpec((Ms, Ns), lambda i: (0, 0))]
-    for a in (res0, m0, v0) if m0 is not None else (res0,):
-        if a is not None:
-            ops.append(a)
-            specs.append(pl.BlockSpec(a.shape, lambda i: (0, 0)))
-    for a in (res1, m1, v1) if m1 is not None else (res1,):
-        if a is not None:
-            ops.append(a)
-            specs.append(pl.BlockSpec(a.shape, lambda i: (0, 0)))
-    kern = functools.partial(_lvl_kernel, interp=interp,
-                             has0=res0 is not None, ov0=m0 is not None,
-                             has1=res1 is not None, ov1=m1 is not None)
-    return pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=specs,
-        out_specs=pl.BlockSpec((Ms, Ns), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Ms, Ns), g.dtype),
-        interpret=interpret,
-    )(*ops)
-
-
-@functools.partial(jax.jit, static_argnames=("interp",))
-def interp_recon_level_xla(g: jax.Array,
-                           res0: Optional[jax.Array] = None,
-                           res1: Optional[jax.Array] = None,
-                           m0: Optional[jax.Array] = None,
-                           v0: Optional[jax.Array] = None,
-                           m1: Optional[jax.Array] = None,
-                           v1: Optional[jax.Array] = None, *,
-                           interp: str = "cubic"):
-    """Jitted XLA twin of :func:`interp_recon_level_pallas`."""
-    return level_core(g, res0, res1, m0, v0, m1, v1, interp=interp)
+    """Jitted XLA twin of :func:`interp_recon_pallas` (the
+    ``IPCOMP_KERNEL_MODE=xla`` path on CPU): the same gather and core."""
+    T, Ne = sweep_geometry(xhat.shape[-1], s)
+    return recon_core(*split_known(known(xhat, s), T), res, Ne=Ne,
+                      interp=interp, ftz=xhat.dtype == jnp.float32)

@@ -1,13 +1,12 @@
-"""Public jit'd wrappers for the fused interpolate+add-residual kernel."""
+"""Public wrappers for the fused interpolate+add-residual kernel."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
 from .. import dispatch, mode
-from .kernel import (ROWS_B, interp_recon_level_pallas,
-                     interp_recon_level_xla, interp_recon_pallas,
-                     interp_recon_xla)
+from ..interp_quant.kernel import sweep_geometry
+from .kernel import interp_recon_pallas, interp_recon_xla
 
 
 def _on_tpu() -> bool:
@@ -16,7 +15,7 @@ def _on_tpu() -> bool:
 
 def interp_recon(xhat, res, *, s: int, interp: str = "cubic",
                  interpret: bool | None = None):
-    """Fused decode phase sweep for arbitrary (R, C): pads rows to the block.
+    """Fused decode phase sweep of one (R, C) problem.
 
     ``xhat`` (R, C) is the partially reconstructed surface (even multiples of
     s already known), ``res`` (R, T) the dequantized residuals for the target
@@ -24,24 +23,9 @@ def interp_recon(xhat, res, *, s: int, interp: str = "cubic",
     caller scatters it back into the sweep view (and applies any escape
     overrides) — the exact inverse of ``interp_quant``'s contract.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     xhat = jnp.asarray(xhat)
-    res = jnp.asarray(res, xhat.dtype)
-    R, C = xhat.shape
-    pad = (-R) % ROWS_B
-    if pad:
-        xhat = jnp.pad(xhat, ((0, pad), (0, 0)))
-        res = jnp.pad(res, ((0, pad), (0, 0)))
-    isz = xhat.dtype.itemsize
-    dispatch.record("interp_recon",
-                    nbytes=(xhat.size + 2 * res.size) * isz)
-    if mode.use_xla():
-        out = interp_recon_xla(xhat, res, s=s, interp=interp)
-    else:
-        out = interp_recon_pallas(xhat, res, s=s, interp=interp,
-                                  interpret=interpret)
-    return out[:R]
+    return interp_recon_batch(xhat[None], jnp.asarray(res, xhat.dtype)[None],
+                              s=s, interp=interp, interpret=interpret)[0]
 
 
 def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
@@ -49,31 +33,30 @@ def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
     """Batched decode phase sweep over stacked equal-shape chunks: (B, R, C).
 
     ``jax.vmap`` makes the batch axis an extra grid dimension of ONE kernel
-    launch — B chunks, one dispatch.  Each batch element is padded/computed
-    exactly like a lone ``interp_recon`` call, so per-chunk reconstructions
-    are bit-identical to the unbatched path.
+    launch — B chunks, one dispatch; every operation is elementwise across
+    the batch, so per-chunk reconstructions are bit-identical to lone
+    calls.
 
     With ``mesh``, the batch axis is zero-padded to a mesh multiple and
     split across the 1-D codec mesh by ``shard_map`` around the identical
     vmapped kernel — no collectives, one logical dispatch, ``mesh size``
-    device launches, pad rows sliced off.  One function holds both
-    layouts so the padding/reshape math cannot drift between them.
+    device launches, pad rows sliced off.
     """
     if interpret is None:
         interpret = not _on_tpu()
+    xla = mode.use_xla()
     xhat = jnp.asarray(xhat)
     res = jnp.asarray(res, xhat.dtype)
     B, R, C = xhat.shape
-    pad = (-R) % ROWS_B
     padb = 0
     if mesh is not None:
         from ...parallel import codec_mesh
         padb = codec_mesh.pad_to_shards(B, mesh)
-    if pad or padb:
-        xhat = jnp.pad(xhat, ((0, padb), (0, pad), (0, 0)))
-        res = jnp.pad(res, ((0, padb), (0, pad), (0, 0)))
+        if padb:
+            xhat = jnp.pad(xhat, ((0, padb), (0, 0), (0, 0)))
+            res = jnp.pad(res, ((0, padb), (0, 0), (0, 0)))
 
-    if mode.use_xla():
+    if xla:
         def kernel(a, b):
             return interp_recon_xla(a, b, s=s, interp=interp)
     else:
@@ -81,16 +64,20 @@ def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
             return interp_recon_pallas(a, b, s=s, interp=interp,
                                        interpret=interpret)
 
-    isz = xhat.dtype.itemsize
-    nbytes = (xhat.size + 2 * res.size) * isz
+    # the kernel's operands: known points, residuals, reconstruction (the
+    # wrapper's gather that builds the first is XLA's, not metered)
+    T, Ne = sweep_geometry(C, s)
+    nbytes = (B + padb) * R * (Ne + 3 + 2 * T) * xhat.dtype.itemsize
     if mesh is None:
-        dispatch.record("interp_recon", batch=B, nbytes=nbytes)
+        dispatch.record("interp_recon", interpret=interpret and not xla,
+                        batch=B, nbytes=nbytes)
         out = jax.vmap(kernel)(xhat, res)
     else:
-        dispatch.record("interp_recon", batch=B,
-                        devices=codec_mesh.shard_count(mesh), nbytes=nbytes)
+        dispatch.record("interp_recon", interpret=interpret and not xla,
+                        batch=B, devices=codec_mesh.shard_count(mesh),
+                        nbytes=nbytes)
         out = codec_mesh.shard_vmap(kernel, mesh)(xhat, res)
-    return out[:B, :R]
+    return out[:B]
 
 
 def interp_recon_sharded(xhat, res, *, s: int, mesh, interp: str = "cubic",
@@ -99,121 +86,3 @@ def interp_recon_sharded(xhat, res, *, s: int, mesh, interp: str = "cubic",
     axis split over the 1-D codec ``mesh`` (thin alias)."""
     return interp_recon_batch(xhat, res, s=s, interp=interp,
                               interpret=interpret, mesh=mesh)
-
-
-def _level_nbytes(g, res0, res1, ov0, ov1) -> int:
-    n = 2 * g.size
-    for r in (res0, res1):
-        if r is not None:
-            n += r.size
-    for ov in (ov0, ov1):
-        if ov is not None:
-            n += ov[0].size + ov[1].size
-    return n * g.dtype.itemsize
-
-
-def interp_recon_level(g, res0=None, res1=None, *, interp: str = "cubic",
-                       ov0=None, ov1=None, interpret: bool | None = None):
-    """ONE launch for one whole 2-D level: both (level, dim) phase sweeps
-    plus escape overrides, on the level's stride-s subgrid.
-
-    ``g`` (Ms, Ns) is ``xhat[::s, ::s]``; ``res0`` (T0, Nse) / ``res1``
-    (Ms, T1) the phases' dequantized residual blocks (None = phase empty);
-    ``ov0`` / ``ov1`` optional ``(mask, values)`` dense override pairs per
-    block.  Returns the updated subgrid — the caller writes it back with
-    ``xhat[::s, ::s] = out``.  Replaces two ``interp_recon`` launches and
-    a host override scatter per level.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    g = jnp.asarray(g)
-    res0 = None if res0 is None else jnp.asarray(res0, g.dtype)
-    res1 = None if res1 is None else jnp.asarray(res1, g.dtype)
-    m0 = v0 = m1 = v1 = None
-    if ov0 is not None:
-        m0 = jnp.asarray(ov0[0], jnp.int32)
-        v0 = jnp.asarray(ov0[1], g.dtype)
-    if ov1 is not None:
-        m1 = jnp.asarray(ov1[0], jnp.int32)
-        v1 = jnp.asarray(ov1[1], g.dtype)
-    dispatch.record("interp_recon",
-                    nbytes=_level_nbytes(g, res0, res1, ov0, ov1))
-    if mode.use_xla():
-        return interp_recon_level_xla(g, res0, res1, m0, v0, m1, v1,
-                                      interp=interp)
-    return interp_recon_level_pallas(g, res0, res1, m0, v0, m1, v1,
-                                     interp=interp, interpret=interpret)
-
-
-def interp_recon_level_batch(g, res0=None, res1=None, *,
-                             interp: str = "cubic", ov0=None, ov1=None,
-                             interpret: bool | None = None, mesh=None):
-    """Batched whole-level sweep over stacked equal-shape chunks.
-
-    ``g`` is (B, Ms, Ns); residual blocks and override pairs carry the same
-    leading batch axis (phase presence is uniform across the stack — equal
-    shapes share a traversal).  One vmapped launch covers all B chunks;
-    with ``mesh`` the stack is zero-padded to a mesh multiple and split
-    across the 1-D codec mesh (pad subgrids reconstruct zeros, sliced off).
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    g = jnp.asarray(g)
-    B = g.shape[0]
-    res0 = None if res0 is None else jnp.asarray(res0, g.dtype)
-    res1 = None if res1 is None else jnp.asarray(res1, g.dtype)
-    m0 = v0 = m1 = v1 = None
-    if ov0 is not None:
-        m0 = jnp.asarray(ov0[0], jnp.int32)
-        v0 = jnp.asarray(ov0[1], g.dtype)
-    if ov1 is not None:
-        m1 = jnp.asarray(ov1[0], jnp.int32)
-        v1 = jnp.asarray(ov1[1], g.dtype)
-    padb = 0
-    if mesh is not None:
-        from ...parallel import codec_mesh
-        padb = codec_mesh.pad_to_shards(B, mesh)
-        if padb:
-            def padb_fn(a):
-                return None if a is None else jnp.pad(
-                    a, ((0, padb),) + ((0, 0),) * (a.ndim - 1))
-            g, res0, res1, m0, v0, m1, v1 = (
-                padb_fn(a) for a in (g, res0, res1, m0, v0, m1, v1))
-
-    has0, ovf0 = res0 is not None, m0 is not None
-    has1, ovf1 = res1 is not None, m1 is not None
-    args = [a for a in (g, res0, m0, v0, res1, m1, v1) if a is not None]
-
-    def kernel(*a):
-        it = iter(a)
-        gg = next(it)
-        r0 = next(it) if has0 else None
-        mm0 = next(it) if ovf0 else None
-        vv0 = next(it) if ovf0 else None
-        r1 = next(it) if has1 else None
-        mm1 = next(it) if ovf1 else None
-        vv1 = next(it) if ovf1 else None
-        if mode.use_xla():
-            return interp_recon_level_xla(gg, r0, r1, mm0, vv0, mm1, vv1,
-                                          interp=interp)
-        return interp_recon_level_pallas(gg, r0, r1, mm0, vv0, mm1, vv1,
-                                         interp=interp, interpret=interpret)
-
-    nbytes = _level_nbytes(g, res0, res1, ov0, ov1)
-    if mesh is None:
-        dispatch.record("interp_recon", batch=B, nbytes=nbytes)
-        out = jax.vmap(kernel)(*args)
-    else:
-        dispatch.record("interp_recon", batch=B,
-                        devices=codec_mesh.shard_count(mesh), nbytes=nbytes)
-        out = codec_mesh.shard_vmap(kernel, mesh)(*args)
-    return out[:B]
-
-
-def interp_recon_level_sharded(g, res0=None, res1=None, *, mesh,
-                               interp: str = "cubic", ov0=None, ov1=None,
-                               interpret: bool | None = None):
-    """Sharded whole-level sweep: ``interp_recon_level_batch`` with the
-    stack split over the 1-D codec ``mesh`` (thin alias)."""
-    return interp_recon_level_batch(g, res0, res1, interp=interp, ov0=ov0,
-                                    ov1=ov1, interpret=interpret, mesh=mesh)

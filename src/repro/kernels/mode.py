@@ -14,7 +14,9 @@ Modes:
 
   * ``pallas`` (default) — ``pl.pallas_call``; interpret mode on CPU/GPU,
     Mosaic-compiled on TPU;
-  * ``xla``             — the jitted pure-jnp core, any backend.
+  * ``xla``             — the jitted pure-jnp core, CPU/GPU only.  On a
+    TPU it raises: the chip path runs the Pallas kernels or nothing, never
+    a silent substitute.
 
 The knob is read per wrapper call (cheap: one env lookup), so tests can
 flip it with ``monkeypatch.setenv`` without reimporting anything.
@@ -38,5 +40,13 @@ def kernel_mode() -> str:
 
 
 def use_xla() -> bool:
-    """True when wrappers should dispatch the jitted XLA twin."""
-    return kernel_mode() == XLA
+    """True when wrappers should dispatch the jitted XLA twin; raises
+    ``RuntimeError`` if that is asked for on a TPU."""
+    if kernel_mode() != XLA:
+        return False
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(f"{ENV}={XLA} is not allowed on a TPU: the "
+                           "codec runs its Pallas kernels there")
+    return True

@@ -1,37 +1,23 @@
-"""Version-tolerant jax API shims for the parallel substrate.
+"""Thin jax API adapters for the parallel substrate.
 
 Every module that places work on a device mesh — the training launcher
 (``launch/steps.py``), the logical-axis context (``parallel.api``), and the
 codec's sharded chunk-grid executor (``parallel.codec_mesh``, see
-``docs/architecture.md``) — goes through this file instead of calling jax's
-mesh/shard APIs directly, because those APIs moved across the releases this
-repo supports.  Two shims:
+``docs/architecture.md``) — goes through this file, so the keyword
+conventions below live in one place:
 
 :func:`shard_map`
-    ``shard_map`` moved twice across jax releases:
-
-      * old:  ``jax.experimental.shard_map.shard_map(f, mesh, in_specs,
-              out_specs, check_rep=...)``
-      * new:  ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-              axis_names=..., check_vma=...)``
-
-    Call sites in this repo use the *new* keyword vocabulary
-    (``axis_names``, ``check_vma``); the wrapper translates to whatever the
-    installed jax provides so the same code runs on both sides of the
-    rename.  On the legacy API, axes not named manual are forwarded via
-    ``auto=`` (the legacy default is manual-everywhere, which would cost
-    SPMD sharding on the untouched axes — see the inline note).
+    ``jax.shard_map`` with this repo's keyword vocabulary (``axis_names``
+    optional, ``check_vma`` defaulting to False).
 
 :func:`make_mesh`
-    ``jax.make_mesh`` (device-order-aware constructor) only exists on
-    newer jax; older releases spell it ``jax.sharding.Mesh`` over an
-    explicit device array.  The wrapper takes (axis sizes, axis names,
-    optional explicit devices) and returns a :class:`jax.sharding.Mesh`
-    either way.
+    A :class:`jax.sharding.Mesh` from (axis sizes, axis names, optional
+    explicit devices): ``jax.make_mesh`` when the device list is implicit,
+    the explicit list verbatim otherwise.
 
-The contract both shims keep: pure API translation, no policy.  Axis
-layout / sizing decisions live with the callers (``launch/mesh.py`` for
-training, ``parallel.codec_mesh`` for the codec).
+Pure API adaptation, no policy: axis layout / sizing decisions live with
+the callers (``launch/mesh.py`` for training, ``parallel.codec_mesh`` for
+the codec).
 """
 from __future__ import annotations
 
@@ -43,45 +29,27 @@ import numpy as np
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names: Optional[set] = None,
               check_vma: bool = False):
-    """Map ``f`` over shards of ``mesh`` (new-API keywords on any jax)."""
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        try:
-            return jax.shard_map(f, check_vma=check_vma, **kw)
-        except TypeError:  # transitional releases: check_rep instead
-            return jax.shard_map(f, check_rep=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # legacy API is manual-by-default: axes NOT named manual must be passed
-    # via auto=, or e.g. steps.py's pod-manual train step would lose SPMD
-    # sharding over the data/model axes (every device recomputing the full
-    # per-pod step)
-    kw = {}
+    """Map ``f`` over shards of ``mesh`` (``jax.shard_map``)."""
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=bool(check_vma), **kw)
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, **kw)
 
 
 def make_mesh(axis_shape: Tuple[int, ...], axis_names: Tuple[str, ...],
               devices: Optional[Sequence] = None) -> "jax.sharding.Mesh":
-    """Build a :class:`jax.sharding.Mesh` on any supported jax release.
+    """Build a :class:`jax.sharding.Mesh`.
 
     ``axis_shape``/``axis_names`` follow ``jax.make_mesh``; ``devices``
-    optionally pins an explicit device list (first ``prod(axis_shape)``
-    local devices by default).  Newer jax goes through ``jax.make_mesh``
-    (which may reorder devices for interconnect locality) only when the
+    optionally pins an explicit device list.  ``jax.make_mesh`` (which may
+    reorder devices for interconnect locality) is used only when the
     device list is implicit — an explicit list is always honored verbatim,
-    on every release, so callers that slice ``jax.devices()`` themselves
-    (e.g. ``codec_mesh.codec_mesh(n)``) get a deterministic mesh.
+    so callers that slice ``jax.devices()`` themselves (e.g.
+    ``codec_mesh.codec_mesh(n)``) get a deterministic mesh.
     """
+    if devices is None:
+        return jax.make_mesh(axis_shape, axis_names)
     from jax.sharding import Mesh
 
-    if devices is None:
-        if hasattr(jax, "make_mesh"):
-            return jax.make_mesh(axis_shape, axis_names)
-        devices = jax.devices()[: int(np.prod(axis_shape))]
     return Mesh(np.asarray(devices).reshape(axis_shape), axis_names)

@@ -128,7 +128,7 @@ def _shape_sig(meta) -> tuple:
     """Batch-compatibility signature: jobs with equal signatures may share
     one stacked kernel launch (same contract as ``encode.shape_groups``
     plus the level/anchor structure ``*_batch`` helpers assume)."""
-    return (tuple(meta.shape), meta.interp,
+    return (tuple(meta.shape), meta.interp, meta.work_dtype,
             tuple(lv.n for lv in meta.levels),
             tuple(meta.anchors_shape))
 

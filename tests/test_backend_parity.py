@@ -23,6 +23,23 @@ from repro.core import negabinary as nbmod
 
 # ------------------------------------------------------- archive parity
 
+# float32 fields run the float32 arithmetic contract (core.arith) on both
+# backends: same archive bytes, same reconstruction bits
+
+def _f32_hostile(shape, seed):
+    """float32 field with the values the contract has to pin down:
+    subnormals of both signs, tiny normals whose sums underflow, zeros."""
+    rng = np.random.default_rng(seed)
+    x = smooth_field(shape, seed).astype(np.float32)
+    flat = x.reshape(-1)
+    flat[::7] = np.float32(3e-39)
+    flat[3::11] = np.float32(-1e-41)
+    flat[5::13] *= np.float32(1e-37)
+    flat[1::17] = 0.0
+    return x + np.float32(0) * rng.standard_normal(shape).astype(np.float32)
+
+
+
 @pytest.mark.parametrize("shape", [(257,), (33, 41), (17, 13, 11)])
 @pytest.mark.parametrize("interp", [LINEAR, CUBIC])
 def test_archives_byte_identical_smooth(shape, interp):
@@ -53,6 +70,20 @@ def test_archives_byte_identical_property(ndim, seed, interp, rel_eb):
     assert np.array_equal(decompress(a), decompress(b))
 
 
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_archives_byte_identical_recorded_counterexample(dtype):
+    """The case hypothesis once recorded against the byte-parity property
+    (ndim=1, seed=0, linear, rel_eb=0.0625), pinned for both contracts."""
+    rng = np.random.default_rng(0)
+    shape = (int(rng.integers(2, 160)),)
+    x = (rng.standard_normal(shape) * rng.uniform(0.1, 100)).astype(dtype)
+    eb = 0.0625 * float(x.max() - x.min())
+    a = compress(x, eb, LINEAR, backend="numpy")
+    b = compress(x, eb, LINEAR, backend="jax")
+    assert a == b
+    assert decompress(a).tobytes() == decompress(b, backend="jax").tobytes()
+
+
 def test_archives_byte_identical_with_escapes():
     """Outliers exercise the int32 wrap/saturate path of the kernel bins."""
     x = smooth_field((40, 40), 1)
@@ -64,6 +95,73 @@ def test_archives_byte_identical_with_escapes():
     b = compress(x, eb, CUBIC, backend="jax")
     assert a == b
     assert metrics.linf(x, decompress(b)) <= eb
+
+
+@pytest.mark.parametrize("shape", [(257,), (33, 41), (17, 13, 11)])
+@pytest.mark.parametrize("interp", [LINEAR, CUBIC])
+def test_archives_byte_identical_smooth_f32(shape, interp):
+    x = smooth_field(shape).astype(np.float32)
+    eb = 1e-4 * float(x.max() - x.min())
+    a = compress(x, eb, interp, backend="numpy")
+    b = compress(x, eb, interp, backend="jax")
+    assert a == b
+    xa, xb = decompress(a), decompress(b, backend="jax")
+    assert xb.dtype == np.float32 and xa.tobytes() == xb.tobytes()
+    assert metrics.linf(x, xb) <= eb
+
+
+@pytest.mark.parametrize("interp", [LINEAR, CUBIC])
+def test_archives_byte_identical_f32_subnormals(interp):
+    """Subnormal operands and underflowing sums: the kernels and the numpy
+    reference flush them identically (and the bound still holds)."""
+    x = _f32_hostile((37, 29), 4)
+    eb = 1e-5 * float(x.max() - x.min())
+    a = compress(x, eb, interp, backend="numpy")
+    b = compress(x, eb, interp, backend="jax")
+    assert a == b
+    xa, xb = decompress(a), decompress(b, backend="jax")
+    assert xa.tobytes() == xb.tobytes()
+    assert metrics.linf(x, xb) <= eb
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_f32_bound_by_construction_near_ulp(backend):
+    """eb of about one float32 ulp of the data: rounding alone would break
+    the bound, so the verify-and-escape screen must do real work — and
+    every read, full or partial, must still meet its reported bound."""
+    from repro.core.container import parse_meta
+
+    x = (1000.0 + 50.0 * smooth_field((40, 36), 6)).astype(np.float32)
+    eb = float(np.spacing(np.float32(np.abs(x).max())))
+    buf = compress(x, eb, CUBIC, backend=backend)
+    meta = parse_meta(buf)
+    assert sum(lv.esc_size for lv in meta.levels) > 0  # the screen escaped
+    full = decompress(buf, backend=backend)
+    assert np.abs(full.astype(np.float64) - x).max() < eb
+    for E in (1e3 * eb, 30 * eb):
+        out, st = retrieve(buf, error_bound=E, backend=backend)
+        assert st.err_bound <= E
+        assert np.abs(out.astype(np.float64) - x).max() <= st.err_bound
+
+
+@settings(max_examples=10, deadline=None)
+@given(st.integers(1, 3), st.integers(0, 10 ** 6),
+       st.sampled_from([LINEAR, CUBIC]), st.floats(1e-7, 1e-1))
+def test_archives_byte_identical_f32_property(ndim, seed, interp, rel_eb):
+    """Rough float32 data over eb from near-ulp to coarse: bytes and bits
+    equal, and the full read within eb."""
+    rng = np.random.default_rng(seed)
+    shape = tuple(int(rng.integers(2, [160, 30, 14][ndim - 1]))
+                  for _ in range(ndim))
+    x = (rng.standard_normal(shape) * rng.uniform(0.1, 100)
+         ).astype(np.float32)
+    eb = rel_eb * float(x.max() - x.min())
+    a = compress(x, eb, interp, backend="numpy")
+    b = compress(x, eb, interp, backend="jax")
+    assert a == b
+    xa, xb = decompress(a), decompress(b, backend="jax")
+    assert xa.tobytes() == xb.tobytes()
+    assert np.abs(xb.astype(np.float64) - x).max() < eb
 
 
 def test_archives_byte_identical_f32_and_chunked():

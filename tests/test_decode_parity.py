@@ -57,6 +57,30 @@ def test_retrieve_bit_identical_property(ndim, seed, interp, rel_eb):
     assert sa.bytes_read == sb.bytes_read
 
 
+@pytest.mark.parametrize("chunk_elems", [None, 300])
+@pytest.mark.parametrize("interp", [LINEAR, CUBIC])
+def test_retrieve_bit_identical_f32(interp, chunk_elems):
+    """float32 archives (float32 arithmetic): a refine ladder on numpy and
+    jax stays bit-identical at every rung, accounting included."""
+    x = smooth_field((41, 30), 8).astype(np.float32)
+    x[9, 4] = np.float32(3e30)   # an escape
+    eb = 1e-6 * float(np.ptp(smooth_field((41, 30), 8)))
+    buf = compress(x, eb, interp, chunk_elems=chunk_elems)
+    sa = sb = None
+    for E in (1e-2, 1e-4, None):
+        kw = {} if E is None else dict(error_bound=E)
+        a, sa = retrieve(open_archive(buf) if sa is None else sa.reader,
+                         state=sa, backend="numpy", **kw)
+        b, sb = retrieve(open_archive(buf) if sb is None else sb.reader,
+                         state=sb, backend="jax", **kw)
+        assert a.dtype == b.dtype == np.float32
+        assert a.tobytes() == b.tobytes()
+        assert sa.err_bound == sb.err_bound
+        assert sa.bytes_read == sb.bytes_read
+        assert metrics.linf(x, b) <= sb.err_bound
+    assert metrics.linf(x, b) <= eb
+
+
 def test_decode_bit_identical_with_escapes():
     """Escaped outliers: the exact-override writeback must land identically
     (initial state AND pinned-zero deltas on later refinements)."""

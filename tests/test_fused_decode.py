@@ -77,6 +77,29 @@ def test_decode_level_fused_matches_unfused(nprev, want):
     assert d.get("decode_fused", 0) == 1
 
 
+@pytest.mark.parametrize("want", [1, 4, 11])
+def test_decode_level_fused_f32_matches_host(want):
+    """float32 mode: one fused launch returns the level's float32 residual
+    of the loaded prefix, bit-identical to the host dequantization."""
+    from repro.core import arith, negabinary
+
+    rng = np.random.default_rng(want)
+    q = rng.integers(-(1 << 29), 1 << 29, size=1500).astype(np.int64)
+    q[::3] //= 1 << 8
+    blobs, nbits = jax_backend.encode_level(q)
+    cur = [blobs[i] if i < min(want, nbits) else None for i in range(nbits)]
+    nb_ref = jax_backend.decode_level(cur, nbits, q.size)
+    eb = 2.5e-6
+    want_res = arith.dequantize(negabinary.from_negabinary(nb_ref),
+                                arith.consts(eb, np.float32))
+    with dispatch.measure() as d:
+        nb_new, res = jax_backend.decode_level_fused(
+            cur, nbits, q.size, None, eb, dtype=np.float32)
+    assert d.get("decode_fused", 0) == 1
+    assert np.array_equal(nb_new, nb_ref)
+    assert res.dtype == np.float32 and res.tobytes() == want_res.tobytes()
+
+
 def test_decode_level_fused_batch_mixed_prefixes_and_ebs():
     """Per-chunk prefixes AND per-chunk error bounds ride one launch."""
     from repro.core import negabinary
@@ -145,6 +168,23 @@ def test_v1_ladder_fused_vs_unfused_vs_numpy():
             assert np.array_equal(o1, o2), bk
             assert b1 == b2, bk
     assert metrics.linf(x, ladders["jax"][-1][0]) <= 1e-6
+
+
+def test_f32_ladder_fused_vs_unfused_vs_numpy():
+    """float32 chunked ladder with an escape: fused (float32 residual out
+    of the kernel) == unfused == numpy at every rung."""
+    x = _chunky_field((50, 41)).astype(np.float32)
+    x[20, 3] = np.float32(-7e30)
+    buf = compress(x, 1e-5, chunk_elems=600)
+    outs = {}
+    for bk in ("numpy", "jax", "jax_unfused"):
+        out1, st = retrieve(open_archive(buf), error_bound=1e-2, backend=bk)
+        out2, st = refine(st, error_bound=1e-4, backend=bk)
+        out3, st = refine(st, backend=bk)
+        outs[bk] = ([o.tobytes() for o in (out1, out2, out3)], st.bytes_read)
+    for bk in ("jax", "jax_unfused"):
+        assert outs[bk] == outs["numpy"], bk
+    assert metrics.linf(x, out3) <= 1e-5
 
 
 def test_chunked_budget_ladder_fused_vs_unfused():

@@ -117,7 +117,7 @@ def test_interp_recon_matches_ref(shape, s, interp, dtype):
 def test_interp_recon_bit_identical_to_numpy_sweep():
     """Decode kernel == interpolation.predict_block + res, bitwise (f64) —
     the invariant that makes jax retrieval parity possible at all."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(11)
         for (R, C), s, interp in [((8, 128), 1, "cubic"),
                                   ((5, 257), 4, "cubic"),
@@ -132,7 +132,7 @@ def test_interp_recon_bit_identical_to_numpy_sweep():
 
 def test_interp_recon_inverts_interp_quant():
     """recon(xhat, dequantized q) == the encode sweep's lossy writeback."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(3)
         x = rng.standard_normal((8, 256))
         xh = rng.standard_normal((8, 256))

@@ -14,7 +14,7 @@ from repro.kernels.interp_recon import interp_recon, interp_recon_ref
                                      ((8, 130), 1)])
 @pytest.mark.parametrize("interp", ["linear", "cubic"])
 def test_interp_quant_f64(shape, s, interp):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(1)
         x = jnp.asarray(rng.standard_normal(shape), jnp.float64)
         xh = jnp.asarray(rng.standard_normal(shape), jnp.float64)
@@ -29,7 +29,7 @@ def test_interp_quant_f64(shape, s, interp):
                                      ((8, 130), 1)])
 @pytest.mark.parametrize("interp", ["linear", "cubic"])
 def test_interp_recon_f64(shape, s, interp):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(2)
         R, C = shape
         T = len(range(s, C, 2 * s))

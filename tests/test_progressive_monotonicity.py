@@ -80,6 +80,50 @@ def test_ladder_bit_identical_across_backends(version):
         assert sn.bytes_read == sj.bytes_read
 
 
+@pytest.mark.parametrize("version", ["v1", "v2"])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_ladder_monotone_f32_refine_equals_fresh_bitwise(version, backend):
+    """float32 sessions re-sweep from the current bins, so every rung is
+    path-independent: wherever the plane sets coincide, the refined and
+    the fresh read are bit-identical — not merely close."""
+    x, _ = _archive(version)
+    x = x.astype(np.float32)
+    kw = dict(chunk_elems=900) if version == "v2" else {}
+    buf = compress(x, 1e-6, CUBIC, **kw)
+    reader = open_archive(buf)
+    st = None
+    prev_err, prev_bytes = float("inf"), 0
+    for E in LADDER[:-1] + (None,):
+        args = {} if E is None else dict(error_bound=E)
+        out, st = retrieve(reader, state=st, backend=backend, **args)
+        assert st.err_bound <= prev_err
+        assert st.bytes_read >= prev_bytes
+        assert metrics.linf(x, out) <= st.err_bound
+        if E is not None:
+            assert st.err_bound <= E
+        prev_err, prev_bytes = st.err_bound, st.bytes_read
+        fresh, fst = retrieve(open_archive(buf), backend=backend, **args)
+        if _plane_sets(st) == _plane_sets(fst):
+            assert out.tobytes() == fresh.tobytes()
+    assert st.err_bound == 1e-6
+
+
+@pytest.mark.parametrize("version", ["v1", "v2"])
+def test_ladder_bit_identical_across_backends_f32(version):
+    x, _ = _archive(version)
+    x = x.astype(np.float32)
+    kw = dict(chunk_elems=900) if version == "v2" else {}
+    buf = compress(x, 1e-6, CUBIC, **kw)
+    rn, rj = open_archive(buf), open_archive(buf)
+    sn = sj = None
+    for E in LADDER:
+        on, sn = retrieve(rn, error_bound=E, state=sn, backend="numpy")
+        oj, sj = retrieve(rj, error_bound=E, state=sj, backend="jax")
+        assert on.tobytes() == oj.tobytes()
+        assert sn.err_bound == sj.err_bound
+        assert sn.bytes_read == sj.bytes_read
+
+
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_refine_api_monotone_bitrate(backend):
     """refine() under growing byte budgets: error monotone non-increasing,
