@@ -10,10 +10,8 @@ runs the chunk grid data-parallel over the local device mesh and records
 sharded vs single-device MB/s and per-device launch fan-out, plus a
 fused-decode entry that races the ``jax`` backend's decode megakernel
 (one ``decode_fused`` + one whole-level recon launch per level) against
-the pre-fusion ``jax_unfused`` baseline, recording MB/s, dispatches,
-launches per level, and per-kernel HBM bytes (the roofline report's
-input).  Everything
-drives the object API (``Codec`` / ``Archive`` / ``Fidelity`` /
+the pre-fusion ``jax_unfused`` baseline, recording MB/s, dispatches and
+launches per level.  Everything drives the object API (``Codec`` / ``Archive`` / ``Fidelity`` /
 ``ExecPolicy``), so the benchmark doubles as its smoke test.  Kernel
 dispatch counts for all modes come from ``repro.kernels.dispatch``, so the
 batched-vs-looped launch-count reduction (and the sharded fan-out) is a
@@ -115,8 +113,7 @@ def _fused_rows(x: np.ndarray, eb: float, buf: bytes, rows, checks,
     """The fused-decode megakernel entry: ``jax`` (fused decode path) vs
     ``jax_unfused`` (the pre-fusion per-phase pipeline, kept registered as
     the baseline) on the v1 2^20 archive.  Records MB/s, total dispatches,
-    per-kernel launch counts and HBM bytes, and launches per level — the
-    inputs of ``benchmarks/roofline_report.py``.  The fused path must
+    per-kernel launch counts and launches per level.  The fused path must
     issue strictly FEWER dispatches (a structural property, asserted even
     in interpret mode) and reach >= 2x the unfused MB/s on this case.
     """
@@ -131,7 +128,7 @@ def _fused_rows(x: np.ndarray, eb: float, buf: bytes, rows, checks,
         warm = archive.open(policy)
         warm.read(Fidelity.error_bound(REFINE_COARSE * eb))
         warm.refine(Fidelity.error_bound(REFINE_FINE * eb))
-        with dispatch.measure() as d, dispatch.measure_bytes() as db:
+        with dispatch.measure() as d:
             outs[bk], dt = timed(lambda: archive.open(policy).read(),
                                  repeat=1)
         nd = sum(d.values())
@@ -144,13 +141,12 @@ def _fused_rows(x: np.ndarray, eb: float, buf: bytes, rows, checks,
                                 op="decompress", seconds=dt, mbps=mbps,
                                 dispatches=nd, levels=L,
                                 dispatches_per_level=nd / L,
-                                dispatches_by_kernel=dict(d),
-                                kernel_bytes=dict(db)))
+                                dispatches_by_kernel=dict(d)))
         stats[bk] = (mbps, nd)
 
         session = archive.open(policy)
         session.read(Fidelity.error_bound(REFINE_COARSE * eb))
-        with dispatch.measure() as d, dispatch.measure_bytes() as db:
+        with dispatch.measure() as d:
             _, dt = timed(session.refine,
                           Fidelity.error_bound(REFINE_FINE * eb), repeat=1)
         nd = sum(d.values())
@@ -161,8 +157,7 @@ def _fused_rows(x: np.ndarray, eb: float, buf: bytes, rows, checks,
         dec_records.append(dict(case="fused_decode", backend=bk, op="refine",
                                 seconds=dt, mbps=mbps, dispatches=nd,
                                 levels=L, dispatches_per_level=nd / L,
-                                dispatches_by_kernel=dict(d),
-                                kernel_bytes=dict(db)))
+                                dispatches_by_kernel=dict(d)))
     checks.append(("fused_parity_bits", "fused_decode", "decompress",
                    bool(np.array_equal(outs["jax"], outs["jax_unfused"]))))
     checks.append(("fused_fewer_dispatches", "fused_decode", "decompress",

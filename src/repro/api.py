@@ -44,6 +44,7 @@ from typing import Iterable, Iterator, Optional, Tuple, Union
 
 import numpy as np
 
+from . import trace
 from .core import container, interpolation, loader
 from .core.bytesource import ByteSource, FileSource, as_source
 from .core.container import CorruptArchiveError
@@ -125,10 +126,14 @@ class Codec:
         ``policy`` selects the execution substrate only; archives are
         byte-identical across policies.
         """
-        return Archive(encode.encode_array(
-            x, self.eb, interp=self.interp, relative=self.relative,
-            chunk_elems=self.chunk_elems, policy=policy,
-            version=self.version))
+        # one request: the archive's validation ends its ``encode`` span
+        with trace.request("encode"):
+            buf = encode.encode_array(
+                x, self.eb, interp=self.interp, relative=self.relative,
+                chunk_elems=self.chunk_elems, policy=policy,
+                version=self.version)
+            with trace.span("encode.container", stage="container"):
+                return Archive(buf)
 
 
 class Archive:

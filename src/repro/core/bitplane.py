@@ -19,6 +19,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .. import trace
+
 #: default zlib compression level; override per process with
 #: ``IPCOMP_ZLIB_LEVEL`` (0–9).  Both backends read the same knob, so the
 #: byte-identical-archive invariant holds at every setting.
@@ -122,12 +124,14 @@ def decompress_plane(blob: bytes, n: int) -> np.ndarray:
 
 def encode_level(nb: np.ndarray) -> Tuple[List[bytes], int]:
     """negabinary ints -> (blobs MSB-first, nbits). blobs[i] is plane nbits-1-i."""
-    nbits = int(nb.max()).bit_length() if nb.size else 0
-    if nbits == 0:
-        return [], 0
-    planes = split_planes(nb, nbits)
-    enc = xor_encode(planes)
-    blobs = [compress_plane(enc[k]) for k in range(nbits - 1, -1, -1)]
+    with trace.span("pack.kernel", stage="kernel_io"):
+        nbits = int(nb.max()).bit_length() if nb.size else 0
+        if nbits == 0:
+            return [], 0
+        enc = xor_encode(split_planes(nb, nbits))
+    with trace.span("pack.zlib", stage="zlib"):
+        blobs = [compress_plane(enc[k]) for k in range(nbits - 1, -1, -1)]
+        _count_zlib(blobs, (nb.size + 7) // 8)
     return blobs, nbits
 
 
@@ -146,19 +150,30 @@ def blobs_from_packed(packed: np.ndarray, n: int) -> Tuple[List[bytes], int]:
     as ``jax_backend.encode_level`` does.)  Both backends therefore write
     one archive format, and a mixed read path cannot exist.
     """
-    occupied = [bool(packed[k].any()) for k in range(packed.shape[0])]
-    nbits = max((k + 1 for k, nz in enumerate(occupied) if nz), default=0)
-    if nbits == 0:
-        return [], 0
-    nbytes = (n + 7) // 8
-    blobs = []
-    for k in range(nbits - 1, -1, -1):
-        if not occupied[k]:
-            blobs.append(b"")  # all-zero plane: same convention as compress_plane
-            continue
-        raw = packed[k].astype(">u4").tobytes()[:nbytes]
-        blobs.append(zlib.compress(raw, zlib_level()))
-    return blobs, nbits
+    with trace.span("pack.zlib", stage="zlib"):
+        occupied = [bool(packed[k].any()) for k in range(packed.shape[0])]
+        nbits = max((k + 1 for k, nz in enumerate(occupied) if nz),
+                    default=0)
+        if nbits == 0:
+            return [], 0
+        nbytes = (n + 7) // 8
+        blobs = []
+        for k in range(nbits - 1, -1, -1):
+            if not occupied[k]:
+                # all-zero plane: same convention as compress_plane
+                blobs.append(b"")
+                continue
+            raw = packed[k].astype(">u4").tobytes()[:nbytes]
+            blobs.append(zlib.compress(raw, zlib_level()))
+        _count_zlib(blobs, nbytes)
+        return blobs, nbits
+
+
+def _count_zlib(blobs: List[bytes], nbytes: int) -> None:
+    """Count one level's zlib bytes on the open span (an all-zero plane
+    is stored as ``b''`` without zlib)."""
+    trace.count("zlib_in_bytes", nbytes * sum(1 for b in blobs if b))
+    trace.count("zlib_out_bytes", sum(len(b) for b in blobs))
 
 
 def decode_level(blobs: List[Optional[bytes]], nbits: int, n: int) -> np.ndarray:

@@ -25,6 +25,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import trace
 from . import arith
 
 LINEAR = "linear"
@@ -169,29 +170,36 @@ def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
     escs: List[List[List[Tuple]]] = [[[] for _ in range(L)] for _ in range(B)]
     offsets = [0] * L
     for ph in iter_phases(shape, L):
-        ax = ph.dim + 1
-        xv = xs[(slice(None),) + ph.view]
-        hv = xhat[(slice(None),) + ph.view]
-        tvals = np.take(xv, ph.targets, axis=ax)
-        if phase_fn is None:
-            pred = predict_block(hv, ax, ph.targets, ph.stride, ph.n_dim,
-                                 interp, c.f32)
-            q = arith.bins(np, tvals, pred, c, np.int64)
-        else:
-            q, pred = phase_fn(xv, hv, ph, c)
-        q, block, esc = arith.screen(tvals, pred, q, c)
-        _assign(hv, ax, ph.targets, block)
-        li = L - ph.level
-        for b in range(B):
-            flat = np.flatnonzero(esc[b].ravel())
-            qs[b][li].append(q[b].ravel())
-            escs[b][li].append((flat + offsets[li],
-                                tvals[b].ravel()[flat].astype(np.float64)))
-        offsets[li] += ph.count
-    return [(xhat[b],
-             [np.concatenate(v) if v else np.zeros(0, np.int64)
-              for v in qs[b]],
-             escs[b], anchors[b]) for b in range(B)]
+        with trace.span("sweep.phase", level=ph.level, dim=ph.dim):
+            ax = ph.dim + 1
+            xv = xs[(slice(None),) + ph.view]
+            hv = xhat[(slice(None),) + ph.view]
+            with trace.span("sweep.layout", stage="sweep_layout"):
+                tvals = np.take(xv, ph.targets, axis=ax)
+            if phase_fn is None:
+                pred = predict_block(hv, ax, ph.targets, ph.stride, ph.n_dim,
+                                     interp, c.f32)
+                q = arith.bins(np, tvals, pred, c, np.int64)
+            else:
+                q, pred = phase_fn(xv, hv, ph, c)
+            with trace.span("sweep.screen", stage="screen"):
+                q, block, esc = arith.screen(tvals, pred, q, c)
+                _assign(hv, ax, ph.targets, block)
+                li = L - ph.level
+                for b in range(B):
+                    flat = np.flatnonzero(esc[b].ravel())
+                    trace.count("escapes", flat.size)
+                    qs[b][li].append(q[b].ravel())
+                    escs[b][li].append(
+                        (flat + offsets[li],
+                         tvals[b].ravel()[flat].astype(np.float64)))
+                offsets[li] += ph.count
+    # the screened streams, joined
+    with trace.span("sweep.screen", stage="screen"):
+        return [(xhat[b],
+                 [np.concatenate(v) if v else np.zeros(0, np.int64)
+                  for v in qs[b]],
+                 escs[b], anchors[b]) for b in range(B)]
 
 
 def reconstruct(shape: Sequence[int], interp: str, anchors: np.ndarray,

@@ -63,6 +63,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+from .. import trace
 from . import arith, bitplane, interpolation
 
 NUMPY = "numpy"
@@ -141,12 +142,18 @@ def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
 
     def phase_fn(xv, hv, ph, c):
         ax = ph.dim + 1
-        xm, lead = _to_lanes(xv, ax)
-        hm, _ = _to_lanes(hv, ax)
-        q, pred = interp_quant_batch(xm, hm, s=ph.stride, eb=eb,
-                                     interp=interp, interpret=interpret,
-                                     mesh=mesh)
-        return _from_lanes(q, lead, ax), _from_lanes(pred, lead, ax)
+        with trace.span("sweep.layout", stage="sweep_layout"):
+            xm, lead = _to_lanes(xv, ax)
+            hm, _ = _to_lanes(hv, ax)
+        with trace.span("sweep.kernel", stage="kernel_io"):
+            q, pred = interp_quant_batch(xm, hm, s=ph.stride, eb=eb,
+                                         interp=interp, interpret=interpret,
+                                         mesh=mesh)
+            q, pred = np.asarray(q), np.asarray(pred)
+            trace.count("h2d_bytes", xm.nbytes + hm.nbytes)
+            trace.count("d2h_bytes", q.nbytes + pred.nbytes)
+        with trace.span("sweep.layout", stage="sweep_layout"):
+            return _from_lanes(q, lead, ax), _from_lanes(pred, lead, ax)
 
     with _x64(arith.work_dtype(xs.dtype)):
         return interpolation.decorrelate_batch(xs, eb, interp, phase_fn)
@@ -175,9 +182,13 @@ def encode_level(q: np.ndarray, interpret: bool | None = None,
     # 1-D input only: the wrapper's 2-D path pads *columns*, which would
     # interleave pad zeros mid-stream and break blobs_from_packed's
     # valid-prefix truncation (level streams are always 1-D anyway)
-    q1 = np.ascontiguousarray(q, np.int32).reshape(-1)
-    packed, n = bitplane_pack(q1, interpret=interpret)
-    return bitplane.blobs_from_packed(np.asarray(packed), int(n))
+    with trace.span("pack.kernel", stage="kernel_io"):
+        q1 = np.ascontiguousarray(q, np.int32).reshape(-1)
+        packed, n = bitplane_pack(q1, interpret=interpret)
+        packed = np.asarray(packed)
+        trace.count("h2d_bytes", q1.nbytes)
+        trace.count("d2h_bytes", packed.nbytes)
+    return bitplane.blobs_from_packed(packed, int(n))
 
 
 def encode_level_batch(q2: np.ndarray, interpret: bool | None = None,
@@ -197,13 +208,16 @@ def encode_level_batch(q2: np.ndarray, interpret: bool | None = None,
     from ..kernels.bitplane_pack import (bitplane_pack_batch,
                                          bitplane_pack_sharded)
 
-    q2i = np.ascontiguousarray(q2, np.int32)
-    if mesh is not None:
-        packed, n_valid = bitplane_pack_sharded(q2i, mesh=mesh,
-                                                interpret=interpret)
-    else:
-        packed, n_valid = bitplane_pack_batch(q2i, interpret=interpret)
-    packed = np.asarray(packed)
+    with trace.span("pack.kernel", stage="kernel_io"):
+        q2i = np.ascontiguousarray(q2, np.int32)
+        if mesh is not None:
+            packed, n_valid = bitplane_pack_sharded(q2i, mesh=mesh,
+                                                    interpret=interpret)
+        else:
+            packed, n_valid = bitplane_pack_batch(q2i, interpret=interpret)
+        packed = np.asarray(packed)
+        trace.count("h2d_bytes", q2i.nbytes)
+        trace.count("d2h_bytes", packed.nbytes)
     return [bitplane.blobs_from_packed(packed[b], int(n_valid))
             for b in range(B)]
 

@@ -45,6 +45,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import trace
 from .. import arith, bitplane, container, interpolation, negabinary
 from . import backends, spec
 from .spec import ExecPolicy
@@ -90,22 +91,38 @@ def encode_array(x: np.ndarray, eb: float,
     if version == 2 and chunk_elems is None:
         raise ValueError("version=2 is the chunked container; "
                          "pass chunk_elems (or use version=1)")
-    x = np.asarray(x)
-    if relative:
-        eb = eb * (float(x.max()) - float(x.min()) or 1.0)
-    if eb <= 0:
-        raise ValueError("error bound must be positive")
-    arith.consts(eb, x.dtype)  # rejects bounds the field's arithmetic cannot honour
-    ctx = policy.bind(chunked=version != 1, encode=True)
+    with trace.request("encode"):
+        x = np.asarray(x)
+        trace.count("field_bytes", x.nbytes)
+        return _encode(x, eb, interp, relative, chunk_elems, policy,
+                       version)
+
+
+def _encode(x: np.ndarray, eb: float, interp: str, relative: bool,
+            chunk_elems: Optional[int], policy: ExecPolicy,
+            version: int) -> bytes:
+    """:func:`encode_array`'s work, inside its ``encode`` span."""
+    with trace.span("encode.prepare", stage="container"):
+        if relative:
+            eb = eb * (float(x.max()) - float(x.min()) or 1.0)
+        if eb <= 0:
+            raise ValueError("error bound must be positive")
+        # rejects bounds the field's arithmetic cannot honour
+        arith.consts(eb, x.dtype)
+        ctx = policy.bind(chunked=version != 1, encode=True)
+        if version != 1:
+            bounds = chunk_bounds(x.shape, chunk_elems
+                                  if chunk_elems is not None
+                                  else max(1, int(x.size)))
+            groups = shape_groups([b - a for a, b in bounds],
+                                  max_group=group_cap(ctx.mesh))
     if version == 1:
         return _compress_single(x, eb, interp, ctx.bk)
-    bounds = chunk_bounds(x.shape, chunk_elems if chunk_elems is not None
-                          else max(1, int(x.size)))
     bufs: List[Optional[bytes]] = [None] * len(bounds)
-    for idxs in shape_groups([b - a for a, b in bounds],
-                             max_group=group_cap(ctx.mesh)):
+    for idxs in groups:
         if ctx.batch_encode and len(idxs) > 1:
-            xs = np.stack([x[bounds[i][0]: bounds[i][1]] for i in idxs])
+            with trace.span("encode.prepare", stage="container"):
+                xs = np.stack([x[bounds[i][0]: bounds[i][1]] for i in idxs])
             for i, buf in zip(idxs, _compress_batch(xs, eb, interp, ctx)):
                 bufs[i] = buf
         else:
@@ -114,7 +131,8 @@ def encode_array(x: np.ndarray, eb: float,
                 bufs[i] = _compress_single(x[a:b], eb, interp, ctx.bk)
     writer = (container.write_v3_archive if version == 3
               else container.write_chunked_archive)
-    return writer(x.shape, x.dtype, eb, interp, bounds, bufs)
+    with trace.span("encode.container", stage="container"):
+        return writer(x.shape, x.dtype, eb, interp, bounds, bufs)
 
 
 def compress(x: np.ndarray, eb: float, interp: str = interpolation.CUBIC,
@@ -207,16 +225,19 @@ def _compress_single(x: np.ndarray, eb: float, interp: str,
     level_blobs, level_meta, esc_blobs = [], [], []
     for li in range(L):
         q = qs[li]
-        nb = negabinary.to_negabinary(q)
+        with trace.span("pack.negabinary", stage="negabinary"):
+            nb = negabinary.to_negabinary(q)
         blobs, nbits = bk.encode_level(q, nb)
-        delta = negabinary.truncation_loss_table(nb, nbits, eb)
+        with trace.span("pack.negabinary", stage="negabinary"):
+            delta = negabinary.truncation_loss_table(nb, nbits, eb)
         level_blobs.append(blobs)
         level_meta.append(dict(level=L - li, n=int(q.size), nbits=nbits,
                                delta_table=delta.tolist()))
         esc_blobs.append(_pack_escapes(escs[li]))
-    return container.write_archive(shape, dtype, eb, interp, L, anchors,
-                                   level_blobs, level_meta, esc_blobs,
-                                   vmax=_vmax(x))
+    with trace.span("encode.container", stage="container"):
+        return container.write_archive(shape, dtype, eb, interp, L, anchors,
+                                       level_blobs, level_meta, esc_blobs,
+                                       vmax=_vmax(x))
 
 
 def _compress_batch(xs: np.ndarray, eb: float, interp: str,
@@ -244,23 +265,27 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
     meta_pc: List[List[dict]] = [[] for _ in range(B)]
     escb_pc: List[List[bytes]] = [[] for _ in range(B)]
     for li in range(L):
-        q2 = np.stack([results[b][1][li] for b in range(B)])
-        nb2 = negabinary.to_negabinary(q2)
+        with trace.span("pack.negabinary", stage="negabinary"):
+            q2 = np.stack([results[b][1][li] for b in range(B)])
+            nb2 = negabinary.to_negabinary(q2)
         if mesh is not None:
             enc = bk.encode_level_sharded(q2, nb2, mesh)
         else:
             enc = bk.encode_level_batch(q2, nb2)
         for b in range(B):
             blobs, nbits = enc[b]
-            delta = negabinary.truncation_loss_table(nb2[b], nbits, eb)
+            with trace.span("pack.negabinary", stage="negabinary"):
+                delta = negabinary.truncation_loss_table(nb2[b], nbits, eb)
             blobs_pc[b].append(blobs)
             meta_pc[b].append(dict(level=L - li, n=int(q2.shape[1]),
                                    nbits=nbits, delta_table=delta.tolist()))
             escb_pc[b].append(_pack_escapes(results[b][2][li]))
-    return [container.write_archive(shape, dtype, eb, interp, L,
-                                    results[b][3], blobs_pc[b], meta_pc[b],
-                                    escb_pc[b], vmax=_vmax(xs[b]))
-            for b in range(B)]
+    with trace.span("encode.container", stage="container"):
+        return [container.write_archive(shape, dtype, eb, interp, L,
+                                        results[b][3], blobs_pc[b],
+                                        meta_pc[b], escb_pc[b],
+                                        vmax=_vmax(xs[b]))
+                for b in range(B)]
 
 
 def _vmax(x: np.ndarray) -> Optional[float]:
@@ -274,11 +299,15 @@ def _vmax(x: np.ndarray) -> Optional[float]:
 
 def _pack_escapes(phase_escs) -> bytes:
     """Escape records (level-global flat idx, exact residuals) -> one blob."""
-    idx_parts = [i for i, v in phase_escs if i.size]
-    val_parts = [v for i, v in phase_escs if i.size]
-    if not idx_parts:
-        return b""
-    idx = np.concatenate(idx_parts).astype(np.int64)
-    val = np.concatenate(val_parts).astype(np.float64)
-    raw = np.int64(idx.size).tobytes() + idx.tobytes() + val.tobytes()
-    return zlib.compress(raw, bitplane.zlib_level())
+    with trace.span("pack.zlib", stage="zlib"):
+        idx_parts = [i for i, v in phase_escs if i.size]
+        val_parts = [v for i, v in phase_escs if i.size]
+        if not idx_parts:
+            return b""
+        idx = np.concatenate(idx_parts).astype(np.int64)
+        val = np.concatenate(val_parts).astype(np.float64)
+        raw = np.int64(idx.size).tobytes() + idx.tobytes() + val.tobytes()
+        blob = zlib.compress(raw, bitplane.zlib_level())
+        trace.count("zlib_in_bytes", len(raw))
+        trace.count("zlib_out_bytes", len(blob))
+        return blob

@@ -38,11 +38,10 @@ chunk-batch engine's unit: B chunks, one launch — and a ``*_sharded``
 entry point that splits the same stack over a 1-D device mesh via
 ``parallel.codec_mesh.shard_vmap`` (every device runs the vmapped kernel
 on its local rows; one logical dispatch, mesh-size device launches).
-Every launch is counted — and its HBM traffic metered — by
-``kernels.dispatch`` (the batched-vs-looped reduction and the sharded
-accounting are asserted in tests; ``benchmarks/backend_speed.py`` records
-throughput and ``benchmarks/roofline_report.py`` turns the byte meters
-into achieved-vs-peak bandwidth).
+Every launch is counted by ``kernels.dispatch`` (the batched-vs-looped
+reduction and the sharded accounting are asserted in tests;
+``benchmarks/backend_speed.py`` records throughput); rooflines come from
+the device trace (``bench/roofline.py``).
 
 Each kernel ships with ops.py (jit'd public wrapper, interpret-mode
 switch) and ref.py or a pure-jnp XLA twin in kernel.py (the oracle for

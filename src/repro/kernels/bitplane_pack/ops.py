@@ -48,8 +48,7 @@ def bitplane_pack(q, *, interpret: bool | None = None):
     pr, pc = (-R) % ROWS_B, (-C) % GROUP
     if pr or pc:
         q = jnp.pad(q, ((0, pr), (0, pc)))
-    dispatch.record("bitplane_pack", interpret=interpret and not xla,
-                    nbytes=2 * q.size * 4)
+    dispatch.record("bitplane_pack", interpret=interpret and not xla)
     if xla:
         packed = bitplane_pack_xla(q)
     else:
@@ -94,15 +93,12 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
         def kernel(a):
             return bitplane_pack_pallas(a, interpret=interpret)
 
-    nbytes = 2 * q.size * 4
     if mesh is None:
-        dispatch.record("bitplane_pack", interpret=interpret and not xla,
-                        batch=B, nbytes=nbytes)
+        dispatch.record("bitplane_pack", interpret=interpret and not xla)
         packed = jax.vmap(kernel)(q)
     else:
         dispatch.record("bitplane_pack", interpret=interpret and not xla,
-                        batch=B, devices=codec_mesh.shard_count(mesh),
-                        nbytes=nbytes)
+                        devices=codec_mesh.shard_count(mesh))
         packed = codec_mesh.shard_vmap(kernel, mesh)(q)
     return packed[:B], n
 
@@ -142,9 +138,7 @@ def bitplane_unpack(plane_words, n: int, *, low_zero: int = 0,
         pw = jnp.pad(pw, ((0, 0), (0, pad)))
     pw = pw.reshape(32, R, _UNPACK_W)
     lz = _lz_array(low_zero)
-    # traffic: packed planes in + (q, nb) out
-    dispatch.record("bitplane_unpack", interpret=interpret and not xla,
-                    nbytes=(pw.size + 2 * R * _UNPACK_W * GROUP) * 4)
+    dispatch.record("bitplane_unpack", interpret=interpret and not xla)
     if xla:
         q, nb = bitplane_unpack_xla(pw, lz)
     else:
@@ -200,15 +194,12 @@ def bitplane_unpack_batch(plane_words, n: int, *, low_zero=0,
         def kernel(a, z):
             return bitplane_unpack_pallas(a, z, interpret=interpret)
 
-    nbytes = (pw.size + 2 * (B + padb) * R * _UNPACK_W * GROUP) * 4
     if mesh is None:
-        dispatch.record("bitplane_unpack", interpret=interpret and not xla,
-                        batch=B, nbytes=nbytes)
+        dispatch.record("bitplane_unpack", interpret=interpret and not xla)
         q, nb = jax.vmap(kernel)(pw, lz)
     else:
         dispatch.record("bitplane_unpack", interpret=interpret and not xla,
-                        batch=B, devices=codec_mesh.shard_count(mesh),
-                        nbytes=nbytes)
+                        devices=codec_mesh.shard_count(mesh))
         q, nb = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(pw, lz)
     q = q.reshape(B + padb, -1)[:B, :n]
     nb = nb.reshape(B + padb, -1)[:B, :n]

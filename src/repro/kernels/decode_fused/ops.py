@@ -107,17 +107,12 @@ def decode_fused_batch(plane_words, nb_old, n: int, *, eb, low_zero=0,
         else:
             kernel = fn
 
-        # traffic: planes (+ old words) in, new words + output out
-        nbytes = pw.size * 4 + (B + padb) * C * (
-            4 + np.dtype(dtype).itemsize + (0 if f32 else 4))
         if mesh is None:
-            dispatch.record("decode_fused", interpret=interpret and not xla,
-                            batch=B, nbytes=nbytes)
+            dispatch.record("decode_fused", interpret=interpret and not xla)
             nb_new, out = jax.vmap(kernel)(*args)
         else:
             dispatch.record("decode_fused", interpret=interpret and not xla,
-                            batch=B, devices=codec_mesh.shard_count(mesh),
-                            nbytes=nbytes)
+                            devices=codec_mesh.shard_count(mesh))
             nb_new, out = codec_mesh.shard_vmap(kernel, mesh,
                                                 n_out=2)(*args)
         nb_new = nb_new.reshape(B + padb, -1)[:B, :n]

@@ -11,7 +11,9 @@ The chunk-batching parity tests and ``benchmarks/backend_speed.py`` use
 dispatches than the per-chunk loop (< chunks x levels for the per-level
 pack/unpack ops).  Counting happens at the Python wrapper layer, so it is
 exact in both interpret mode (CPU) and compiled Mosaic (TPU): one wrapper
-call = one ``pallas_call`` execution.
+call = one ``pallas_call`` execution.  Each launch is also counted on the
+innermost open ``repro.trace`` span (``launches``), so a recorded request
+shows which stage issued it.
 
 Sharded execution adds a second axis to the accounting: a sharded call is
 ONE logical dispatch (one traced ``shard_map``, counted in ``_counts``
@@ -32,42 +34,31 @@ from collections import Counter
 from contextlib import contextmanager
 from typing import Dict, Iterator
 
+from .. import trace
+
 #: cumulative launches per kernel name since process start (or reset())
 _counts: Counter = Counter()
-#: cumulative batch elements covered per kernel name (launches weighted by
-#: their batch size; equals _counts for unbatched calls)
-_elements: Counter = Counter()
 #: cumulative per-device launches (launches weighted by mesh size; equals
 #: _counts for unsharded calls)
 _device_counts: Counter = Counter()
-#: cumulative bytes moved per kernel name (each launch's input + output
-#: array bytes, as accounted by its wrapper) — the numerator of the
-#: roofline report's achieved-bytes/s (``benchmarks/roofline_report.py``)
-_bytes: Counter = Counter()
 #: cumulative launches that ran in the Pallas interpreter (the CPU path);
 #: a run on the chip must record none — ``chip_smoke.py`` checks this
 _interpreted: Counter = Counter()
 
 
-def record(name: str, batch: int = 1, devices: int = 1,
-           nbytes: int = 0, interpret: bool = False) -> None:
-    """Count one kernel launch covering ``batch`` chunk-sized problems.
+def record(name: str, devices: int = 1, interpret: bool = False) -> None:
+    """Count one kernel launch.
 
     ``devices`` is the mesh fan-out of the launch: a ``shard_map``-ed call
     is one *logical* dispatch that runs on ``devices`` devices at once
-    (1 = unsharded, the default).  ``nbytes`` is the launch's memory
-    traffic (input + output array bytes, pad included — what the launch
-    actually moves), accumulated for roofline accounting.  ``interpret``
-    marks a launch that runs in the Pallas interpreter instead of a
-    compiled kernel.
+    (1 = unsharded, the default).  ``interpret`` marks a launch that runs
+    in the Pallas interpreter instead of a compiled kernel.
     """
     _counts[name] += 1
-    _elements[name] += batch
     _device_counts[name] += devices
-    if nbytes:
-        _bytes[name] += nbytes
     if interpret:
         _interpreted[name] += 1
+    trace.count("launches")
 
 
 def counts() -> Dict[str, int]:
@@ -89,11 +80,6 @@ def total() -> int:
     return sum(_counts.values())
 
 
-def bytes_counts() -> Dict[str, int]:
-    """Bytes moved per kernel since start/reset (copy)."""
-    return dict(_bytes)
-
-
 def interpreted_counts() -> Dict[str, int]:
     """Interpreted (non-compiled) launches per kernel since start/reset."""
     return dict(_interpreted)
@@ -102,9 +88,7 @@ def interpreted_counts() -> Dict[str, int]:
 def reset() -> None:
     _interpreted.clear()
     _counts.clear()
-    _elements.clear()
     _device_counts.clear()
-    _bytes.clear()
 
 
 @contextmanager
@@ -123,22 +107,6 @@ def measure() -> Iterator[Dict[str, int]]:
         yield out
     finally:
         out.update((_counts - before))
-
-
-@contextmanager
-def measure_bytes() -> Iterator[Dict[str, int]]:
-    """Like :func:`measure`, but collecting bytes moved per kernel.
-
-    The yielded dict maps kernel name to the total input + output array
-    bytes its launches moved inside the block — the numerator of
-    achieved bytes/s in the roofline report.
-    """
-    before = Counter(_bytes)
-    out: Dict[str, int] = {}
-    try:
-        yield out
-    finally:
-        out.update((_bytes - before))
 
 
 @contextmanager

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 
 from ...core import arith
 from .. import dispatch, mode
-from .kernel import interp_quant_pallas, interp_quant_xla, sweep_geometry
+from .kernel import interp_quant_pallas, interp_quant_xla
 
 
 def _on_tpu() -> bool:
@@ -49,7 +49,7 @@ def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
     x = jnp.asarray(x)
     xhat = jnp.asarray(xhat, x.dtype)
     c = arith.consts(eb, x.dtype)
-    B, R, C = x.shape
+    B = x.shape[0]
     padb = 0
     if mesh is not None:
         from ...parallel import codec_mesh
@@ -66,19 +66,12 @@ def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
             return interp_quant_pallas(a, b, s=s, c=c, interp=interp,
                                        interpret=interpret)
 
-    # the kernel's operands: known points, targets, bins, predictions (the
-    # wrapper's gathers that build the first two are XLA's, not metered)
-    T, Ne = sweep_geometry(C, s)
-    nbytes = (B + padb) * R * ((Ne + 3 + T) * x.dtype.itemsize
-                               + T * (4 + x.dtype.itemsize))
     if mesh is None:
-        dispatch.record("interp_quant", interpret=interpret and not xla,
-                        batch=B, nbytes=nbytes)
+        dispatch.record("interp_quant", interpret=interpret and not xla)
         q, pred = jax.vmap(kernel)(x, xhat)
     else:
         dispatch.record("interp_quant", interpret=interpret and not xla,
-                        batch=B, devices=codec_mesh.shard_count(mesh),
-                        nbytes=nbytes)
+                        devices=codec_mesh.shard_count(mesh))
         q, pred = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(x, xhat)
     return q[:B], pred[:B]
 

@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import dispatch, mode
-from ..interp_quant.kernel import sweep_geometry
 from .kernel import interp_recon_pallas, interp_recon_xla
 
 
@@ -47,7 +46,7 @@ def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
     xla = mode.use_xla()
     xhat = jnp.asarray(xhat)
     res = jnp.asarray(res, xhat.dtype)
-    B, R, C = xhat.shape
+    B = xhat.shape[0]
     padb = 0
     if mesh is not None:
         from ...parallel import codec_mesh
@@ -64,18 +63,12 @@ def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
             return interp_recon_pallas(a, b, s=s, interp=interp,
                                        interpret=interpret)
 
-    # the kernel's operands: known points, residuals, reconstruction (the
-    # wrapper's gather that builds the first is XLA's, not metered)
-    T, Ne = sweep_geometry(C, s)
-    nbytes = (B + padb) * R * (Ne + 3 + 2 * T) * xhat.dtype.itemsize
     if mesh is None:
-        dispatch.record("interp_recon", interpret=interpret and not xla,
-                        batch=B, nbytes=nbytes)
+        dispatch.record("interp_recon", interpret=interpret and not xla)
         out = jax.vmap(kernel)(xhat, res)
     else:
         dispatch.record("interp_recon", interpret=interpret and not xla,
-                        batch=B, devices=codec_mesh.shard_count(mesh),
-                        nbytes=nbytes)
+                        devices=codec_mesh.shard_count(mesh))
         out = codec_mesh.shard_vmap(kernel, mesh)(xhat, res)
     return out[:B]
 

@@ -280,14 +280,3 @@ def test_refine_interleave_dispatch_and_bits():
         assert np.array_equal(a, b)
     assert outs["jax"][3]["decode_fused"] <= \
         outs["jax_unfused"][3]["bitplane_unpack"]
-
-
-def test_fused_records_kernel_bytes():
-    """The roofline report reads bytes-moved per dispatch: the fused path
-    must account its traffic."""
-    x = smooth_field((40, 40), 5)
-    buf = compress(x, 1e-5)
-    with dispatch.measure_bytes() as nb:
-        retrieve(open_archive(buf), error_bound=1e-3, backend="jax")
-    assert nb.get("decode_fused", 0) > 0
-    assert nb.get("interp_recon", 0) > 0
