@@ -160,7 +160,7 @@ def probe_arith() -> None:
               f"arith: {interp} reconstruction equals numpy")
     bins = rng.integers(-arith.QMAX, arith.QMAX, 50000)
     bins[::2] //= 1 << 9                          # both sides of 2**24
-    packed, n = bitplane_pack(jnp.asarray(bins, jnp.int32))
+    packed, n, _ = bitplane_pack(jnp.asarray(bins, jnp.int32))
     words = np.asarray(packed).reshape(32, -1)
     for low_zero in (0, 5):
         nb, res = decode_fused(words, None, n, eb=eb, low_zero=low_zero,

@@ -6,7 +6,8 @@ compression pipeline through the Pallas TPU kernels instead of numpy:
   * ``kernels.interp_quant``  — fused interpolation-predict + quantize for
     every (level, dim) phase sweep (§4.1–§4.2 in one VMEM pass);
   * ``kernels.bitplane_pack`` — negabinary + 2-bit-prefix XOR + bitplane
-    packing collapsed to three integer ops per element (§4.4).
+    packing collapsed to three integer ops per element (§4.4), with the
+    level's truncation-loss extrema reduced in the same program.
 
 ``retrieve``/``refine``/``decompress(..., backend="jax")`` route the decode
 direction — the operation progressive compression exists to make fast
@@ -167,16 +168,19 @@ def decorrelate_sharded(xs: np.ndarray, eb: float, interp: str, mesh,
     return decorrelate_batch(xs, eb, interp, interpret=interpret, mesh=mesh)
 
 
-def encode_level(q: np.ndarray, interpret: bool | None = None,
-                 ) -> Tuple[List[bytes], int]:
-    """Kernel-backed twin of ``bitplane.encode_level`` (takes q, not nb).
+def encode_level(q: np.ndarray, eb: float, interpret: bool | None = None,
+                 ) -> Tuple[List[bytes], int, np.ndarray]:
+    """Kernel-backed twin of the numpy backend's ``encode_level`` slot:
+    ``(blobs, nbits, delta_table)`` of one level stream.
 
     The Pallas kernel fuses negabinary conversion, XOR-predictive coding and
-    bit-transposition; the host only truncates pad bytes and zlibs each
-    plane.  Byte-identical blobs to the numpy encoder.
+    bit-transposition, and the same device program reduces the stream to
+    its truncation-loss extrema; the host only truncates pad bytes, zlibs
+    each plane and finishes the table (:func:`_loss_tables`).  Byte-
+    identical blobs and tables to the numpy encoder.
     """
     if q.size == 0:
-        return [], 0
+        return [], 0, np.zeros(1)
     from ..kernels.bitplane_pack import bitplane_pack
 
     # 1-D input only: the wrapper's 2-D path pads *columns*, which would
@@ -184,49 +188,74 @@ def encode_level(q: np.ndarray, interpret: bool | None = None,
     # valid-prefix truncation (level streams are always 1-D anyway)
     with trace.span("pack.kernel", stage="kernel_io"):
         q1 = np.ascontiguousarray(q, np.int32).reshape(-1)
-        packed, n = bitplane_pack(q1, interpret=interpret)
-        packed = np.asarray(packed)
+        packed, n, extrema = bitplane_pack(q1, interpret=interpret)
+        packed, extrema = np.asarray(packed), np.asarray(extrema)
         trace.count("h2d_bytes", q1.nbytes)
-        trace.count("d2h_bytes", packed.nbytes)
-    return bitplane.blobs_from_packed(packed, int(n))
+        trace.count("d2h_bytes", packed.nbytes + extrema.nbytes)
+    blobs, nbits = bitplane.blobs_from_packed(packed, int(n))
+    (delta,) = _loss_tables(extrema[None], [nbits], eb)
+    return blobs, nbits, delta
 
 
-def encode_level_batch(q2: np.ndarray, interpret: bool | None = None,
-                       mesh=None) -> List[Tuple[List[bytes], int]]:
+def encode_level_batch(q2: np.ndarray, eb: float,
+                       interpret: bool | None = None, mesh=None,
+                       ) -> List[Tuple[List[bytes], int, np.ndarray]]:
     """Batched twin of :func:`encode_level`: (B, n) stacked level streams.
 
     One vmapped pack launch covers the whole stack; the host then truncates
     and zlibs each chunk's planes independently (per-chunk ``nbits`` and
-    blobs), so every returned ``(blobs, nbits)`` is byte-identical to an
-    unbatched :func:`encode_level` call on that row.  With ``mesh``, the
-    stack is split over the 1-D codec mesh first (one launch per device;
+    blobs) and finishes each chunk's table, so every returned ``(blobs,
+    nbits, delta_table)`` is byte-identical to an unbatched
+    :func:`encode_level` call on that row.  With ``mesh``, the stack is
+    split over the 1-D codec mesh first (one launch per device;
     :func:`encode_level_sharded` is the registry-facing alias).
     """
     B, n = q2.shape
     if n == 0:
-        return [([], 0) for _ in range(B)]
+        return [([], 0, np.zeros(1)) for _ in range(B)]
     from ..kernels.bitplane_pack import (bitplane_pack_batch,
                                          bitplane_pack_sharded)
 
     with trace.span("pack.kernel", stage="kernel_io"):
         q2i = np.ascontiguousarray(q2, np.int32)
         if mesh is not None:
-            packed, n_valid = bitplane_pack_sharded(q2i, mesh=mesh,
-                                                    interpret=interpret)
+            packed, n_valid, extrema = bitplane_pack_sharded(
+                q2i, mesh=mesh, interpret=interpret)
         else:
-            packed, n_valid = bitplane_pack_batch(q2i, interpret=interpret)
-        packed = np.asarray(packed)
+            packed, n_valid, extrema = bitplane_pack_batch(
+                q2i, interpret=interpret)
+        packed, extrema = np.asarray(packed), np.asarray(extrema)
         trace.count("h2d_bytes", q2i.nbytes)
-        trace.count("d2h_bytes", packed.nbytes)
-    return [bitplane.blobs_from_packed(packed[b], int(n_valid))
-            for b in range(B)]
+        trace.count("d2h_bytes", packed.nbytes + extrema.nbytes)
+    enc = [bitplane.blobs_from_packed(packed[b], int(n_valid))
+           for b in range(B)]
+    deltas = _loss_tables(extrema, [nbits for _, nbits in enc], eb)
+    return [(blobs, nbits, delta)
+            for (blobs, nbits), delta in zip(enc, deltas)]
 
 
-def encode_level_sharded(q2: np.ndarray, mesh,
+def encode_level_sharded(q2: np.ndarray, eb: float, mesh,
                          interpret: bool | None = None,
-                         ) -> List[Tuple[List[bytes], int]]:
+                         ) -> List[Tuple[List[bytes], int, np.ndarray]]:
     """Sharded per-level pack: :func:`encode_level_batch` over a mesh."""
-    return encode_level_batch(q2, interpret=interpret, mesh=mesh)
+    return encode_level_batch(q2, eb, interpret=interpret, mesh=mesh)
+
+
+def _loss_tables(extrema: np.ndarray, nbits: List[int],
+                 eb: float) -> List[np.ndarray]:
+    """Each stream's ``negabinary.truncation_loss_table`` from its device
+    ``loss_extrema``: entry b is max |low-b-digit value| * 2 eb, the
+    reference's own float64 expression, so the tables are bit-identical
+    for every stream of bins within +-``arith.QMAX``."""
+    out = []
+    with trace.span("pack.negabinary", stage="negabinary"):
+        for (mx, mn), nb in zip(extrema, nbits):
+            trace.count("loss_tables_device")
+            delta = np.zeros(nb + 1, np.float64)
+            for b in range(1, nb + 1):
+                delta[b] = float(max(int(mx[b]), -int(mn[b]))) * 2.0 * eb
+            out.append(delta)
+    return out
 
 
 # ----------------------------------------------------------------- decode

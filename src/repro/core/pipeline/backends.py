@@ -15,12 +15,15 @@ suites pin this down):
       compression-side sweep in the field's arithmetic (``core.arith``):
       per-level int64 bin streams + escape records with level-global
       indices (see ``interpolation.decorrelate_batch``).
-  encode_level(q_int64, nb_uint32) -> (blobs MSB-first, nbits)
-      negabinary + XOR-predictive bitplane packing of one level stream;
-      both representations of the same values are passed so each substrate
-      starts from whichever it prefers (numpy from the host-precomputed
-      negabinary words, the kernel from the raw bins it converts on-device)
-      without a redundant O(n) conversion.
+  encode_level(q_int64, eb) -> (blobs MSB-first, nbits, delta_table)
+      negabinary + XOR-predictive bitplane packing of one level stream of
+      bins within +-``arith.QMAX`` (the escape screen stores no other),
+      and its truncation-loss table (``negabinary.truncation_loss_table``,
+      the archive's ``delta_table``).  The backend that makes the
+      negabinary words makes the table: numpy converts on the host and
+      tables there; the kernel converts the raw bins on the device and
+      reduces them to the table's maxima in the same program, so the
+      host never converts.
   decode_level(blobs, nbits, n) -> uint32 truncated negabinary
       inverse of encode_level for a loaded MSB-first blob prefix
       (None = not loaded; b'' = loaded, all-zero encoded plane).
@@ -37,7 +40,8 @@ scheduling and ``docs/architecture.md`` for the full dataflow):
 
   decorrelate_batch(xs (B, *shape), eb, interp) -> B-list of the
       scalar tuples;
-  encode_level_batch(q2 (B, n), nb2 (B, n)) -> B-list of (blobs, nbits);
+  encode_level_batch(q2 (B, n) int32, eb) -> B-list of (blobs, nbits,
+      delta_table);
   decode_level_batch(B blob-prefix lists w/ equal nbits AND equal loaded
       prefix, nbits, n) -> B-list of truncated negabinary arrays;
   reconstruct_batch(shape, interp, anchors (B, ...), yhat [(B, n_l)],
@@ -49,7 +53,7 @@ mesh (``parallel.codec_mesh``), over which the stack axis is split so
 every mesh device executes the batched primitive on its local chunks:
 
   decorrelate_sharded(xs, eb, interp, mesh)        -> as decorrelate_batch
-  encode_level_sharded(q2, nb2, mesh)              -> as encode_level_batch
+  encode_level_sharded(q2, eb, mesh)               -> as encode_level_batch
   decode_level_sharded(blob_lists, nbits, n, mesh) -> as decode_level_batch
   reconstruct_sharded(shape, interp, anchors, yhat, mesh, overrides=,
       out_dtype=, dtype=)                          -> as reconstruct_batch
@@ -107,7 +111,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .. import bitplane, interpolation, jax_backend
+from ... import trace
+from .. import bitplane, interpolation, jax_backend, negabinary
 # single source for the backend-name constants (the reverse import would be
 # circular: jax_backend.resolve delegates here function-locally)
 from ..jax_backend import AUTO, JAX, JAX_UNFUSED, NUMPY
@@ -203,22 +208,13 @@ def get(choice) -> CodecBackend:
 
 # ---------------------------------------------------------- numpy reference
 
-def _numpy_encode_level(q: np.ndarray, nb: np.ndarray) -> Tuple[List[bytes], int]:
-    return bitplane.encode_level(nb)
-
-
-def _jax_encode_level(q: np.ndarray, nb: np.ndarray) -> Tuple[List[bytes], int]:
-    return jax_backend.encode_level(q)
-
-
-def _jax_encode_level_batch(q2: np.ndarray, nb2: np.ndarray,
-                            ) -> List[Tuple[List[bytes], int]]:
-    return jax_backend.encode_level_batch(q2)
-
-
-def _jax_encode_level_sharded(q2: np.ndarray, nb2: np.ndarray, mesh,
-                              ) -> List[Tuple[List[bytes], int]]:
-    return jax_backend.encode_level_sharded(q2, mesh)
+def _numpy_encode_level(q: np.ndarray, eb: float,
+                        ) -> Tuple[List[bytes], int, np.ndarray]:
+    with trace.span("pack.negabinary", stage="negabinary"):
+        nb = negabinary.to_negabinary(q)
+    blobs, nbits = bitplane.encode_level(nb)
+    with trace.span("pack.negabinary", stage="negabinary"):
+        return blobs, nbits, negabinary.truncation_loss_table(nb, nbits, eb)
 
 
 register(CodecBackend(
@@ -233,15 +229,15 @@ register(CodecBackend(
 register(CodecBackend(
     name=JAX,
     decorrelate=jax_backend.decorrelate,
-    encode_level=_jax_encode_level,
+    encode_level=jax_backend.encode_level,
     decode_level=jax_backend.decode_level,
     reconstruct=jax_backend.reconstruct,
     decorrelate_batch=jax_backend.decorrelate_batch,
-    encode_level_batch=_jax_encode_level_batch,
+    encode_level_batch=jax_backend.encode_level_batch,
     decode_level_batch=jax_backend.decode_level_batch,
     reconstruct_batch=jax_backend.reconstruct_batch,
     decorrelate_sharded=jax_backend.decorrelate_sharded,
-    encode_level_sharded=_jax_encode_level_sharded,
+    encode_level_sharded=jax_backend.encode_level_sharded,
     decode_level_sharded=jax_backend.decode_level_sharded,
     reconstruct_sharded=jax_backend.reconstruct_sharded,
     decode_level_fused=jax_backend.decode_level_fused,
@@ -259,15 +255,15 @@ register(CodecBackend(
 register(CodecBackend(
     name=JAX_UNFUSED,
     decorrelate=jax_backend.decorrelate,
-    encode_level=_jax_encode_level,
+    encode_level=jax_backend.encode_level,
     decode_level=jax_backend.decode_level,
     reconstruct=jax_backend.reconstruct,
     decorrelate_batch=jax_backend.decorrelate_batch,
-    encode_level_batch=_jax_encode_level_batch,
+    encode_level_batch=jax_backend.encode_level_batch,
     decode_level_batch=jax_backend.decode_level_batch,
     reconstruct_batch=jax_backend.reconstruct_batch,
     decorrelate_sharded=jax_backend.decorrelate_sharded,
-    encode_level_sharded=_jax_encode_level_sharded,
+    encode_level_sharded=jax_backend.encode_level_sharded,
     decode_level_sharded=jax_backend.decode_level_sharded,
     reconstruct_sharded=jax_backend.reconstruct_sharded,
 ))

@@ -46,7 +46,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from ... import trace
-from .. import arith, bitplane, container, interpolation, negabinary
+from .. import arith, bitplane, container, interpolation
 from . import backends, spec
 from .spec import ExecPolicy
 
@@ -225,11 +225,7 @@ def _compress_single(x: np.ndarray, eb: float, interp: str,
     level_blobs, level_meta, esc_blobs = [], [], []
     for li in range(L):
         q = qs[li]
-        with trace.span("pack.negabinary", stage="negabinary"):
-            nb = negabinary.to_negabinary(q)
-        blobs, nbits = bk.encode_level(q, nb)
-        with trace.span("pack.negabinary", stage="negabinary"):
-            delta = negabinary.truncation_loss_table(nb, nbits, eb)
+        blobs, nbits, delta = bk.encode_level(q, eb)
         level_blobs.append(blobs)
         level_meta.append(dict(level=L - li, n=int(q.size), nbits=nbits,
                                delta_table=delta.tolist()))
@@ -266,16 +262,16 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
     escb_pc: List[List[bytes]] = [[] for _ in range(B)]
     for li in range(L):
         with trace.span("pack.negabinary", stage="negabinary"):
-            q2 = np.stack([results[b][1][li] for b in range(B)])
-            nb2 = negabinary.to_negabinary(q2)
+            # stored bins lie within +-arith.QMAX: stack them as the
+            # pack's int32 operand directly
+            q2 = np.stack([results[b][1][li] for b in range(B)],
+                          dtype=np.int32)
         if mesh is not None:
-            enc = bk.encode_level_sharded(q2, nb2, mesh)
+            enc = bk.encode_level_sharded(q2, eb, mesh)
         else:
-            enc = bk.encode_level_batch(q2, nb2)
+            enc = bk.encode_level_batch(q2, eb)
         for b in range(B):
-            blobs, nbits = enc[b]
-            with trace.span("pack.negabinary", stage="negabinary"):
-                delta = negabinary.truncation_loss_table(nb2[b], nbits, eb)
+            blobs, nbits, delta = enc[b]
             blobs_pc[b].append(blobs)
             meta_pc[b].append(dict(level=L - li, n=int(q2.shape[1]),
                                    nbits=nbits, delta_table=delta.tolist()))

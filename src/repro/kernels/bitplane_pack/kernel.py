@@ -32,6 +32,11 @@ element carries its own mask width) instead of fragmenting a chunk group
 into one launch per ``(nbits, prefix)`` bucket, and refine ladders stop
 re-tracing the kernel once per distinct prefix.
 
+``loss_extrema`` is the XLA reduction the pack's program runs beside the
+kernel over the same bins: per stream, the extremes of every low-digit
+value, from which the host finishes the level's truncation-loss table
+without converting the stream to negabinary itself.
+
 ``unpack_words`` is the pure-jnp core shared by the Pallas kernel body and
 the jitted XLA twin (``IPCOMP_KERNEL_MODE=xla`` — see ``kernels.mode``):
 one definition, so the two execution modes cannot drift.
@@ -76,6 +81,35 @@ def pack_core(qt):
         m ^= (m << j) & 0xFFFFFFFF
     # row r now holds bit (31 - r) of every input word
     return [a[GROUP - 1 - k] for k in range(32)]
+
+
+#: for b = 0..32: the mask of the b low negabinary digits, and the digits
+#: of M (the negative powers of -2) under it
+_LOW = np.array([(1 << b) - 1 for b in range(33)], np.uint32)
+_LOW_M = _LOW & NEG_M
+
+
+def loss_extrema(q):
+    """(R, C) int32 bins -> (2, 33) int32: for b = 0..32, the max (row 0)
+    and min (row 1) over the stream of the value of each bin's b low
+    negabinary digits.
+
+    Truncating b digits drops exactly those digits, so |value - truncated
+    value| is |low-digit value|, and ``negabinary.truncation_loss_table``'s
+    entry b is max(max, -min) * 2 eb.  The low-digit value is the
+    negabinary decode restricted to the mask, ((nb & m) ^ (M & m)) -
+    (M & m).  For bins within +-``arith.QMAX`` it fits int32 at every b,
+    and the reference's decode does not wrap, so the two agree exactly.
+    Zero pad bins add zeros, which cannot change max(max, -min).
+    """
+    u32 = jnp.uint32
+    nb = (q.astype(u32) + NEG_M) ^ NEG_M
+    shape = (33,) + (1,) * q.ndim
+    low = jnp.asarray(_LOW).reshape(shape)
+    low_m = jnp.asarray(_LOW_M).reshape(shape)
+    v = jax.lax.bitcast_convert_type(((nb & low) ^ low_m) - low_m, jnp.int32)
+    axes = tuple(range(1, v.ndim))
+    return jnp.stack([jnp.max(v, axis=axes), jnp.min(v, axis=axes)])
 
 
 def _kernel(q_ref, out_ref):

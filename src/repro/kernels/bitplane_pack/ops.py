@@ -1,13 +1,16 @@
 """Public jit'd wrappers for the bitplane packing / unpacking kernels."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import dispatch, mode
 from .kernel import (GROUP, ROWS_B, bitplane_pack_pallas, bitplane_pack_xla,
-                     bitplane_unpack_pallas, bitplane_unpack_xla)
+                     bitplane_unpack_pallas, bitplane_unpack_xla,
+                     loss_extrema)
 
 # words per row fed to the unpack kernel: 128 lanes of uint32 = 4096
 # elements per row, matching the 1-D pack wrapper's C = 128 * GROUP
@@ -27,11 +30,22 @@ def _lz_array(low_zero, B: int | None = None):
     return jnp.asarray(lz).reshape(B, 1, 1)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret", "xla"))
+def _pack_level(q, *, interpret: bool, xla: bool):
+    """(R, C) int32 bins -> (packed planes, ``loss_extrema``): the pack and
+    the stream's truncation-loss extrema as one device program over the
+    bins the pack has uploaded anyway."""
+    packed = (bitplane_pack_xla(q) if xla
+              else bitplane_pack_pallas(q, interpret=interpret))
+    return packed, loss_extrema(q)
+
+
 def bitplane_pack(q, *, interpret: bool | None = None):
     """(n,) or (R, C) int32 -> (32, R', W) packed planes (+ padding info).
 
-    Pads to (ROWS_B, GROUP) multiples; returns (packed, n_valid) where the
-    flattened valid prefix of each plane covers the original n elements.
+    Pads to (ROWS_B, GROUP) multiples; returns (packed, n_valid, extrema)
+    where the flattened valid prefix of each plane covers the original n
+    elements and ``extrema`` is the stream's (2, 33) ``loss_extrema``.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -49,20 +63,19 @@ def bitplane_pack(q, *, interpret: bool | None = None):
     if pr or pc:
         q = jnp.pad(q, ((0, pr), (0, pc)))
     dispatch.record("bitplane_pack", interpret=interpret and not xla)
-    if xla:
-        packed = bitplane_pack_xla(q)
-    else:
-        packed = bitplane_pack_pallas(q, interpret=interpret)
-    return packed, n
+    packed, extrema = _pack_level(q, interpret=interpret, xla=xla)
+    return packed, n, extrema
 
 
 def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
-    """(B, n) int32 stacked 1-D level streams -> ((B, 32, R, W) packed, n).
+    """(B, n) int32 stacked 1-D level streams -> ((B, 32, R, W) packed, n,
+    (B, 2, 33) extrema).
 
     Each batch row gets the 1-D wrapper's layout — pad at the END of its
     flat stream, so ``blobs_from_packed`` per chunk sees the same valid
     prefix as an unbatched call — and the whole stack runs as ONE
-    ``jax.vmap``-ed kernel launch instead of B.
+    ``jax.vmap``-ed kernel launch instead of B, which also returns each
+    row's ``loss_extrema``.
 
     With ``mesh``, the batch axis is zero-padded to a mesh multiple
     (all-zero pad streams pack to all-zero words, sliced back off) and
@@ -86,21 +99,15 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
     if pr:
         q = jnp.pad(q, ((0, 0), (0, pr), (0, 0)))
 
-    if xla:
-        def kernel(a):
-            return bitplane_pack_xla(a)
-    else:
-        def kernel(a):
-            return bitplane_pack_pallas(a, interpret=interpret)
-
+    kernel = functools.partial(_pack_level, interpret=interpret, xla=xla)
     if mesh is None:
         dispatch.record("bitplane_pack", interpret=interpret and not xla)
-        packed = jax.vmap(kernel)(q)
+        packed, extrema = jax.vmap(kernel)(q)
     else:
         dispatch.record("bitplane_pack", interpret=interpret and not xla,
                         devices=codec_mesh.shard_count(mesh))
-        packed = codec_mesh.shard_vmap(kernel, mesh)(q)
-    return packed[:B], n
+        packed, extrema = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(q)
+    return packed[:B], n, extrema[:B]
 
 
 def bitplane_pack_sharded(q, *, mesh, interpret: bool | None = None):

@@ -197,13 +197,18 @@ def test_backend_resolve():
 
 # ------------------------------------------- bitplane_pack blob parity
 
+EB = 3.7e-4
+
+
 def _enc_parity(q):
     q = np.asarray(q, np.int64)
     nb = nbmod.to_negabinary(q)
     want = bp.encode_level(nb)
-    got = jax_backend.encode_level(q)
+    got = jax_backend.encode_level(q, EB)
     assert got[1] == want[1], "nbits mismatch"
     assert got[0] == want[0], "blob mismatch"
+    table = nbmod.truncation_loss_table(nb, want[1], EB)
+    assert got[2].tobytes() == table.tobytes(), "loss table mismatch"
 
 
 @pytest.mark.parametrize("n", [1, 7, 255, 256, 4095, 4096, 4097, 8192 + 3])
@@ -215,7 +220,8 @@ def test_encode_level_padding_edges(n):
 
 def test_encode_level_nbits_zero():
     _enc_parity(np.zeros(100, np.int64))        # all-zero: ([], 0)
-    assert jax_backend.encode_level(np.zeros(0, np.int64)) == ([], 0)
+    blobs, nbits, delta = jax_backend.encode_level(np.zeros(0, np.int64), EB)
+    assert (blobs, nbits, delta.tolist()) == ([], 0, [0.0])
 
 
 def test_encode_level_all_zero_middle_plane():

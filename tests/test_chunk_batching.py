@@ -49,7 +49,7 @@ def test_decode_level_batch_mixed_prefixes_one_dispatch():
     like its scalar call."""
     from repro.core import jax_backend
     q = np.arange(-50, 50, dtype=np.int64)
-    blobs, nbits = jax_backend.encode_level(q)
+    blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
     full = list(blobs)
     shorter = [blobs[i] if i < nbits - 1 else None for i in range(nbits)]
     shortest = [blobs[i] if i < 2 else None for i in range(nbits)]
@@ -104,6 +104,39 @@ def test_batched_compress_fewer_dispatches_than_chunks_x_levels():
     # the sweep dispatches shrink too, and so does the overall count
     assert bat["interp_quant"] < loop["interp_quant"]
     assert sum(bat.values()) < sum(loop.values())
+
+
+def test_isabel_settings_archives_and_loss_table_counts():
+    """The benchmark's codec settings (relative bound, cubic, v3, equal
+    chunks plus a ragged tail) on a small float32 field: jax and numpy
+    write the same bytes, the jax backend makes every level's
+    truncation-loss table from its device extrema and the numpy backend
+    none (it tables on the host)."""
+    from collections import Counter
+
+    from repro import trace
+    from repro.api import Codec, ExecPolicy
+
+    x = _chunky_field((18, 16, 12)).astype(np.float32)
+    codec = Codec(eb=1e-6, relative=True, interp=CUBIC,
+                  chunk_elems=4 * 16 * 12, version=3)
+    arcs, counts = {}, {}
+    for backend in ("jax", "numpy"):
+        trace.clear()
+        with trace.recording():
+            arcs[backend] = codec.compress(
+                x, ExecPolicy(backend=backend)).tobytes()
+        counts[backend] = Counter()
+        for r in trace.records():
+            counts[backend].update(r.counts)
+    trace.clear()
+    assert arcs["jax"] == arcs["numpy"]
+    r = open_archive(arcs["jax"])
+    shapes = [r.chunk_reader(i).meta.L for i in range(len(r.meta.chunks))]
+    assert len(shapes) == 5             # 4 equal slabs + a 2-row tail
+    tables = sum(shapes)
+    assert counts["jax"]["loss_tables_device"] == tables
+    assert counts["numpy"]["loss_tables_device"] == 0
 
 
 def test_numpy_backend_batch_flag_is_noop():

@@ -61,7 +61,7 @@ def test_decode_level_fused_matches_unfused(nprev, want):
 
     rng = np.random.default_rng(nprev * 16 + want)
     q = rng.integers(-900, 900, size=1023).astype(np.int64)
-    blobs, nbits = jax_backend.encode_level(q)
+    blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
     eb = 3.7e-4
     prev = [blobs[i] if i < min(nprev, nbits) else None for i in range(nbits)]
     cur = [blobs[i] if i < min(want, nbits) else None for i in range(nbits)]
@@ -86,7 +86,7 @@ def test_decode_level_fused_f32_matches_host(want):
     rng = np.random.default_rng(want)
     q = rng.integers(-(1 << 29), 1 << 29, size=1500).astype(np.int64)
     q[::3] //= 1 << 8
-    blobs, nbits = jax_backend.encode_level(q)
+    blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
     cur = [blobs[i] if i < min(want, nbits) else None for i in range(nbits)]
     nb_ref = jax_backend.decode_level(cur, nbits, q.size)
     eb = 2.5e-6
@@ -106,7 +106,7 @@ def test_decode_level_fused_batch_mixed_prefixes_and_ebs():
 
     rng = np.random.default_rng(9)
     q = rng.integers(-500, 500, size=640).astype(np.int64)
-    blobs, nbits = jax_backend.encode_level(q)
+    blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
     wants = [nbits, max(1, nbits - 2), 1, 0]
     ebs = [1e-3, 2e-4, 5e-5, 1e-3]
     blob_lists = [[blobs[i] if i < w else None for i in range(nbits)]
@@ -135,7 +135,7 @@ def test_inflate_level_prefetch_seam():
     """``decode_level_fused(words=...)`` consumes a pre-inflated
     ``inflate_level`` result unchanged — the two-slot prefetch seam."""
     q = np.arange(-200, 200, dtype=np.int64)
-    blobs, nbits = jax_backend.encode_level(q)
+    blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
     nb_old = np.zeros(q.size, np.uint32)
     direct = jax_backend.decode_level_fused(blobs, nbits, q.size, nb_old,
                                             1e-4)

@@ -69,7 +69,7 @@ def test_interp_quant_error_bound():
 def test_bitplane_pack_matches_ref(shape):
     rng = np.random.default_rng(shape[1])
     q = rng.integers(-(1 << 20), 1 << 20, size=shape).astype(np.int32)
-    packed, n = bitplane_pack(q)
+    packed, n, _ = bitplane_pack(q)
     # oracle on the padded array the wrapper actually packed
     R, C = shape
     pr, pc = (-R) % 8, (-C) % 32
@@ -84,7 +84,7 @@ def test_bitplane_pack_prefix_decodes_to_truncation(keep):
     invariant the CPU container relies on (§4.4)."""
     rng = np.random.default_rng(keep)
     q = rng.integers(-(1 << 24), 1 << 24, size=(8, 64)).astype(np.int32)
-    packed, _ = bitplane_pack(q)
+    packed, _, _ = bitplane_pack(q)
     got_nb = np.asarray(unpack_planes_ref(jnp.asarray(packed), keep))
     want = nbmod.truncate(nbmod.to_negabinary(q.astype(np.int64).ravel()),
                           32 - keep).reshape(8, 64)
@@ -154,7 +154,7 @@ def test_bitplane_unpack_kernel_matches_truncation(keep):
     n = 5000
     q = rng.integers(-(1 << 30), 1 << 30, n).astype(np.int32)
     q[0], q[1] = (1 << 30), -(1 << 30)
-    packed, _ = bitplane_pack(q)
+    packed, _, _ = bitplane_pack(q)
     packed = np.asarray(packed)
     words = packed.reshape(32, -1).copy()
     low = 32 - keep
@@ -174,7 +174,7 @@ def test_bitplane_unpack_padding_edges(n):
     """n not a multiple of the word/row geometry: full round trip exact."""
     rng = np.random.default_rng(n)
     q = rng.integers(-(1 << 24), 1 << 24, n).astype(np.int32)
-    packed, _ = bitplane_pack(q)
+    packed, _, _ = bitplane_pack(q)
     words = np.asarray(packed).reshape(32, -1)
     got = np.asarray(bitplane_unpack(words, n=n, low_zero=0))
     np.testing.assert_array_equal(got, q)
@@ -184,7 +184,7 @@ def test_bitplane_pack_agrees_with_cpu_container_bits():
     """Plane k bit content matches the CPU pipeline's XOR-encoded plane k."""
     rng = np.random.default_rng(3)
     q = rng.integers(-(1 << 15), 1 << 15, size=(8, 32)).astype(np.int32)
-    packed, _ = bitplane_pack(q)
+    packed, _, _ = bitplane_pack(q)
     nb = nbmod.to_negabinary(q.astype(np.int64).ravel())
     planes = bpmod.split_planes(nb, 32)
     enc = bpmod.xor_encode(planes)

@@ -119,11 +119,12 @@ def test_interp_kernels_compile_alone(one_chip):
 def test_bitplane_pack_compiles(one_chip, batch):
     from repro.kernels.bitplane_pack import bitplane_pack, bitplane_pack_batch
 
+    # the packed planes and the truncation-loss extrema: one program
     if batch is None:
-        _compile(lambda q: bitplane_pack(q, interpret=False)[0],
+        _compile(lambda q: bitplane_pack(q, interpret=False)[::2],
                  _spec(one_chip, (N_STREAM,), jnp.int32))
     else:
-        _compile(lambda q: bitplane_pack_batch(q, interpret=False)[0],
+        _compile(lambda q: bitplane_pack_batch(q, interpret=False)[::2],
                  _spec(one_chip, (batch, N_STREAM), jnp.int32))
 
 
