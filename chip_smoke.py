@@ -28,9 +28,9 @@ fails.  The last stdout line of a passing run is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
 ``--chips 4`` runs only the sharded chunk grid instead: compress and a
-full read over ``codec_mesh(4)``, compared in the same process with a
-one-device run (equal bytes, equal bits), and launches recorded on all
-four devices.
+full read with ``ExecPolicy(backend="jax", shard=4)``, compared in the
+same process with a one-device run (equal bytes, equal bits), and
+launches recorded on all four devices.
 
   python chip_smoke.py              # one chip
   python chip_smoke.py --chips 4    # four chips (sharded path)
@@ -250,11 +250,9 @@ def _fanned_out(logical: dict, dev: dict, kernel: str) -> bool:
 def run_four_chips(x: np.ndarray) -> None:
     from repro.api import Codec, ExecPolicy, Fidelity
     from repro.kernels import dispatch
-    from repro.parallel import codec_mesh
 
-    mesh = codec_mesh.codec_mesh(4)
     one, four = ExecPolicy(backend="jax"), ExecPolicy(backend="jax",
-                                                      shard=mesh)
+                                                      shard=4)
     codec = Codec(eb=EB_REL, relative=True, chunk_elems=CHUNK_ELEMS,
                   version=3)
     arc1, t1 = timed(lambda: codec.compress(x, one))

@@ -25,8 +25,9 @@ CPU), emitting archives byte-identical — and reconstructions bit-identical
 — to the numpy reference.  Chunked (v2) archives are scheduled in
 equal-shape groups and, where the backend ships batched primitives, each
 group runs through ``jax.vmap``-ed kernel launches (``batch_chunks=``
-opts out); ``shard="auto"``/a 1-D mesh additionally splits each group
-across local devices via shard_map (``parallel.codec_mesh``).  Bytes and
+opts out); ``shard="auto"``/a device count/a 1-D mesh additionally
+splits each group across local devices via shard_map
+(``parallel.codec_mesh``).  Bytes and
 bits never depend on the execution mode — see docs/format.md and
 docs/architecture.md.
 """

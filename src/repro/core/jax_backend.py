@@ -54,8 +54,8 @@ has a ``*_sharded`` twin (same stack, plus a 1-D device mesh): the stack
 axis is split across the mesh via ``parallel.codec_mesh`` and every device
 runs the vmapped kernel on its local chunks — data-parallel, collective-
 free, and still bit-identical (``compress``/``retrieve``/``refine``/
-``decompress`` expose this as ``shard="auto"`` / an explicit mesh; see
-``docs/architecture.md``).
+``decompress`` expose this as ``shard="auto"`` / a device count / an
+explicit mesh; see ``docs/architecture.md``).
 """
 from __future__ import annotations
 
@@ -147,17 +147,40 @@ def decorrelate_batch(xs: np.ndarray, eb: float, interp: str,
             xm, lead = _to_lanes(xv, ax)
             hm, _ = _to_lanes(hv, ax)
         with trace.span("sweep.kernel", stage="kernel_io"):
-            q, pred = interp_quant_batch(xm, hm, s=ph.stride, eb=eb,
+            ins = (xm, hm) if mesh is None else _place((xm, hm), mesh)
+            q, pred = interp_quant_batch(*ins, s=ph.stride, eb=eb,
                                          interp=interp, interpret=interpret,
                                          mesh=mesh)
             q, pred = np.asarray(q), np.asarray(pred)
-            trace.count("h2d_bytes", xm.nbytes + hm.nbytes)
+            trace.count("h2d_bytes", sum(a.nbytes for a in ins))
             trace.count("d2h_bytes", q.nbytes + pred.nbytes)
+            if mesh is not None:
+                q, pred = _unpad((q, pred), xm.shape[0])
         with trace.span("sweep.layout", stage="sweep_layout"):
             return _from_lanes(q, lead, ax), _from_lanes(pred, lead, ax)
 
     with _x64(arith.work_dtype(xs.dtype)):
         return interpolation.decorrelate_batch(xs, eb, interp, phase_fn)
+
+
+def _place(arrays, mesh) -> list:
+    """The sharded path's kernel inputs: host stacks padded to a mesh
+    multiple and put on the mesh (``codec_mesh.place``), with the real
+    and pad elements that go to the chips counted."""
+    from ..parallel import codec_mesh
+
+    with trace.span("shard.layout", stage="shard"):
+        placed, pad = codec_mesh.place(arrays, mesh)
+    a = arrays[0]
+    trace.count("shard_rows", a.size)
+    trace.count("shard_pad_rows", pad * (a.size // a.shape[0]))
+    return placed
+
+
+def _unpad(outs, b: int) -> list:
+    """The sharded path's host outputs without their pad rows."""
+    with trace.span("shard.layout", stage="shard"):
+        return [o[:b] for o in outs]
 
 
 def decorrelate_sharded(xs: np.ndarray, eb: float, interp: str, mesh,
@@ -219,14 +242,18 @@ def encode_level_batch(q2: np.ndarray, eb: float,
     with trace.span("pack.kernel", stage="kernel_io"):
         q2i = np.ascontiguousarray(q2, np.int32)
         if mesh is not None:
+            (q2d,) = _place((q2i,), mesh)
             packed, n_valid, extrema = bitplane_pack_sharded(
-                q2i, mesh=mesh, interpret=interpret)
+                q2d, mesh=mesh, interpret=interpret)
         else:
+            q2d = q2i
             packed, n_valid, extrema = bitplane_pack_batch(
                 q2i, interpret=interpret)
         packed, extrema = np.asarray(packed), np.asarray(extrema)
-        trace.count("h2d_bytes", q2i.nbytes)
+        trace.count("h2d_bytes", q2d.nbytes)
         trace.count("d2h_bytes", packed.nbytes + extrema.nbytes)
+        if mesh is not None:
+            packed, extrema = _unpad((packed, extrema), B)
     enc = [bitplane.blobs_from_packed(packed[b], int(n_valid))
            for b in range(B)]
     deltas = _loss_tables(extrema, [nbits for _, nbits in enc], eb)

@@ -191,7 +191,8 @@ def resolve_exec_mesh(shard, backend_shards: bool, *, chunked: bool,
     """``shard=`` policy shared by both codec directions -> mesh or None.
 
     Delegates mesh resolution to ``parallel.codec_mesh.resolve_shard``
-    ("auto" -> all local devices when >1, Mesh -> validated 1-D), then
+    ("auto" -> all local devices when >1, n -> the first n devices, Mesh
+    -> validated 1-D), then
     applies the pipeline rules: sharding needs a chunk grid and the
     stacked scheduler, so an *explicit* mesh combined with an unchunked
     archive or ``batch_chunks=False`` is a contradiction and raises, while
@@ -236,11 +237,13 @@ class ExecPolicy:
         Equal-shape chunk batching for v2 archives: None/True = batch when
         the backend ships batched primitives, False = per-chunk loop.
     ``shard``
-        None | "auto" | an explicit 1-D ``jax.sharding.Mesh`` — the chunk
-        grid is split across the mesh and each device runs its local
-        shard.  "auto" degrades quietly (no mesh on a single device, no
-        mesh for v1 archives); an explicit mesh is a hard request and
-        raises where it cannot apply.
+        None | "auto" | a device count ``n`` | an explicit 1-D
+        ``jax.sharding.Mesh`` — the chunk grid is split across the mesh
+        (``n``: ``codec_mesh(n)``, the first ``n`` local devices) and each
+        device runs its local shard.  "auto" degrades quietly (no mesh on
+        a single device, no mesh for v1 archives); a count or an explicit
+        mesh is a hard request and raises where it cannot apply (a count
+        outside ``1..jax.device_count()`` at construction).
 
     Validation happens ONCE here: unknown backends, malformed ``shard``
     values, and the explicit-mesh + ``batch_chunks=False`` contradiction

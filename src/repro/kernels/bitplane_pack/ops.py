@@ -80,7 +80,9 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
     With ``mesh``, the batch axis is zero-padded to a mesh multiple
     (all-zero pad streams pack to all-zero words, sliced back off) and
     split across the 1-D codec mesh; each device packs its local rows
-    with the same vmapped kernel.  One function holds both layouts so the
+    with the same vmapped kernel.  A stack already placed by
+    ``codec_mesh.place`` is not padded again, and its outputs keep the
+    pad rows.  One function holds both layouts so the
     byte-critical stream padding cannot drift between them.
     """
     if interpret is None:
@@ -99,14 +101,15 @@ def bitplane_pack_batch(q, *, interpret: bool | None = None, mesh=None):
     if pr:
         q = jnp.pad(q, ((0, 0), (0, pr), (0, 0)))
 
-    kernel = functools.partial(_pack_level, interpret=interpret, xla=xla)
     if mesh is None:
         dispatch.record("bitplane_pack", interpret=interpret and not xla)
+        kernel = functools.partial(_pack_level, interpret=interpret, xla=xla)
         packed, extrema = jax.vmap(kernel)(q)
     else:
         dispatch.record("bitplane_pack", interpret=interpret and not xla,
                         devices=codec_mesh.shard_count(mesh))
-        packed, extrema = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(q)
+        packed, extrema = codec_mesh.shard_vmap(
+            _pack_level, mesh, n_out=2, interpret=interpret, xla=xla)(q)
     return packed[:B], n, extrema[:B]
 
 
@@ -195,19 +198,17 @@ def bitplane_unpack_batch(plane_words, n: int, *, low_zero=0,
         lz = jnp.pad(lz, ((0, padb), (0, 0), (0, 0)))
 
     if xla:
-        def kernel(a, z):
-            return bitplane_unpack_xla(a, z)
+        fn, params = bitplane_unpack_xla, {}
     else:
-        def kernel(a, z):
-            return bitplane_unpack_pallas(a, z, interpret=interpret)
+        fn, params = bitplane_unpack_pallas, dict(interpret=interpret)
 
     if mesh is None:
         dispatch.record("bitplane_unpack", interpret=interpret and not xla)
-        q, nb = jax.vmap(kernel)(pw, lz)
+        q, nb = jax.vmap(functools.partial(fn, **params))(pw, lz)
     else:
         dispatch.record("bitplane_unpack", interpret=interpret and not xla,
                         devices=codec_mesh.shard_count(mesh))
-        q, nb = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(pw, lz)
+        q, nb = codec_mesh.shard_vmap(fn, mesh, n_out=2, **params)(pw, lz)
     q = q.reshape(B + padb, -1)[:B, :n]
     nb = nb.reshape(B + padb, -1)[:B, :n]
     if with_nb:

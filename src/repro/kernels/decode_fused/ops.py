@@ -52,6 +52,21 @@ def decode_fused(plane_words, nb_old, n: int, *, eb: float, low_zero=0,
     return out[0][0], out[1][0]
 
 
+def _fused(planes, nb_old, low_zero, scale, *, interpret: bool,
+           xla: bool):
+    """One chunk's fused decode in the chosen kernel mode."""
+    if xla:
+        return decode_fused_xla(planes, nb_old, low_zero, scale)
+    return decode_fused_pallas(planes, nb_old, low_zero, scale,
+                               interpret=interpret)
+
+
+def _fused_f32(planes, low_zero, scale, *, interpret: bool, xla: bool):
+    """:func:`_fused` of a float32 field, which keeps no previous words."""
+    return _fused(planes, None, low_zero, scale, interpret=interpret,
+                  xla=xla)
+
+
 def decode_fused_batch(plane_words, nb_old, n: int, *, eb, low_zero=0,
                        interpret: bool | None = None, mesh=None,
                        dtype=np.float64):
@@ -97,24 +112,16 @@ def decode_fused_batch(plane_words, nb_old, n: int, *, eb, low_zero=0,
             args.append(old.reshape(B + padb, R, _UNPACK_W * GROUP))
         args += [lz, sc]
 
-        if xla:
-            fn = decode_fused_xla
-        else:
-            fn = functools.partial(decode_fused_pallas, interpret=interpret)
-        if f32:
-            def kernel(a, z, e):
-                return fn(a, None, z, e)
-        else:
-            kernel = fn
-
+        fn = _fused_f32 if f32 else _fused
+        params = dict(interpret=interpret, xla=xla)
         if mesh is None:
             dispatch.record("decode_fused", interpret=interpret and not xla)
-            nb_new, out = jax.vmap(kernel)(*args)
+            nb_new, out = jax.vmap(functools.partial(fn, **params))(*args)
         else:
             dispatch.record("decode_fused", interpret=interpret and not xla,
                             devices=codec_mesh.shard_count(mesh))
-            nb_new, out = codec_mesh.shard_vmap(kernel, mesh,
-                                                n_out=2)(*args)
+            nb_new, out = codec_mesh.shard_vmap(fn, mesh, n_out=2,
+                                                **params)(*args)
         nb_new = nb_new.reshape(B + padb, -1)[:B, :n]
         out = out.reshape(B + padb, -1)[:B, :n]
         return nb_new, out

@@ -1,6 +1,8 @@
 """Public wrappers for the fused interpolate+quantize kernel."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -41,7 +43,9 @@ def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
     mesh multiple (``codec_mesh.pad_to_shards``) and ``shard_map`` places
     consecutive rows on consecutive devices, each running the same vmapped
     kernel — one collective-free launch per device, one *logical* dispatch
-    total (recorded with ``devices=mesh size``), pad rows sliced off.
+    total (recorded with ``devices=mesh size``), pad rows sliced off.  A
+    stack already placed by ``codec_mesh.place`` (a mesh multiple) is
+    neither padded nor sliced: its outputs keep the pad rows.
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -59,20 +63,18 @@ def interp_quant_batch(x, xhat, *, s: int, eb: float, interp: str = "cubic",
             xhat = jnp.pad(xhat, ((0, padb), (0, 0), (0, 0)))
 
     if xla:
-        def kernel(a, b):
-            return interp_quant_xla(a, b, s=s, c=c, interp=interp)
+        fn, params = interp_quant_xla, dict(s=s, c=c, interp=interp)
     else:
-        def kernel(a, b):
-            return interp_quant_pallas(a, b, s=s, c=c, interp=interp,
-                                       interpret=interpret)
+        fn, params = interp_quant_pallas, dict(s=s, c=c, interp=interp,
+                                               interpret=interpret)
 
     if mesh is None:
         dispatch.record("interp_quant", interpret=interpret and not xla)
-        q, pred = jax.vmap(kernel)(x, xhat)
+        q, pred = jax.vmap(functools.partial(fn, **params))(x, xhat)
     else:
         dispatch.record("interp_quant", interpret=interpret and not xla,
                         devices=codec_mesh.shard_count(mesh))
-        q, pred = codec_mesh.shard_vmap(kernel, mesh, n_out=2)(x, xhat)
+        q, pred = codec_mesh.shard_vmap(fn, mesh, n_out=2, **params)(x, xhat)
     return q[:B], pred[:B]
 
 

@@ -1,6 +1,8 @@
 """Public wrappers for the fused interpolate+add-residual kernel."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -56,20 +58,18 @@ def interp_recon_batch(xhat, res, *, s: int, interp: str = "cubic",
             res = jnp.pad(res, ((0, padb), (0, 0), (0, 0)))
 
     if xla:
-        def kernel(a, b):
-            return interp_recon_xla(a, b, s=s, interp=interp)
+        fn, params = interp_recon_xla, dict(s=s, interp=interp)
     else:
-        def kernel(a, b):
-            return interp_recon_pallas(a, b, s=s, interp=interp,
-                                       interpret=interpret)
+        fn, params = interp_recon_pallas, dict(s=s, interp=interp,
+                                               interpret=interpret)
 
     if mesh is None:
         dispatch.record("interp_recon", interpret=interpret and not xla)
-        out = jax.vmap(kernel)(xhat, res)
+        out = jax.vmap(functools.partial(fn, **params))(xhat, res)
     else:
         dispatch.record("interp_recon", interpret=interpret and not xla,
                         devices=codec_mesh.shard_count(mesh))
-        out = codec_mesh.shard_vmap(kernel, mesh)(xhat, res)
+        out = codec_mesh.shard_vmap(fn, mesh, **params)(xhat, res)
     return out[:B]
 
 

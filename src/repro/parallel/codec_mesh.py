@@ -11,16 +11,18 @@ module maps it onto devices:
     sharded kernel entry points shard over;
   * :func:`resolve_shard` turns the user-facing ``shard=`` argument of
     ``compress`` / ``retrieve`` / ``refine`` / ``decompress``
-    (``None`` | ``"auto"`` | an explicit 1-D ``Mesh``) into a mesh or
-    ``None``;
+    (``None`` | ``"auto"`` | a device count | an explicit 1-D ``Mesh``)
+    into a mesh or ``None``;
   * :func:`shard_vmap` wraps a per-chunk kernel function in
-    ``vmap``-inside-``shard_map``: every device runs the same vmapped
-    kernel on its local slice of the chunk stack — one collective-free
-    launch per device per call;
+    ``vmap``-inside-``shard_map`` under one cached ``jit``: every device
+    runs the same vmapped kernel on its local slice of the chunk stack —
+    one collective-free launch per device per call, compiled once per
+    kernel parameters, mesh and shape;
   * :func:`pad_to_shards` rounds a ragged group's stack up to a multiple
     of the mesh size so ``shard_map`` can split it evenly (pad problems
     are all-zero and their outputs are sliced off; the codec never sees
-    them).
+    them), and :func:`place` does that padding on the host while it puts
+    each device's rows straight onto that device.
 
 Mesh construction and ``shard_map`` itself go through the version-tolerant
 ``parallel.compat`` shims.  The sharded path is an execution detail by
@@ -30,10 +32,12 @@ to the single-device path (``tests/test_sharded_codec.py`` pins this), so
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from . import compat
 
@@ -71,21 +75,27 @@ def resolve_shard(shard) -> Optional[Mesh]:
     ``None``/``False`` -> unsharded.  ``"auto"`` -> a mesh over every
     local device when there is more than one, else None — single-device
     "auto" stays on the plain batched path rather than paying shard_map
-    overhead for a 1-way split.  An explicit :class:`Mesh` is validated
-    (1-D) and used as-is, including the 1-device case (useful for parity
-    tests).  Whether the *backend* can shard is the pipeline's call
-    (``CodecBackend.shards_encode`` / ``shards_decode``): backends without
-    sharded primitives fall back to their scalar/batched path.
+    overhead for a 1-way split.  A positive ``int`` ``n`` ->
+    ``codec_mesh(n)``, a hard request like an explicit mesh: it raises
+    unless ``1 <= n <= jax.device_count()`` (a deployment states its chip
+    count, so more local devices than that are left alone).  An explicit
+    :class:`Mesh` is validated (1-D) and used as-is, including the
+    1-device case (useful for parity tests).  Whether the *backend* can
+    shard is the pipeline's call (``CodecBackend.shards_encode`` /
+    ``shards_decode``): backends without sharded primitives fall back to
+    their scalar/batched path.
     """
     if shard is None or shard is False:
         return None
     if isinstance(shard, Mesh):
         shard_count(shard)  # validates 1-D
         return shard
+    if isinstance(shard, int) and not isinstance(shard, bool):
+        return codec_mesh(shard)
     if shard == AUTO:
         return codec_mesh() if jax.device_count() > 1 else None
-    raise ValueError(f"shard must be None, 'auto', or a 1-D Mesh; "
-                     f"got {shard!r}")
+    raise ValueError(f"shard must be None, 'auto', a device count, or a "
+                     f"1-D Mesh; got {shard!r}")
 
 
 def pad_to_shards(b: int, mesh: Mesh) -> int:
@@ -93,20 +103,65 @@ def pad_to_shards(b: int, mesh: Mesh) -> int:
     return (-b) % shard_count(mesh)
 
 
-def shard_vmap(fn, mesh: Mesh, *, n_out: int = 1):
-    """``shard_map(vmap(fn))`` over axis 0 of every argument.
+def place(arrays: Sequence[np.ndarray], mesh: Mesh
+          ) -> Tuple[list, int]:
+    """Host stacks of equal length ``b`` -> (device arrays, pad rows).
+
+    Each array is padded with zero rows up to a mesh multiple and split
+    on axis 0 over the mesh, every device receiving only its own rows:
+    the host builds no padded copy and no device forwards rows to
+    another.  The result is the layout :func:`shard_vmap`'s programs take,
+    so they run without moving data first.
+    """
+    b = arrays[0].shape[0]
+    pad = pad_to_shards(b, mesh)
+    sharding = NamedSharding(mesh, PartitionSpec(CODEC_AXIS))
+
+    def put(a: np.ndarray):
+        def rows(index):
+            lo, hi, _ = index[0].indices(b + pad)
+            if hi <= b:
+                return a[lo:hi]
+            part = np.zeros((hi - lo,) + a.shape[1:], a.dtype)
+            if lo < b:
+                part[:b - lo] = a[lo:b]
+            return part
+        return jax.make_array_from_callback((b + pad,) + a.shape[1:],
+                                            sharding, rows)
+
+    return [put(a) for a in arrays], pad
+
+
+def shard_vmap(fn, mesh: Mesh, *, n_out: int = 1, **params):
+    """``jit(shard_map(vmap(partial(fn, **params))))`` over axis 0 of
+    every argument.
 
     ``fn`` is a per-chunk kernel function (the exact function the batched
-    entry points vmap); the returned callable takes stacked arrays whose
-    leading dimension is a multiple of the mesh size (see
-    :func:`pad_to_shards`) and runs ``vmap(fn)`` on each device's local
-    rows.  ``n_out`` is the number of outputs (each sharded the same way).
-    No collectives are emitted — the chunk axis is embarrassingly parallel
-    — so the per-device program is exactly the single-device batched
-    program on a smaller stack, which is why sharded results are
-    bit-identical.
+    entry points vmap) and ``params`` its keyword parameters, all
+    hashable; the returned callable takes stacked arrays whose leading
+    dimension is a multiple of the mesh size (see :func:`pad_to_shards`)
+    and runs ``vmap(fn)`` on each device's local rows.  ``n_out`` is the
+    number of outputs (each sharded the same way).  No collectives are
+    emitted — the chunk axis is embarrassingly parallel — so the
+    per-device program is exactly the single-device batched program on a
+    smaller stack, which is why sharded results are bit-identical.
+
+    The callable is cached on (``fn``, mesh, ``n_out``, ``params``): a
+    fresh ``shard_map`` per call would be traced and compiled on every
+    call, where this one compiles once per input shape.
     """
+    return _shard_vmap(fn, mesh, n_out, tuple(sorted(params.items())))
+
+
+#: sharded programs kept: each error bound brings sweep programs of its
+#: own (the bound is a compile-time constant of ``interp_quant``)
+_PROGRAMS = 1024
+
+
+@functools.lru_cache(maxsize=_PROGRAMS)
+def _shard_vmap(fn, mesh: Mesh, n_out: int, params: tuple):
     spec = PartitionSpec(CODEC_AXIS)
     out_specs = spec if n_out == 1 else tuple(spec for _ in range(n_out))
-    return compat.shard_map(jax.vmap(fn), mesh=mesh, in_specs=spec,
-                            out_specs=out_specs)
+    body = jax.vmap(functools.partial(fn, **dict(params)))
+    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=spec,
+                                    out_specs=out_specs))

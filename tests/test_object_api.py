@@ -180,6 +180,38 @@ def test_exec_policy_mesh_contradictions():
     assert metrics.linf(X, out) <= 1e-2
 
 
+@pytest.mark.parametrize("shard", [0, -1, "too_many", True])
+def test_exec_policy_bad_device_count_raises(shard):
+    """A device count is a hard request, checked at construction: a
+    count outside 1..jax.device_count(), or a bool, is refused."""
+    jax = pytest.importorskip("jax")
+    if shard == "too_many":
+        shard = jax.device_count() + 1
+    with pytest.raises(ValueError):
+        ExecPolicy(backend="jax", shard=shard)
+
+
+@pytest.mark.parametrize("shard", [None, "auto", 1, "mesh"])
+def test_exec_policy_device_count(shard):
+    """``shard=n`` is ``codec_mesh(n)``; None, "auto" and a Mesh resolve
+    as before."""
+    jax = pytest.importorskip("jax")
+    from repro.parallel import codec_mesh
+    if shard == "mesh":
+        shard = codec_mesh.codec_mesh(1)
+    mesh = ExecPolicy(backend="jax", shard=shard).bind(
+        chunked=True, encode=True).mesh
+    if shard is None or (shard == "auto" and jax.device_count() == 1):
+        assert mesh is None
+    elif shard == 1:
+        assert mesh == codec_mesh.codec_mesh(1)
+        assert codec_mesh.shard_count(mesh) == 1
+    elif shard == "auto":
+        assert codec_mesh.shard_count(mesh) == jax.device_count()
+    else:
+        assert mesh is shard
+
+
 # ------------------------------------------------- decompress signature fix
 
 def test_decompress_accepts_retrieve_kwargs():

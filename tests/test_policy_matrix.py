@@ -37,6 +37,7 @@ def _policies():
     import jax  # noqa: F401  (explicit mesh needs a device)
     from repro.parallel import codec_mesh
     pols.append(ExecPolicy(backend="jax", shard=codec_mesh.codec_mesh(1)))
+    pols.append(ExecPolicy(backend="jax", shard=1))   # a device count
     pols.append(ExecPolicy(backend="numpy",
                            shard=codec_mesh.codec_mesh(1)))  # falls back
     return pols

@@ -65,8 +65,16 @@ def test_resolve_shard_contract():
         assert codec_mesh.shard_count(auto) == N_DEV
     else:
         assert auto is None
+    # a device count is codec_mesh(n), checked like an explicit mesh
+    assert codec_mesh.resolve_shard(N_DEV) == mesh
+    assert codec_mesh.shard_count(codec_mesh.resolve_shard(1)) == 1
+    for bad in (0, -1, N_DEV + 1):
+        with pytest.raises(ValueError, match="devices"):
+            codec_mesh.resolve_shard(bad)
     with pytest.raises(ValueError, match="shard must be"):
         codec_mesh.resolve_shard("always")
+    with pytest.raises(ValueError, match="shard must be"):
+        codec_mesh.resolve_shard(True)
 
 
 def test_resolve_shard_rejects_2d_mesh():

@@ -70,6 +70,15 @@ def one_chip(topo):
     cc.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def four_chips(one_chip, topo):
+    """The codec mesh over the described host's four chips."""
+    from repro.parallel import codec_mesh, compat
+
+    return compat.make_mesh((4,), (codec_mesh.CODEC_AXIS,),
+                            devices=topo.devices[:4])
+
+
 def _compile(fn, *shapes):
     """jit + lower + compile for the described chip; returns HLO text."""
     text = jax.jit(fn).lower(*shapes).compile().as_text()
@@ -159,6 +168,35 @@ def test_decode_fused_compiles(one_chip, batch, n):
             w, None, n, eb=[EB] * batch, low_zero=[2] * batch,
             interpret=False, dtype=np.float32),
             _spec(one_chip, (batch, 32, nw), jnp.uint32))
+
+
+@pytest.mark.parametrize("R,C,s", PHASES)
+def test_interp_quant_compiles_sharded(four_chips, R, C, s):
+    """The sharded sweep over four chips: the six-chunk group padded to
+    eight stack slots, two on each chip, with no collective."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.kernels.interp_quant import interp_quant_batch
+    from repro.parallel.codec_mesh import CODEC_AXIS
+
+    rows = NamedSharding(four_chips, PartitionSpec(CODEC_AXIS))
+    x = _spec(rows, (8, R, C), jnp.float32)
+    text = _compile(lambda a, b: interp_quant_batch(
+        a, b, s=s, eb=EB, interpret=False, mesh=four_chips), x, x)
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_bitplane_pack_compiles_sharded(four_chips):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.kernels.bitplane_pack import bitplane_pack_batch
+    from repro.parallel.codec_mesh import CODEC_AXIS
+
+    rows = NamedSharding(four_chips, PartitionSpec(CODEC_AXIS))
+    text = _compile(lambda q: bitplane_pack_batch(
+        q, interpret=False, mesh=four_chips)[::2],
+        _spec(rows, (8, N_STREAM), jnp.int32))
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 # ---------------------------------------------- no fallback on the chip path

@@ -30,6 +30,9 @@ PARENT = {
     "pack.kernel": "encode",
     "pack.zlib": "encode",
 }
+#: the sharded path's placement and unpadding, inside the kernel I/O
+SHARD_PARENT = {("shard.layout", "sweep.kernel"),
+                ("shard.layout", "pack.kernel")}
 #: the host stage each span's time counts to (its ``stage`` attribute,
 #: which the benchmark's stage metrics read, ``bench/stages.py``); the
 #: request's and each phase's own time is in no stage
@@ -44,6 +47,7 @@ STAGE = {
     "pack.negabinary": "negabinary",
     "pack.kernel": "kernel_io",
     "pack.zlib": "zlib",
+    "shard.layout": "shard",
 }
 
 
@@ -209,9 +213,11 @@ def tree(records):
                    for r in records)
 
 
-@pytest.mark.parametrize("backend", ["jax", "numpy"])
-def test_chunked_compress_spans_and_bytes(backend):
-    x, policy = field(), ExecPolicy(backend=backend)
+@pytest.mark.parametrize("backend,shard", [("jax", None), ("numpy", None),
+                                           ("jax", 1)],
+                         ids=["jax", "numpy", "jax-shard1"])
+def test_chunked_compress_spans_and_bytes(backend, shard):
+    x, policy = field(), ExecPolicy(backend=backend, shard=shard)
     plain = CODEC.compress(x, policy).tobytes()
     with trace.recording():
         arc = CODEC.compress(x, policy)
@@ -225,6 +231,8 @@ def test_chunked_compress_spans_and_bytes(backend):
     if backend == "numpy":
         # the host sweep: no kernel I/O in its phases
         want -= {("sweep.kernel", "sweep.phase")}
+    if shard is not None:
+        want |= SHARD_PARENT
     assert set(tree(recs)) == want
     assert all(r.attrs.get("stage") == STAGE[r.name] for r in recs)
     assert root.counts == {"field_bytes": x.nbytes}
@@ -239,6 +247,11 @@ def test_chunked_compress_spans_and_bytes(backend):
                    and r.counts["launches"] == 1 for r in kio)
         assert total["launches"] == sum(
             r.counts.get("launches", 0) for r in kio)
+    if shard is None:
+        assert "shard_rows" not in total and "shard_pad_rows" not in total
+    else:
+        # a one-device mesh takes the batched group (2 chunks) unpadded
+        assert total["shard_rows"] > 0 and total["shard_pad_rows"] == 0
 
 
 def test_single_chunk_and_batched_paths_nest_alike():
