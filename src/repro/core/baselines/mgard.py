@@ -56,6 +56,7 @@ class PMGARD:
             q = np.clip(q, -(1 << 30), 1 << 30)  # baseline: no escape channel
             nb = negabinary.to_negabinary(q)
             blobs, nbits = bitplane.encode_level(nb)
+            blobs = bitplane.wait(blobs)
             delta = negabinary.truncation_loss_table(nb, nbits, eb_c)
             lvl_meta.append(dict(n=int(q.size), nbits=nbits,
                                  sizes=[len(b) for b in blobs],

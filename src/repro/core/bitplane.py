@@ -14,8 +14,10 @@ independently decodable (the "blocks" of Fig. 2).
 from __future__ import annotations
 
 import os
+import threading
 import zlib
-from typing import List, Optional, Tuple
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Iterable, List, NamedTuple, Optional, Tuple, Union
 
 import numpy as np
 
@@ -108,13 +110,6 @@ def xor_decode_plane(enc_k: np.ndarray, b_k1: Optional[np.ndarray],
     return b
 
 
-def compress_plane(bits: np.ndarray) -> bytes:
-    """Pack a 0/1 uint8 array and zlib it. All-zero planes compress to b''."""
-    if bits.size == 0 or not bits.any():
-        return b""
-    return zlib.compress(np.packbits(bits).tobytes(), zlib_level())
-
-
 def decompress_plane(blob: bytes, n: int) -> np.ndarray:
     if not blob:
         return np.zeros(n, np.uint8)
@@ -122,20 +117,94 @@ def decompress_plane(blob: bytes, n: int) -> np.ndarray:
     return np.unpackbits(raw, count=n)
 
 
-def encode_level(nb: np.ndarray) -> Tuple[List[bytes], int]:
-    """negabinary ints -> (blobs MSB-first, nbits). blobs[i] is plane nbits-1-i."""
+class Deflate(NamedTuple):
+    """A blob being deflated on the shared pool (:func:`wait` gives its
+    bytes): the job's future and the size of the stream it deflates."""
+    future: Future
+    n_in: int
+
+
+#: a level's blob as the encoders return it: ``b''`` for an all-zero
+#: plane or an empty escape set, else a :class:`Deflate` in flight
+Blob = Union[bytes, Deflate]
+
+_POOL: Optional[ThreadPoolExecutor] = None
+_POOL_LOCK = threading.Lock()
+
+
+def _pool() -> ThreadPoolExecutor:
+    """The encode path's deflate workers, one per CPU this process may
+    run on, made on first use and shared by every caller.  A job only
+    deflates and never waits on another, so callers that are themselves
+    pool threads (the checkpoint bundle's per-shard savers) cannot
+    deadlock it."""
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            _POOL = ThreadPoolExecutor(
+                max_workers=len(os.sched_getaffinity(0)),
+                thread_name_prefix="ipcomp-deflate")
+        return _POOL
+
+
+def _forget_pool() -> None:
+    # a forked child has none of its parent's workers
+    global _POOL, _POOL_LOCK
+    _POOL, _POOL_LOCK = None, threading.Lock()
+
+
+os.register_at_fork(after_in_child=_forget_pool)
+
+
+def _deflate(level: int, make, *args) -> bytes:
+    return zlib.compress(make(*args), level)
+
+
+def submit(level: int, n_in: int, make, *args) -> Deflate:
+    """Deflate ``make(*args)`` (``n_in`` bytes) at zlib ``level`` on the
+    shared pool.  ``make`` runs on the worker too, so the stream is built
+    off the calling thread; it must only read ``args``."""
+    return Deflate(_pool().submit(_deflate, level, make, *args), n_in)
+
+
+def wait(blobs: Iterable[Blob]) -> List[bytes]:
+    """``blobs`` as bytes, in order, waiting for each deflate in flight.
+    Counts the deflates' bytes in and out and the jobs (``zlib_jobs``)
+    on the calling thread's open span."""
+    out, n_in, jobs = [], 0, 0
+    for b in blobs:
+        if isinstance(b, Deflate):
+            n_in += b.n_in
+            jobs += 1
+            b = b.future.result()
+        out.append(b)
+    trace.count("zlib_in_bytes", n_in)
+    trace.count("zlib_out_bytes", sum(len(b) for b in out))
+    trace.count("zlib_jobs", jobs)
+    return out
+
+
+def encode_level(nb: np.ndarray) -> Tuple[List[Blob], int]:
+    """negabinary ints -> (blobs MSB-first, nbits). blobs[i] is plane
+    nbits-1-i: bit-packed and deflated on the shared pool, ``b''`` when
+    the plane is all zero (:func:`wait` gives the bytes)."""
     with trace.span("pack.kernel", stage="kernel_io"):
         nbits = int(nb.max()).bit_length() if nb.size else 0
         if nbits == 0:
             return [], 0
         enc = xor_encode(split_planes(nb, nbits))
     with trace.span("pack.zlib", stage="zlib"):
-        blobs = [compress_plane(enc[k]) for k in range(nbits - 1, -1, -1)]
-        _count_zlib(blobs, (nb.size + 7) // 8)
-    return blobs, nbits
+        level, nbytes = zlib_level(), (nb.size + 7) // 8
+        return [submit(level, nbytes, np.packbits, enc[k]) if enc[k].any()
+                else b"" for k in range(nbits - 1, -1, -1)], nbits
 
 
-def blobs_from_packed(packed: np.ndarray, n: int) -> Tuple[List[bytes], int]:
+def _plane_bytes(words: np.ndarray, nbytes: int) -> np.ndarray:
+    """One packed plane's first ``nbytes`` bytes, words big-endian."""
+    return words.astype(">u4").reshape(-1).view(np.uint8)[:nbytes]
+
+
+def blobs_from_packed(packed: np.ndarray, n: int) -> Tuple[List[Blob], int]:
     """Pre-packed XOR-coded plane words -> (blobs MSB-first, nbits).
 
     ``packed`` is the (32, R, W) uint32 output of the ``bitplane_pack``
@@ -145,10 +214,12 @@ def blobs_from_packed(packed: np.ndarray, n: int) -> Tuple[List[bytes], int]:
     the first ``n`` elements are real; the 1-D wrapper appends its pad at
     the END of the flat stream and pad words are all-zero (q=0 -> nb=0 ->
     enc=0), so truncating the big-endian byte stream to ceil(n/8) bytes
-    reproduces ``compress_plane``'s output byte-for-byte.  (The wrapper's
-    2-D path pads columns mid-stream instead — callers must flatten first,
-    as ``jax_backend.encode_level`` does.)  Both backends therefore write
-    one archive format, and a mixed read path cannot exist.
+    reproduces :func:`encode_level`'s stream byte-for-byte.  (The
+    wrapper's 2-D path pads columns mid-stream instead — callers must
+    flatten first, as ``jax_backend.encode_level`` does.)  Both backends
+    therefore write one archive format, and a mixed read path cannot
+    exist.  Each occupied plane is deflated on the shared pool, as in
+    :func:`encode_level`.
     """
     with trace.span("pack.zlib", stage="zlib"):
         occupied = [bool(packed[k].any()) for k in range(packed.shape[0])]
@@ -156,24 +227,10 @@ def blobs_from_packed(packed: np.ndarray, n: int) -> Tuple[List[bytes], int]:
                     default=0)
         if nbits == 0:
             return [], 0
-        nbytes = (n + 7) // 8
-        blobs = []
-        for k in range(nbits - 1, -1, -1):
-            if not occupied[k]:
-                # all-zero plane: same convention as compress_plane
-                blobs.append(b"")
-                continue
-            raw = packed[k].astype(">u4").tobytes()[:nbytes]
-            blobs.append(zlib.compress(raw, zlib_level()))
-        _count_zlib(blobs, nbytes)
-        return blobs, nbits
-
-
-def _count_zlib(blobs: List[bytes], nbytes: int) -> None:
-    """Count one level's zlib bytes on the open span (an all-zero plane
-    is stored as ``b''`` without zlib)."""
-    trace.count("zlib_in_bytes", nbytes * sum(1 for b in blobs if b))
-    trace.count("zlib_out_bytes", sum(len(b) for b in blobs))
+        level, nbytes = zlib_level(), (n + 7) // 8
+        return [submit(level, nbytes, _plane_bytes, packed[k], nbytes)
+                if occupied[k] else b""
+                for k in range(nbits - 1, -1, -1)], nbits
 
 
 def decode_level(blobs: List[Optional[bytes]], nbits: int, n: int) -> np.ndarray:

@@ -32,8 +32,7 @@ from __future__ import annotations
 from .pipeline.backends import CodecBackend, get as get_backend
 from .pipeline.decode import (_retrieve_chunked, decompress, open_archive,
                               read_archive, refine, retrieve, split_budget)
-from .pipeline.encode import (_compress_single, _pack_escapes, chunk_bounds,
-                              compress, encode_array)
+from .pipeline.encode import chunk_bounds, compress, encode_array
 from .pipeline.spec import ExecPolicy, Fidelity, IPCompDeprecationWarning
 from .pipeline.state import (ChunkedRetrievalState, RetrievalState,
                              _unpack_escapes, initial_state)

@@ -192,14 +192,15 @@ def decorrelate_sharded(xs: np.ndarray, eb: float, interp: str, mesh,
 
 
 def encode_level(q: np.ndarray, eb: float, interpret: bool | None = None,
-                 ) -> Tuple[List[bytes], int, np.ndarray]:
+                 ) -> Tuple[List[bitplane.Blob], int, np.ndarray]:
     """Kernel-backed twin of the numpy backend's ``encode_level`` slot:
     ``(blobs, nbits, delta_table)`` of one level stream.
 
     The Pallas kernel fuses negabinary conversion, XOR-predictive coding and
     bit-transposition, and the same device program reduces the stream to
-    its truncation-loss extrema; the host only truncates pad bytes, zlibs
-    each plane and finishes the table (:func:`_loss_tables`).  Byte-
+    its truncation-loss extrema; the host only finishes the table
+    (:func:`_loss_tables`) and hands each plane's pad truncation and
+    deflate to the shared pool (``bitplane.blobs_from_packed``).  Byte-
     identical blobs and tables to the numpy encoder.
     """
     if q.size == 0:
@@ -220,17 +221,17 @@ def encode_level(q: np.ndarray, eb: float, interpret: bool | None = None,
     return blobs, nbits, delta
 
 
-def encode_level_batch(q2: np.ndarray, eb: float,
-                       interpret: bool | None = None, mesh=None,
-                       ) -> List[Tuple[List[bytes], int, np.ndarray]]:
+def encode_level_batch(
+        q2: np.ndarray, eb: float, interpret: bool | None = None, mesh=None,
+) -> List[Tuple[List[bitplane.Blob], int, np.ndarray]]:
     """Batched twin of :func:`encode_level`: (B, n) stacked level streams.
 
-    One vmapped pack launch covers the whole stack; the host then truncates
-    and zlibs each chunk's planes independently (per-chunk ``nbits`` and
-    blobs) and finishes each chunk's table, so every returned ``(blobs,
-    nbits, delta_table)`` is byte-identical to an unbatched
-    :func:`encode_level` call on that row.  With ``mesh``, the stack is
-    split over the 1-D codec mesh first (one launch per device;
+    One vmapped pack launch covers the whole stack; the host then submits
+    each chunk's planes to the deflate pool independently (per-chunk
+    ``nbits`` and blobs) and finishes each chunk's table, so every
+    returned ``(blobs, nbits, delta_table)`` is byte-identical to an
+    unbatched :func:`encode_level` call on that row.  With ``mesh``, the
+    stack is split over the 1-D codec mesh first (one launch per device;
     :func:`encode_level_sharded` is the registry-facing alias).
     """
     B, n = q2.shape
@@ -261,9 +262,9 @@ def encode_level_batch(q2: np.ndarray, eb: float,
             for (blobs, nbits), delta in zip(enc, deltas)]
 
 
-def encode_level_sharded(q2: np.ndarray, eb: float, mesh,
-                         interpret: bool | None = None,
-                         ) -> List[Tuple[List[bytes], int, np.ndarray]]:
+def encode_level_sharded(
+        q2: np.ndarray, eb: float, mesh, interpret: bool | None = None,
+) -> List[Tuple[List[bitplane.Blob], int, np.ndarray]]:
     """Sharded per-level pack: :func:`encode_level_batch` over a mesh."""
     return encode_level_batch(q2, eb, interpret=interpret, mesh=mesh)
 

@@ -23,7 +23,9 @@ suites pin this down):
       negabinary words makes the table: numpy converts on the host and
       tables there; the kernel converts the raw bins on the device and
       reduces them to the table's maxima in the same program, so the
-      host never converts.
+      host never converts.  Each blob is a ``bitplane.Blob``: ``b''``
+      for an all-zero plane, else a deflate still running on the shared
+      host pool, which ``bitplane.wait`` turns into bytes.
   decode_level(blobs, nbits, n) -> uint32 truncated negabinary
       inverse of encode_level for a loaded MSB-first blob prefix
       (None = not loaded; b'' = loaded, all-zero encoded plane).
@@ -209,7 +211,7 @@ def get(choice) -> CodecBackend:
 # ---------------------------------------------------------- numpy reference
 
 def _numpy_encode_level(q: np.ndarray, eb: float,
-                        ) -> Tuple[List[bytes], int, np.ndarray]:
+                        ) -> Tuple[List[bitplane.Blob], int, np.ndarray]:
     with trace.span("pack.negabinary", stage="negabinary"):
         nb = negabinary.to_negabinary(q)
     blobs, nbits = bitplane.encode_level(nb)

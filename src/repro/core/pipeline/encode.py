@@ -40,8 +40,7 @@ single-device ones.
 """
 from __future__ import annotations
 
-import zlib
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -117,18 +116,20 @@ def _encode(x: np.ndarray, eb: float, interp: str, relative: bool,
             groups = shape_groups([b - a for a, b in bounds],
                                   max_group=group_cap(ctx.mesh))
     if version == 1:
-        return _compress_single(x, eb, interp, ctx.bk)
-    bufs: List[Optional[bytes]] = [None] * len(bounds)
+        return _write([_compress_single(x, eb, interp, ctx.bk)],
+                      eb, interp)[0]
+    parts: List[Optional[_Staged]] = [None] * len(bounds)
     for idxs in groups:
         if ctx.batch_encode and len(idxs) > 1:
             with trace.span("encode.prepare", stage="container"):
                 xs = np.stack([x[bounds[i][0]: bounds[i][1]] for i in idxs])
-            for i, buf in zip(idxs, _compress_batch(xs, eb, interp, ctx)):
-                bufs[i] = buf
+            for i, part in zip(idxs, _compress_batch(xs, eb, interp, ctx)):
+                parts[i] = part
         else:
             for i in idxs:
                 a, b = bounds[i]
-                bufs[i] = _compress_single(x[a:b], eb, interp, ctx.bk)
+                parts[i] = _compress_single(x[a:b], eb, interp, ctx.bk)
+    bufs = _write(parts, eb, interp)
     writer = (container.write_v3_archive if version == 3
               else container.write_chunked_archive)
     with trace.span("encode.container", stage="container"):
@@ -215,11 +216,38 @@ def shape_groups(row_counts: Sequence[int],
             for a in range(0, len(g), max_group)]
 
 
+class _Staged(NamedTuple):
+    """One chunk's v1 archive while its blobs deflate on the pool: what
+    ``container.write_archive`` takes, with ``x`` for its ``vmax``."""
+    x: np.ndarray
+    L: int
+    anchors: np.ndarray
+    level_blobs: List[List[bitplane.Blob]]
+    level_meta: List[dict]
+    esc_blobs: List[bitplane.Blob]
+
+
+def _write(parts: Sequence[_Staged], eb: float, interp: str,
+           ) -> List[bytes]:
+    """Wait for every deflate of ``parts``, then write their archives in
+    order.  Callers stage a whole compress before this one join, so the
+    deflates of each level overlap the sweep and pack of the levels and
+    chunks after it."""
+    with trace.span("pack.zlib", stage="zlib"):
+        blobs = [([bitplane.wait(pl) for pl in p.level_blobs],
+                  bitplane.wait(p.esc_blobs)) for p in parts]
+    with trace.span("encode.container", stage="container"):
+        return [container.write_archive(p.x.shape, p.x.dtype, eb, interp,
+                                        p.L, p.anchors, lb, p.level_meta,
+                                        esc, vmax=_vmax(p.x))
+                for p, (lb, esc) in zip(parts, blobs)]
+
+
 def _compress_single(x: np.ndarray, eb: float, interp: str,
-                     bk: backends.CodecBackend) -> bytes:
-    """One (chunk-sized) array -> one v1 archive, via the chosen backend."""
-    shape, dtype = x.shape, x.dtype
-    L = interpolation.num_levels(shape)
+                     bk: backends.CodecBackend) -> _Staged:
+    """One (chunk-sized) array -> its v1 archive staged for :func:`_write`,
+    via the chosen backend."""
+    L = interpolation.num_levels(x.shape)
     _, qs, escs, anchors = bk.decorrelate(x, eb, interp)
 
     level_blobs, level_meta, esc_blobs = [], [], []
@@ -230,15 +258,12 @@ def _compress_single(x: np.ndarray, eb: float, interp: str,
         level_meta.append(dict(level=L - li, n=int(q.size), nbits=nbits,
                                delta_table=delta.tolist()))
         esc_blobs.append(_pack_escapes(escs[li]))
-    with trace.span("encode.container", stage="container"):
-        return container.write_archive(shape, dtype, eb, interp, L, anchors,
-                                       level_blobs, level_meta, esc_blobs,
-                                       vmax=_vmax(x))
+    return _Staged(x, L, anchors, level_blobs, level_meta, esc_blobs)
 
 
 def _compress_batch(xs: np.ndarray, eb: float, interp: str,
-                    ctx: spec.ExecContext) -> List[bytes]:
-    """B equal-shape chunks (stacked on axis 0) -> B v1 archives.
+                    ctx: spec.ExecContext) -> List[_Staged]:
+    """B equal-shape chunks (stacked on axis 0) -> B staged v1 archives.
 
     Exactly ``_compress_single`` per chunk, but the sweep and the per-level
     pack each run ONCE for the whole stack through the backend's batched
@@ -250,16 +275,15 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
     """
     bk, mesh = ctx.bk, ctx.mesh
     B = xs.shape[0]
-    shape, dtype = xs.shape[1:], xs.dtype
-    L = interpolation.num_levels(shape)
+    L = interpolation.num_levels(xs.shape[1:])
     if mesh is not None:
         results = bk.decorrelate_sharded(xs, eb, interp, mesh)
     else:
         results = bk.decorrelate_batch(xs, eb, interp)
 
-    blobs_pc: List[List[List[bytes]]] = [[] for _ in range(B)]
+    blobs_pc: List[List[List[bitplane.Blob]]] = [[] for _ in range(B)]
     meta_pc: List[List[dict]] = [[] for _ in range(B)]
-    escb_pc: List[List[bytes]] = [[] for _ in range(B)]
+    escb_pc: List[List[bitplane.Blob]] = [[] for _ in range(B)]
     for li in range(L):
         with trace.span("pack.negabinary", stage="negabinary"):
             # stored bins lie within +-arith.QMAX: stack them as the
@@ -276,12 +300,8 @@ def _compress_batch(xs: np.ndarray, eb: float, interp: str,
             meta_pc[b].append(dict(level=L - li, n=int(q2.shape[1]),
                                    nbits=nbits, delta_table=delta.tolist()))
             escb_pc[b].append(_pack_escapes(results[b][2][li]))
-    with trace.span("encode.container", stage="container"):
-        return [container.write_archive(shape, dtype, eb, interp, L,
-                                        results[b][3], blobs_pc[b],
-                                        meta_pc[b], escb_pc[b],
-                                        vmax=_vmax(xs[b]))
-                for b in range(B)]
+    return [_Staged(xs[b], L, results[b][3], blobs_pc[b], meta_pc[b],
+                    escb_pc[b]) for b in range(B)]
 
 
 def _vmax(x: np.ndarray) -> Optional[float]:
@@ -293,17 +313,21 @@ def _vmax(x: np.ndarray) -> Optional[float]:
     return float(a.max()) if a.size else 0.0
 
 
-def _pack_escapes(phase_escs) -> bytes:
-    """Escape records (level-global flat idx, exact residuals) -> one blob."""
+def _pack_escapes(phase_escs) -> bitplane.Blob:
+    """Escape records (level-global flat idx, exact residuals) -> one blob,
+    deflated on the shared pool (``b''`` when there are none)."""
     with trace.span("pack.zlib", stage="zlib"):
         idx_parts = [i for i, v in phase_escs if i.size]
         val_parts = [v for i, v in phase_escs if i.size]
         if not idx_parts:
             return b""
-        idx = np.concatenate(idx_parts).astype(np.int64)
-        val = np.concatenate(val_parts).astype(np.float64)
-        raw = np.int64(idx.size).tobytes() + idx.tobytes() + val.tobytes()
-        blob = zlib.compress(raw, bitplane.zlib_level())
-        trace.count("zlib_in_bytes", len(raw))
-        trace.count("zlib_out_bytes", len(blob))
-        return blob
+        n = sum(i.size for i in idx_parts)
+        return bitplane.submit(bitplane.zlib_level(), 8 + 16 * n,
+                               _escape_bytes, idx_parts, val_parts)
+
+
+def _escape_bytes(idx_parts, val_parts) -> bytes:
+    """The escape blob's stream: count, then indices, then values."""
+    idx = np.concatenate(idx_parts).astype(np.int64)
+    val = np.concatenate(val_parts).astype(np.float64)
+    return np.int64(idx.size).tobytes() + idx.tobytes() + val.tobytes()

@@ -13,6 +13,7 @@ import pytest
 from _fields import smooth_field
 from repro.core import (CUBIC, ChunkedRetrievalState, compress, decompress,
                         metrics, open_archive, refine, retrieve)
+from repro.core import bitplane
 from repro.core.pipeline import backends, shape_groups
 from repro.kernels import dispatch
 
@@ -50,6 +51,7 @@ def test_decode_level_batch_mixed_prefixes_one_dispatch():
     from repro.core import jax_backend
     q = np.arange(-50, 50, dtype=np.int64)
     blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
+    blobs = bitplane.wait(blobs)
     full = list(blobs)
     shorter = [blobs[i] if i < nbits - 1 else None for i in range(nbits)]
     shortest = [blobs[i] if i < 2 else None for i in range(nbits)]

@@ -48,6 +48,7 @@ def test_negabinary_truncation_uncertainty(vals, d):
 def test_bitplane_roundtrip(vals):
     x = np.array(vals, np.uint32)
     blobs, nbits = bp.encode_level(x)
+    blobs = bp.wait(blobs)
     got = bp.decode_level(list(blobs), nbits, x.size)
     assert np.array_equal(got, x)
 
@@ -58,6 +59,7 @@ def test_bitplane_prefix_decode_is_truncation(vals, keep_from_msb):
     """Loading a plane prefix must equal negabinary truncation exactly."""
     x = np.array(vals, np.uint32)
     blobs, nbits = bp.encode_level(x)
+    blobs = bp.wait(blobs)
     k = min(keep_from_msb, nbits)
     part = list(blobs[:k]) + [None] * (nbits - k)
     got = bp.decode_level(part, nbits, x.size)

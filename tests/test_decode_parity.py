@@ -188,6 +188,7 @@ def _dec_parity(q, wants=None):
     q = np.asarray(q, np.int64)
     nb = nbmod.to_negabinary(q)
     blobs, nbits = bp.encode_level(nb)
+    blobs = bp.wait(blobs)
     if wants is None:
         wants = sorted({0, 1, nbits // 2, max(nbits - 1, 0), nbits})
     for want in wants:

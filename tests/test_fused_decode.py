@@ -18,7 +18,7 @@ import pytest
 from _fields import smooth_field
 from repro.core import (CUBIC, compress, decompress, metrics, open_archive,
                         refine, retrieve)
-from repro.core import jax_backend
+from repro.core import bitplane, jax_backend
 from repro.core.pipeline import backends
 from repro.kernels import dispatch
 from repro.parallel import codec_mesh
@@ -62,6 +62,7 @@ def test_decode_level_fused_matches_unfused(nprev, want):
     rng = np.random.default_rng(nprev * 16 + want)
     q = rng.integers(-900, 900, size=1023).astype(np.int64)
     blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
+    blobs = bitplane.wait(blobs)
     eb = 3.7e-4
     prev = [blobs[i] if i < min(nprev, nbits) else None for i in range(nbits)]
     cur = [blobs[i] if i < min(want, nbits) else None for i in range(nbits)]
@@ -87,6 +88,7 @@ def test_decode_level_fused_f32_matches_host(want):
     q = rng.integers(-(1 << 29), 1 << 29, size=1500).astype(np.int64)
     q[::3] //= 1 << 8
     blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
+    blobs = bitplane.wait(blobs)
     cur = [blobs[i] if i < min(want, nbits) else None for i in range(nbits)]
     nb_ref = jax_backend.decode_level(cur, nbits, q.size)
     eb = 2.5e-6
@@ -107,6 +109,7 @@ def test_decode_level_fused_batch_mixed_prefixes_and_ebs():
     rng = np.random.default_rng(9)
     q = rng.integers(-500, 500, size=640).astype(np.int64)
     blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
+    blobs = bitplane.wait(blobs)
     wants = [nbits, max(1, nbits - 2), 1, 0]
     ebs = [1e-3, 2e-4, 5e-5, 1e-3]
     blob_lists = [[blobs[i] if i < w else None for i in range(nbits)]
@@ -136,6 +139,7 @@ def test_inflate_level_prefetch_seam():
     ``inflate_level`` result unchanged — the two-slot prefetch seam."""
     q = np.arange(-200, 200, dtype=np.int64)
     blobs, nbits, _ = jax_backend.encode_level(q, 1.0)
+    blobs = bitplane.wait(blobs)
     nb_old = np.zeros(q.size, np.uint32)
     direct = jax_backend.decode_level_fused(blobs, nbits, q.size, nb_old,
                                             1e-4)

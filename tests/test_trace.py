@@ -254,6 +254,40 @@ def test_chunked_compress_spans_and_bytes(backend, shard):
         assert total["shard_rows"] > 0 and total["shard_pad_rows"] == 0
 
 
+@pytest.mark.parametrize("backend", ["jax", "numpy"])
+def test_deflates_count_on_the_join_and_record_no_other_request(backend):
+    """The pool's workers record nothing: every span of one compress is
+    in its ``encode`` request.  The join counts the deflates' bytes as
+    the archive holds them, and one ``zlib_jobs`` per non-empty blob."""
+    import zlib
+
+    from repro.core import container
+
+    x = field()
+    with trace.recording():
+        arc = CODEC.compress(x, ExecPolicy(backend=backend)).tobytes()
+    recs = trace.records()
+    (root,) = [r for r in recs if r.parent is None]
+    assert root.name == "encode" and all(r.request == root.id for r in recs)
+    total = Counter()
+    for r in recs:
+        total.update(r.counts)
+    meta = container.parse_v3_meta(arc)
+    blobs = [(lv.esc_offset, lv.esc_size) for m in meta.chunk_metas
+             for lv in m.levels]
+    blobs += [(o, s) for m in meta.chunk_metas for lv in m.levels
+              for o, s in zip(lv.plane_offsets, lv.plane_sizes)]
+    blobs = [arc[o:o + s] for o, s in blobs if s]
+    assert total["zlib_jobs"] == len(blobs) > 0
+    assert total["zlib_out_bytes"] == sum(map(len, blobs))
+    assert total["zlib_in_bytes"] == sum(len(zlib.decompress(b))
+                                         for b in blobs)
+    # one join per compress, and only it waits on the pool
+    joins = [r for r in recs if "zlib_jobs" in r.counts]
+    assert [r.name for r in joins] == ["pack.zlib"]
+    assert joins[0].parent == root.id
+
+
 def test_single_chunk_and_batched_paths_nest_alike():
     """v1 (one array through the scalar primitives) and the batched group
     give the same span tree shape under one request each."""
